@@ -86,9 +86,12 @@ def run(verbose: bool = True) -> dict:
         lambda: ops.segment_minmax_tiles(gidx, vals, n, 64, ("min", "max"), tile=256)
     )
 
-    # fused project arithmetic ((a*2+1, a/b) over one VMEM pass)
+    # fused project arithmetic ((a*2+1, (a-b)*0.75) over one VMEM pass)
     ptbl = jnp.asarray(r.normal(size=(n, 2)).astype(np.float32))
-    descrs = (("add", ("mul", ("col", 0), ("lit", 2.0)), ("lit", 1.0)), ("div", ("col", 0), ("col", 1)))
+    descrs = (
+        ("add", ("mul", ("col", 0), ("lit", 2.0)), ("lit", 1.0)),
+        ("mul", ("sub", ("col", 0), ("col", 1)), ("lit", 0.75)),
+    )
     results["project_arith_us"] = _time(lambda: ops.project_tiles(ptbl, descrs, tile=256))
 
     # one-launch fused chain (filter → project → segment fold) vs the same

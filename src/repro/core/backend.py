@@ -18,10 +18,12 @@ Two backends ship in-tree:
     reduction matmuls move bit patterns exactly — the kernels are
     bit-identical to numpy for every fixed-width dtype, including
     ``-0.0``, NaN payloads, Inf, and full-range int64.  Eligibility is
-    decided per morsel *and per column*; anything outside a kernel's
-    envelope — var-width columns, validity masks, unsupported literal /
-    column dtype pairings, or jax being absent entirely — falls back to
-    the numpy kernel, so results are identical either way.
+    decided per morsel *and per column*, by explicit checks before a
+    launch; anything outside a kernel's envelope — var-width columns,
+    validity masks, unsupported literal / column dtype pairings — runs the
+    numpy kernel, so results are identical either way.  A kernel that fails
+    to compile or run fails the request: no error turns into a silent numpy
+    answer, and resolving ``pallas`` without jax installed raises.
 
 Dispatchable ops:
 
@@ -29,11 +31,12 @@ Dispatchable ops:
                     {<, <=, >, >=, ==, !=}; predicate column float32 /
                     int32 / int64; projected columns any fixed-width dtype
     filter          the unfused form (projects every column)
-    project         arithmetic Expr chains (+ - * / over float32 columns,
-                    + - * over int32 columns, python-scalar literals)
+    project         arithmetic Expr chains (+ - * over float32 or int32
+                    columns, python-scalar literals)
     segment_reduce  per-group partial folds: count always, sum for integer
                     columns (8-bit-limb exact, wraparound-identical to
-                    numpy), min/max for finite float32, int32-safe integer,
+                    numpy), min/max for finite float32 without ``-0.0``,
+                    int32-safe integer,
                     and the wide dtypes int64 / uint32 / uint64 / float64
                     via a two-word hi/lo compare — two masked-reduce kernel
                     passes over an order-preserving int64 key image (uint64:
@@ -46,14 +49,18 @@ Dispatchable ops:
                     back; ≤ 256 groups per morsel
 
 ``get_backend("auto")`` selects pallas only when jax reports a real TPU;
-interpret-mode Pallas on CPU is for correctness tests, not speed.
+interpret-mode Pallas on CPU is for correctness tests, not speed.  The
+first kernel load points JAX's persistent compilation cache at
+``JAX_COMPILATION_CACHE_DIR`` when set, else at the checkout's fixed
+``.jax_cache`` (see :func:`configure_compile_cache`).
 """
 
 from __future__ import annotations
 
+import importlib.util
 import math
 import threading
-import warnings
+from pathlib import Path
 from typing import Callable
 
 import numpy as np
@@ -72,6 +79,9 @@ __all__ = [
     "FUSED_INELIGIBLE",
     "FusedChainPlan",
     "plan_fused_chain",
+    "configure_compile_cache",
+    "COMPILE_CACHE_DIR",
+    "resolve_device",
 ]
 
 
@@ -215,34 +225,54 @@ def _planes_to_values(planes: np.ndarray, dtype) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # pallas backend
 # ---------------------------------------------------------------------------
+# fixed, inside the checkout: the cache key includes the directory, so a
+# path that moved between runs would never hit
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Give the kernels a persistent compilation cache; returns its
+    directory.  ``JAX_COMPILATION_CACHE_DIR`` (which jax reads at import)
+    wins when set; otherwise the cache goes to the fixed
+    :data:`COMPILE_CACHE_DIR`.  Mosaic compiles take a second or two, so
+    every compile is cached, not only those above jax's default minimum."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+        compilation_cache.reset_cache()  # re-initialize on the next compile
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax.config.jax_compilation_cache_dir
+
+
 class PallasBackend(ComputeBackend):
     name = "pallas"
     tile = 256
 
     def __init__(self):
         self._kernel_mod = None
-        self._disabled = False
         self._lock = threading.Lock()
         self.kernel_calls = 0  # observability: kernel dispatch count
+        # morsels whose float32 arithmetic left the kernels' exact envelope
+        # (inf, NaN, subnormal, flushed underflow) and were recomputed by
+        # the numpy reference
+        self.envelope_rejects = 0
         # float sums folded through the f64-accumulating reference path
         # (host-side; the kernels' 32-bit lanes cannot hold f64) — the
         # explicit, counted successor of the old silent fallback
         self.f64_folds = 0
 
     def _ops(self):
-        """Import the jit'd kernel wrappers once; a failed import (no jax)
-        permanently disables dispatch and every kernel falls back to numpy."""
-        if self._disabled:
-            return None
+        """Import the jit'd kernel wrappers once and place the compile
+        cache; an import failure propagates."""
         if self._kernel_mod is None:
             with self._lock:
-                if self._kernel_mod is None and not self._disabled:
-                    try:
-                        from repro.kernels import ops as kernel_ops
+                if self._kernel_mod is None:
+                    from repro.kernels import ops as kernel_ops
 
-                        self._kernel_mod = kernel_ops
-                    except Exception:
-                        self._disabled = True
+                    configure_compile_cache()
+                    self._kernel_mod = kernel_ops
         return self._kernel_mod
 
 
@@ -342,8 +372,7 @@ def _fused_plan(batch: RecordBatch, predicate: Expr, columns: list):
 
 @register_kernel("pallas", "filter_select")
 def _pl_filter_select(bk: PallasBackend, batch: RecordBatch, predicate: Expr, columns: list):
-    kernel_ops = bk._ops()
-    plan = _fused_plan(batch, predicate, columns) if kernel_ops is not None else None
+    plan = _fused_plan(batch, predicate, columns)
     if plan is None or batch.num_rows == 0:
         return _np_filter_select(bk, batch, predicate, columns)
     op, kind, t_hi, t_lo, pred_name = plan
@@ -367,10 +396,7 @@ def _pl_filter_select(bk: PallasBackend, batch: RecordBatch, predicate: Expr, co
             table[:n, start + j] = p
     t_hi_bits = int(np.array([t_hi], np.float32).view(np.int32)[0]) if kind == "f32" else int(t_hi)
     scalars = np.asarray([n, t_hi_bits, int(t_lo)], np.int32)
-    try:
-        out, counts = kernel_ops.filter_select_planes(pred_arr, table, scalars, op, kind, tile=tile)
-    except Exception:
-        return _np_filter_select(bk, batch, predicate, columns)
+    out, counts = bk._ops().filter_select_planes(pred_arr, table, scalars, op, kind, tile=tile)
     bk.kernel_calls += 1
     counts = np.asarray(counts)
     n_sel = int(counts.sum())
@@ -392,8 +418,9 @@ def _pl_filter(bk: PallasBackend, batch: RecordBatch, predicate: Expr):
 
 
 # -- fused project arithmetic ----------------------------------------------
-_ARITH_F32 = {"add", "sub", "mul", "div"}
-_ARITH_I32 = {"add", "sub", "mul"}  # int div/mod promote to float64 in numpy
+# int div/mod promote to float64 in numpy; a TPU's float32 division is not
+# correctly rounded (off by an ulp on about a third of ordinary operands)
+_ARITH = {"add", "sub", "mul"}
 
 
 def _contraction_safe(op: str, a, b) -> bool:
@@ -403,7 +430,8 @@ def _contraction_safe(op: str, a, b) -> bool:
     1-ulp divergence whenever the product is inexact.  Only exact products
     are immune, so a float32 mul may sit directly under add/sub solely when
     one factor is a power-of-two literal (a mantissa-preserving scale).
-    Division never contracts, and integer arithmetic is exact."""
+    The TPU rounds such a product separately, as numpy does; the rule holds
+    for the CPU interpreter.  Integer arithmetic is exact."""
     if op not in ("add", "sub"):
         return True
     for t in (a, b):
@@ -414,6 +442,26 @@ def _contraction_safe(op: str, a, b) -> bool:
         ):
             return False
     return True
+
+
+_F32_MIN_NORMAL = 2.0**-126
+
+
+def _f32_lit(v):
+    """A float32 arithmetic literal, or None.  Weak python scalars (and
+    <=32-bit float scalars) keep float32 arithmetic under numpy promotion;
+    the kernels' exact envelope also needs the literal finite and zero or
+    normal as a float32 (a subnormal would be flushed on the device)."""
+    if not (isinstance(v, (int, float)) or (isinstance(v, np.floating) and v.dtype.itemsize <= 4)):
+        return None
+    try:
+        with np.errstate(over="ignore"):
+            v32 = float(np.float32(v))
+    except OverflowError:
+        return None
+    if not math.isfinite(v32) or 0.0 < abs(v32) < _F32_MIN_NORMAL:
+        return None
+    return float(v)
 
 
 def _is_pow2_f32(v) -> bool:
@@ -442,18 +490,15 @@ def _arith_descr(e, batch: RecordBatch, group: str, col_idx: dict):
         if isinstance(v, (bool, np.bool_)):
             return None
         if group == "float32":
-            # weak scalars (and <=32-bit float scalars) keep f32 arithmetic
-            if isinstance(v, (int, float)) or (isinstance(v, np.floating) and v.dtype.itemsize <= 4):
-                return ("lit", float(v))
-            return None
+            v = _f32_lit(v)
+            return None if v is None else ("lit", v)
         if isinstance(v, (int, np.integer)) and not isinstance(v, np.uint64):
             vi = int(v)
             if isinstance(v, np.int64) or not (-(2**31) <= vi <= 2**31 - 1):
                 return None  # would promote to int64 (or raise) in numpy
             return ("lit", vi)
         return None
-    allowed = _ARITH_F32 if group == "float32" else _ARITH_I32
-    if e.op not in allowed or len(e.args) != 2:
+    if e.op not in _ARITH or len(e.args) != 2:
         return None
     a = _arith_descr(e.args[0], batch, group, col_idx)
     if a is None:
@@ -470,8 +515,7 @@ def _arith_descr(e, batch: RecordBatch, group: str, col_idx: dict):
 def _pl_project(bk: PallasBackend, batch: RecordBatch, exprs: dict, out_schema):
     from repro.core.operators import project_morsel
 
-    kernel_ops = bk._ops()
-    if kernel_ops is None or batch.num_rows == 0:
+    if batch.num_rows == 0:
         return project_morsel(batch, exprs, out_schema)
     # plan each expression independently (per-column eligibility)
     groups: dict = {}  # group dtype -> (col_idx, [(out name, descr)])
@@ -494,20 +538,21 @@ def _pl_project(bk: PallasBackend, batch: RecordBatch, exprs: dict, out_schema):
     n = batch.num_rows
     tile = bk.tile
     n_pad = -(-n // tile) * tile
-    try:
-        for group, (col_idx, outs) in groups.items():
-            if not outs:
-                continue
-            np_dt = np.dtype(group)
-            table = np.zeros((n_pad, max(1, len(col_idx))), np_dt)
-            for cname, j in col_idx.items():
-                table[:n, j] = batch.column(cname).values
-            res = np.asarray(kernel_ops.project_tiles(table, tuple(d for _, d in outs), tile=tile))
-            for j, (name, _d) in enumerate(outs):
-                planned[name] = np.ascontiguousarray(res[:n, j])
-    except Exception:
-        return project_morsel(batch, exprs, out_schema)
-    bk.kernel_calls += 1
+    kernel_ops = bk._ops()
+    for group, (col_idx, outs) in groups.items():
+        if not outs:
+            continue
+        np_dt = np.dtype(group)
+        table = np.zeros((n_pad, max(1, len(col_idx))), np_dt)
+        for cname, j in col_idx.items():
+            table[:n, j] = batch.column(cname).values
+        res = np.asarray(kernel_ops.project_tiles(table, tuple(d for _, d in outs), tile=tile))
+        bk.kernel_calls += 1
+        if res[:n, -1].any():  # float32 arithmetic left the exact envelope
+            bk.envelope_rejects += 1
+            return project_morsel(batch, exprs, out_schema)
+        for j, (name, _d) in enumerate(outs):
+            planned[name] = np.ascontiguousarray(res[:n, j])
     # assemble exactly like the reference evaluator: kernel outputs for the
     # planned exprs, numpy evaluation (+dtype coercion) for the rest
     new_cols = {}
@@ -546,12 +591,19 @@ def _limbs_to_int64(sums: np.ndarray) -> np.ndarray:
     return total
 
 
+def _f32_mm_ok(values: np.ndarray) -> bool:
+    """float32 min/max runs on the kernel's order keys: exact for finite
+    values, but NaN propagation and the ``-0.0``/``+0.0`` tie (numpy keeps
+    whichever came later) are not order-key semantics."""
+    return bool(np.isfinite(values).all()) and not ((values == 0.0) & np.signbit(values)).any()
+
+
 def _mm_eligible(values: np.ndarray, kind: str):
-    """Kernel-ready min/max column or None.  float32 must be finite (XLA
-    reduce NaN semantics are not IEEE-reliable); integers must fit int32."""
+    """Kernel-ready min/max column or None.  float32 must pass
+    :func:`_f32_mm_ok`; integers must fit int32."""
     dt = values.dtype
     if dt == np.float32:
-        return values if np.isfinite(values).all() else None
+        return values if _f32_mm_ok(values) else None
     if dt.kind == "b" or (dt.kind == "i" and dt.itemsize <= 4) or (dt.kind == "u" and dt.itemsize <= 2):
         return values.astype(np.int32)
     return None
@@ -640,13 +692,7 @@ def _wide_decode(hi: np.ndarray, lo_s: np.ndarray) -> np.ndarray:
 @register_kernel("pallas", "segment_reduce")
 def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
     kernel_ops = bk._ops()
-    if (
-        kernel_ops is None
-        or ngroups == 0
-        or ngroups > _SEG_GROUP_CAP
-        or n_rows > kernel_ops.SUM_ROW_CAP
-        or n_rows == 0
-    ):
+    if ngroups == 0 or ngroups > _SEG_GROUP_CAP or n_rows > kernel_ops.SUM_ROW_CAP or n_rows == 0:
         return {}
     sums: list = []  # (state name, values)
     fsums: list = []  # (state name, f64 values) — host f64 reference path
@@ -678,69 +724,66 @@ def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
     g32[:n_rows] = np.asarray(gidx, np.int64)[:n_rows]
     out: dict = {}
     kernel_used = False
-    try:
-        if sums or count_names:
-            limb_tbl = np.zeros((n_pad, max(1, _SUM_LIMBS * len(sums))), np.int32)
-            for i, (_name, values) in enumerate(sums):
-                for k, limb in enumerate(_sum_limbs(values)):
-                    limb_tbl[:n_rows, _SUM_LIMBS * i + k] = limb
-            s_res, c_res = kernel_ops.segment_sum_tiles(g32, limb_tbl, n_rows, g_pad, tile=tile)
-            s_res, c_res = np.asarray(s_res), np.asarray(c_res)
-            for i, (name, _values) in enumerate(sums):
-                out[name] = _limbs_to_int64(s_res[:ngroups, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)])
-            for name in count_names:
-                out[name] = c_res[:ngroups].astype(np.int64)
-            kernel_used = True
-        for kind, entries in mms.items():
-            if not entries:
-                continue
-            np_dt = np.float32 if kind == "f32" else np.int32
-            tbl = np.zeros((n_pad, len(entries)), np_dt)
-            for j, (_name, _fn, col) in enumerate(entries):
-                tbl[:n_rows, j] = col
-            fns = tuple(fn for _n, fn, _c in entries)
-            res = np.asarray(kernel_ops.segment_minmax_tiles(g32, tbl, n_rows, g_pad, fns, tile=tile))
-            for j, (name, _fn, _c) in enumerate(entries):
-                out[name] = np.ascontiguousarray(res[:ngroups, j])
-            kernel_used = True
-        if wides:
-            # two-word compare: pass 1 reduces the signed hi words; pass 2
-            # reduces the sign-flipped lo words among only the rows whose hi
-            # word equals their group's extreme (others masked to the
-            # identity sentinel).  Lexicographic (hi, lo') == int64 order on
-            # the order-preserving keys; each column's decoder maps the
-            # extremes (and the empty-group sentinels) back to the source
-            # dtype — int64/uint32 directly, uint64/float64 by inverting
-            # their monotone int64 image (see ``_mm_wide_eligible``).
-            fns = tuple(fn for _n, fn, _c, _d in wides)
-            hi_tbl = np.zeros((n_pad, len(wides)), np.int32)
-            lo_cols = []
-            for j, (_name, _fn, col, _dec) in enumerate(wides):
-                hi, lo = _wide_words(col)
-                hi_tbl[:n_rows, j] = hi
-                lo_cols.append((hi, lo))
-            h_res = np.asarray(kernel_ops.segment_minmax_tiles(g32, hi_tbl, n_rows, g_pad, fns, tile=tile))
-            lo_tbl = np.empty((n_pad, len(wides)), np.int32)
-            for j, (_name, fn, _col, _dec) in enumerate(wides):
-                sent = np.int32(2**31 - 1) if fn == "min" else np.int32(-(2**31))
-                lo_tbl[:, j] = sent
-                hi, lo = lo_cols[j]
-                at_extreme = hi == h_res[:, j][g32[:n_rows]]
-                lo_tbl[:n_rows, j] = np.where(at_extreme, lo, sent)
-            l_res = np.asarray(kernel_ops.segment_minmax_tiles(g32, lo_tbl, n_rows, g_pad, fns, tile=tile))
-            for j, (name, fn, _col, decode) in enumerate(wides):
-                keys64 = _wide_decode(h_res[:ngroups, j], np.ascontiguousarray(l_res[:ngroups, j]))
-                out[name] = decode(keys64, fn)
-            kernel_used = True
-        for name, values in fsums:
-            # f64-accumulating reference path: bit-identical to the numpy
-            # scatter because a fresh state's accumulators start at +0.0 and
-            # np.add.at adds this morsel's values in the same row order
-            acc = np.zeros(ngroups, np.float64)
-            np.add.at(acc, np.asarray(gidx, np.int64), np.asarray(values, np.float64))
-            out[name] = acc
-    except Exception:
-        return {}
+    if sums or count_names:
+        limb_tbl = np.zeros((n_pad, max(1, _SUM_LIMBS * len(sums))), np.int32)
+        for i, (_name, values) in enumerate(sums):
+            for k, limb in enumerate(_sum_limbs(values)):
+                limb_tbl[:n_rows, _SUM_LIMBS * i + k] = limb
+        s_res, c_res = kernel_ops.segment_sum_tiles(g32, limb_tbl, n_rows, g_pad, tile=tile)
+        s_res, c_res = np.asarray(s_res), np.asarray(c_res)
+        for i, (name, _values) in enumerate(sums):
+            out[name] = _limbs_to_int64(s_res[:ngroups, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)])
+        for name in count_names:
+            out[name] = c_res[:ngroups].astype(np.int64)
+        kernel_used = True
+    for kind, entries in mms.items():
+        if not entries:
+            continue
+        np_dt = np.float32 if kind == "f32" else np.int32
+        tbl = np.zeros((n_pad, len(entries)), np_dt)
+        for j, (_name, _fn, col) in enumerate(entries):
+            tbl[:n_rows, j] = col
+        fns = tuple(fn for _n, fn, _c in entries)
+        res = np.asarray(kernel_ops.segment_minmax_tiles(g32, tbl, n_rows, g_pad, fns, tile=tile))
+        for j, (name, _fn, _c) in enumerate(entries):
+            out[name] = np.ascontiguousarray(res[:ngroups, j])
+        kernel_used = True
+    if wides:
+        # two-word compare: pass 1 reduces the signed hi words; pass 2
+        # reduces the sign-flipped lo words among only the rows whose hi
+        # word equals their group's extreme (others masked to the
+        # identity sentinel).  Lexicographic (hi, lo') == int64 order on
+        # the order-preserving keys; each column's decoder maps the
+        # extremes (and the empty-group sentinels) back to the source
+        # dtype — int64/uint32 directly, uint64/float64 by inverting
+        # their monotone int64 image (see ``_mm_wide_eligible``).
+        fns = tuple(fn for _n, fn, _c, _d in wides)
+        hi_tbl = np.zeros((n_pad, len(wides)), np.int32)
+        lo_cols = []
+        for j, (_name, _fn, col, _dec) in enumerate(wides):
+            hi, lo = _wide_words(col)
+            hi_tbl[:n_rows, j] = hi
+            lo_cols.append((hi, lo))
+        h_res = np.asarray(kernel_ops.segment_minmax_tiles(g32, hi_tbl, n_rows, g_pad, fns, tile=tile))
+        lo_tbl = np.empty((n_pad, len(wides)), np.int32)
+        for j, (_name, fn, _col, _dec) in enumerate(wides):
+            sent = np.int32(2**31 - 1) if fn == "min" else np.int32(-(2**31))
+            lo_tbl[:, j] = sent
+            hi, lo = lo_cols[j]
+            at_extreme = hi == h_res[:, j][g32[:n_rows]]
+            lo_tbl[:n_rows, j] = np.where(at_extreme, lo, sent)
+        l_res = np.asarray(kernel_ops.segment_minmax_tiles(g32, lo_tbl, n_rows, g_pad, fns, tile=tile))
+        for j, (name, fn, _col, decode) in enumerate(wides):
+            keys64 = _wide_decode(h_res[:ngroups, j], np.ascontiguousarray(l_res[:ngroups, j]))
+            out[name] = decode(keys64, fn)
+        kernel_used = True
+    for name, values in fsums:
+        # f64-accumulating reference path: bit-identical to the numpy
+        # scatter because a fresh state's accumulators start at +0.0 and
+        # np.add.at adds this morsel's values in the same row order
+        acc = np.zeros(ngroups, np.float64)
+        np.add.at(acc, np.asarray(gidx, np.int64), np.asarray(values, np.float64))
+        out[name] = acc
     if kernel_used:
         bk.kernel_calls += 1
     if fsums:
@@ -766,9 +809,7 @@ def _lit_value(v, group: str):
     if isinstance(v, (bool, np.bool_)):
         return None
     if group == "float32":
-        if isinstance(v, (int, float)) or (isinstance(v, np.floating) and v.dtype.itemsize <= 4):
-            return float(v)
-        return None
+        return _f32_lit(v)
     if isinstance(v, (int, np.integer)) and not isinstance(v, np.uint64):
         vi = int(v)
         if isinstance(v, np.int64) or not (-(2**31) <= vi <= 2**31 - 1):
@@ -823,8 +864,7 @@ def _lower_arith_named(e, mapping: dict, src_schema, group: str):
     if e.op == "lit":
         v = _lit_value(e.args[0], group)
         return None if v is None else ("lit", v)
-    allowed = _ARITH_F32 if group == "float32" else _ARITH_I32
-    if e.op not in allowed or len(e.args) != 2:
+    if e.op not in _ARITH or len(e.args) != 2:
         return None
     a = _lower_arith_named(e.args[0], mapping, src_schema, group)
     if a is None:
@@ -871,8 +911,6 @@ def plan_fused_chain(specs: list, in_schema, agg=None, backend=None):
     if backend is None or getattr(backend, "name", None) != "pallas":
         return None
     kernel_ops = backend._ops()
-    if kernel_ops is None:
-        return None
     mapping = {f.name: ("src", f.name) for f in in_schema}
     cur = in_schema
     filt = None
@@ -1140,46 +1178,32 @@ class FusedChainPlan:
         self._af_cols = af_cols
         self._ai_cols = ai_cols
         self._with_gidx = bool(fsums)
-        self._gidx_off = self._dp + len(descrs_f) + len(descrs_i)
+        # ctab columns: [pass | computed f32 | computed i32 | flag? | gidx?]
+        computed_end = self._dp + len(descrs_f) + len(descrs_i)
+        self._flag_off = computed_end if descrs_f else None
+        self._gidx_off = computed_end + (1 if descrs_f else 0)
         self._checked_cols = checked_cols
         self._sizer = None
-        self._dev_idx = None
         self._dev = None
-        self._dev_resolved = False
         self._staged: dict = {}
         self._stage_lock = threading.Lock()
         self._stage_closed = False
 
     # -- executor wiring ----------------------------------------------------
-    def bind(self, sizer, device_index=None) -> None:
-        """Attach the pipeline's stat sink and (optional) device pin."""
+    def bind(self, sizer, device=None) -> None:
+        """Attach the pipeline's stat sink and (optional) jax device pin
+        (see :func:`resolve_device`)."""
         self._sizer = sizer
-        self._dev_idx = device_index
+        self._dev = device
 
-    def _bump(self, counter: str, k: int = 1) -> None:
-        if self._sizer is not None:
-            self._sizer.bump(counter, k)
-
-    def _device(self):
-        if self._dev_resolved:
-            return self._dev
-        self._dev_resolved = True
-        if self._dev_idx is not None:
-            try:
-                import jax
-
-                devs = jax.devices()
-            except Exception:
-                return None
-            if 0 <= self._dev_idx < len(devs):
-                self._dev = devs[self._dev_idx]
-            else:
-                warnings.warn(
-                    f"DACP_DEVICES index {self._dev_idx} out of range "
-                    f"({len(devs)} jax devices); staging to the default device",
-                    stacklevel=2,
-                )
-        return self._dev
+    def _count_launch(self, out, staged: bool) -> None:
+        if self._sizer is None:
+            return
+        self._sizer.bump("fused_launches")
+        if staged:
+            self._sizer.bump("transfers_overlapped")
+        (dev,) = out[1].devices()  # where the launch really ran
+        self._sizer.bump_device(dev.id)
 
     # -- per-morsel envelope ------------------------------------------------
     def _pad(self, n: int) -> int:
@@ -1201,16 +1225,9 @@ class FusedChainPlan:
         compute).  run/fold pops the staged buffers by batch identity."""
         if self._stage_closed or not self._morsel_ok(batch):
             return
-        try:
-            import jax
-        except Exception:
-            return
-        arrs = self._encode(batch)
-        dev = self._device()
-        try:
-            put = {k: (jax.device_put(v, dev) if dev is not None else jax.device_put(v)) for k, v in arrs.items()}
-        except Exception:
-            return
+        import jax
+
+        put = jax.device_put(self._encode(batch), self._dev)
         with self._stage_lock:
             if self._stage_closed:  # raced a CANCEL teardown: drop, don't leak
                 return
@@ -1275,6 +1292,15 @@ class FusedChainPlan:
         parts = [ctab[i * t : i * t + int(c)] for i, c in enumerate(counts) if c]
         return np.concatenate(parts) if parts else ctab[:0]
 
+    def _left_envelope(self, compact: np.ndarray) -> bool:
+        """Whether a surviving row's float32 arithmetic left the kernels'
+        exact envelope; the morsel then runs the per-op path, where the
+        projection falls to the numpy reference."""
+        if self._flag_off is None or not compact[:, self._flag_off].any():
+            return False
+        self._bk.envelope_rejects += 1
+        return True
+
     def _decode_ref(self, compact: np.ndarray, ref):
         tag = ref[0]
         if tag == "pass":
@@ -1286,6 +1312,10 @@ class FusedChainPlan:
 
     def _launch(self, arrs: dict, gidx: np.ndarray, n: int, segmented: bool, ngroups: int):
         scalars = np.asarray([n, self._t_hi, self._t_lo, 0], np.int32)
+        if self._dev is not None:  # unstaged morsels too run on the pinned chip
+            import jax
+
+            scalars, arrs, gidx = jax.device_put((scalars, arrs, gidx), self._dev)
         return self._kernel_ops.fused_chain_tiles(
             scalars,
             arrs["pred"],
@@ -1319,17 +1349,14 @@ class FusedChainPlan:
         arrs = staged if staged is not None else self._encode(batch)
         n = batch.num_rows
         gidx = np.zeros(self._pad(n), np.int32)
-        try:
-            out = self._launch(arrs, gidx, n, segmented=False, ngroups=8)
-        except Exception:
-            return FUSED_INELIGIBLE
+        out = self._launch(arrs, gidx, n, segmented=False, ngroups=8)
         ctab, counts = np.asarray(out[0]), np.asarray(out[1])
-        self._bump("fused_launches")
-        if staged is not None:
-            self._bump("transfers_overlapped")
+        self._count_launch(out, staged is not None)
         if int(counts.sum()) == 0:
             return None
         compact = self._compact(ctab, counts)
+        if self._left_envelope(compact):
+            return FUSED_INELIGIBLE
         cols = []
         for f, ref in self._out_decode:
             vals = self._decode_ref(compact, ref)
@@ -1348,7 +1375,7 @@ class FusedChainPlan:
         if not self._morsel_ok(batch):
             return FUSED_INELIGIBLE
         for _state, _fn, s in self._mmf:
-            if not np.isfinite(batch.column(s).values).all():
+            if not _f32_mm_ok(batch.column(s).values):
                 return FUSED_INELIGIBLE
         from repro.core.operators import GroupState
         from repro.core.schema import Field, Schema
@@ -1369,14 +1396,12 @@ class FusedChainPlan:
         n = batch.num_rows
         g32 = np.zeros(self._pad(n), np.int32)
         g32[:n] = gidx_full
-        try:
-            out = self._launch(arrs, g32, n, segmented=True, ngroups=g_pad)
-        except Exception:
-            return FUSED_INELIGIBLE
+        out = self._launch(arrs, g32, n, segmented=True, ngroups=g_pad)
         ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst = [np.asarray(o) for o in out]
-        self._bump("fused_launches")
-        if staged is not None:
-            self._bump("transfers_overlapped")
+        self._count_launch(out, staged is not None)
+        compact = self._compact(ctab, counts) if self._fsums or self._flag_off is not None else None
+        if compact is not None and self._left_envelope(compact):
+            return FUSED_INELIGIBLE
         gcnt_v = gcnt[:ng]
         alive = np.flatnonzero(gcnt_v > 0)
         if alive.size == 0:
@@ -1401,7 +1426,6 @@ class FusedChainPlan:
         for j, (state, _fn, _s) in enumerate(self._mmi):
             acc[state] = gmmi[perm, j].astype(np.int64)
         if self._fsums:
-            compact = self._compact(ctab, counts)
             g_sel = compact[:, self._gidx_off]
             for state, ref in self._fsums:
                 vals = np.asarray(self._decode_ref(compact, ref), np.float64)
@@ -1413,6 +1437,17 @@ class FusedChainPlan:
         return st
 
 
+def resolve_device(index: int):
+    """The jax device a ``DACP_DEVICES`` / ``ExecutorConfig.devices`` index
+    names; an index past this host's devices raises."""
+    import jax
+
+    devs = jax.devices()
+    if not 0 <= index < len(devs):
+        raise ValueError(f"device index {index} out of range: jax has {len(devs)} device(s)")
+    return devs[index]
+
+
 # ---------------------------------------------------------------------------
 # backend selection
 # ---------------------------------------------------------------------------
@@ -1422,35 +1457,30 @@ _instances_lock = threading.Lock()
 
 
 def _jax_tpu() -> bool:
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:
+    """Whether jax runs on a TPU.  Only a missing jax reads as "no": a
+    chip that fails to initialise (e.g. held by another process) raises."""
+    if importlib.util.find_spec("jax") is None:
         return False
+    import jax
+
+    return jax.default_backend() == "tpu"
 
 
 def available_backends() -> list:
-    out = ["numpy"]
-    try:
-        import importlib.util
-
-        if importlib.util.find_spec("jax") is not None:
-            out.append("pallas")
-    except Exception:
-        pass
-    return out
+    return ["numpy", "pallas"] if importlib.util.find_spec("jax") is not None else ["numpy"]
 
 
 def get_backend(name: str | None = None) -> ComputeBackend:
     """Resolve a backend by name.  ``auto`` (default, or env
     ``DACP_BACKEND``) picks pallas only on a real TPU; ``pallas`` without
-    jax still resolves — its kernels just fall back to numpy."""
+    jax installed raises."""
     name = name or env_str("DACP_BACKEND")
     if name == "auto":
         name = "pallas" if _jax_tpu() else "numpy"
     if name not in BACKENDS:
         raise KeyError(f"unknown compute backend {name!r}; known: {sorted(BACKENDS)}")
+    if name not in available_backends():
+        raise RuntimeError(f"compute backend {name!r} needs jax, which is not installed")
     with _instances_lock:
         inst = _instances.get(name)
         if inst is None:

@@ -107,8 +107,9 @@ _register(
     "DACP_DEVICES",
     "devices",
     None,
-    "Jax device indices that fused-pipeline stages round-robin staged "
-    "uploads across (default: jax's default device).",
+    "Jax device indices that pallas pipelines round-robin across: fused "
+    "launches, staged uploads and per-op kernels (default: jax's default "
+    "device; an index past the host's devices fails the run).",
 )
 _register(
     "DACP_SCAN_WORKERS",

@@ -67,6 +67,7 @@ or closed.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue
 import threading
@@ -75,7 +76,7 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Callable, Iterator
 
-from repro.core.backend import FUSED_INELIGIBLE, ComputeBackend, get_backend, plan_fused_chain
+from repro.core.backend import FUSED_INELIGIBLE, ComputeBackend, get_backend, plan_fused_chain, resolve_device
 from repro.core.batch import RecordBatch, concat_batches
 from repro.core.dag import Dag, Node
 from repro.core.env import env_bytes, env_devices, env_dir, env_int, env_morsel_rows, env_str, knob_default
@@ -152,10 +153,11 @@ class ExecutorConfig:
     spill_dir     directory for spill partition files (None = the system
                   temp dir; env ``DACP_SPILL_DIR``).
     spill_fanout  partitions per grace-hash level (≥ 2).
-    devices       jax device indices that fused-pipeline stages round-robin
-                  their device-resident launches/staged uploads across
-                  (None = jax's default device; env ``DACP_DEVICES`` as a
-                  comma-separated list, validated with warn + fallback).
+    devices       jax device indices that pallas pipelines round-robin
+                  across: each pipeline's fused launches, staged uploads
+                  and per-op kernels run on its device (None = jax's
+                  default device; env ``DACP_DEVICES`` as a comma-separated
+                  list).  An index past the host's devices fails the run.
     """
 
     num_workers: int = field(default_factory=default_workers)
@@ -255,6 +257,7 @@ class _MorselSizer:
         self.fused_launches = 0
         self.transfers_overlapped = 0
         self.micromorsels_coalesced = 0
+        self.device_launches: dict = {}  # jax device id -> fused launches run there
         self._m = None  # EWMA moments (E[r], E[t], E[r²], E[r·t])
         self._lock = threading.Lock()
 
@@ -264,6 +267,10 @@ class _MorselSizer:
     def bump(self, counter: str, k: int = 1) -> None:
         with self._lock:
             setattr(self, counter, getattr(self, counter) + k)
+
+    def bump_device(self, device_id: int) -> None:
+        with self._lock:
+            self.device_launches[device_id] = self.device_launches.get(device_id, 0) + 1
 
     def observe(self, rows: int, seconds: float) -> None:
         if rows <= 0:
@@ -330,6 +337,7 @@ class ExecutorStats:
             "fused_launches": sizer.fused_launches,
             "transfers_overlapped": sizer.transfers_overlapped,
             "micromorsels_coalesced": sizer.micromorsels_coalesced,
+            "device_launches": dict(sizer.device_launches),
         }
 
     def attach(self, sizer: _MorselSizer) -> None:
@@ -351,6 +359,10 @@ class ExecutorStats:
         """Aggregate morsel/row progress across finished + live stages."""
         done = list(self.pipelines)
         running = [self._entry(s) for s in list(self.live)]
+        per_device: dict = {}
+        for p in done + running:
+            for dev, k in p.get("device_launches", {}).items():
+                per_device[dev] = per_device.get(dev, 0) + k
         return {
             "morsels_done": sum(p["morsels"] for p in done + running),
             "rows_processed": sum(p["rows"] for p in done + running),
@@ -359,6 +371,7 @@ class ExecutorStats:
             "fused_launches": sum(p.get("fused_launches", 0) for p in done + running),
             "transfers_overlapped": sum(p.get("transfers_overlapped", 0) for p in done + running),
             "micromorsels_coalesced": sum(p.get("micromorsels_coalesced", 0) for p in done + running),
+            "device_launches": per_device,
         }
 
     def to_dict(self) -> dict:
@@ -556,6 +569,22 @@ def _branch_items(cops, batches, sizer: _MorselSizer, cfg: ExecutorConfig, do_st
 _device_rr = itertools.count()  # round-robin cursor over cfg.devices
 
 
+def _next_device(cfg: ExecutorConfig, backend: ComputeBackend):
+    """The next pipeline's jax device, or None (jax's default device)."""
+    if not cfg.devices or backend.name != "pallas":
+        return None
+    return resolve_device(cfg.devices[next(_device_rr) % len(cfg.devices)])
+
+
+def _on_device(dev):
+    """Run a morsel's per-op kernels on its pipeline's device."""
+    if dev is None:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.default_device(dev)
+
+
 def _run_ordered(
     branches: list,
     cfg: ExecutorConfig,
@@ -587,21 +616,23 @@ def _run_ordered(
         window=cfg.effective_window(),
         prefetch=cfg.prefetch_batches,
     )
+    devices = [_next_device(cfg, backend) for _ in compiled]
     plans = [cops[1] for _, cops in compiled if cops[1] is not None]
-    for pl in plans:
-        dev = cfg.devices[next(_device_rr) % len(cfg.devices)] if cfg.devices else None
-        pl.bind(sizer, dev)
+    for (_, cops), dev in zip(compiled, devices):
+        if cops[1] is not None:
+            cops[1].bind(sizer, dev)
     if stats is not None:
         stats.attach(sizer)  # live progress (flow STATUS) before the stage ends
 
     if cfg.num_workers <= 1:
         try:
-            for br, cops in compiled:
+            for (br, cops), dev in zip(compiled, devices):
                 for m in _branch_items(cops, br.sdf.iter_batches(), sizer, cfg, do_stage=False):
                     if cancel is not None and cancel.is_set():
                         raise FlowCancelled("execution cancelled")
                     t0 = time.perf_counter()
-                    out = make_item(cops, m)
+                    with _on_device(dev):
+                        out = make_item(cops, m)
                     sizer.observe(m.num_rows, time.perf_counter() - t0)
                     if out is not None:
                         yield out
@@ -618,9 +649,9 @@ def _run_ordered(
         pf.start()  # all sources (incl. every exchange pull) activate now
 
     def morsels():
-        for (_, cops), pf in zip(compiled, prefetchers):
+        for (_, cops), pf, dev in zip(compiled, prefetchers, devices):
             for m in _branch_items(cops, pf, sizer, cfg, do_stage=True):
-                yield cops, m
+                yield cops, m, dev
 
     it = morsels()
     src_lock = threading.Lock()
@@ -643,7 +674,7 @@ def _run_ordered(
                 if state["total"] is not None:
                     return
                 try:
-                    cops, m = next(it)
+                    cops, m, dev = next(it)
                 except StopIteration:
                     state["total"] = state["assigned"]
                     with cond:
@@ -660,7 +691,8 @@ def _run_ordered(
                 state["assigned"] = seq + 1
             try:
                 t0 = time.perf_counter()
-                out = make_item(cops, m)
+                with _on_device(dev):
+                    out = make_item(cops, m)
                 sizer.observe(m.num_rows, time.perf_counter() - t0)
             except BaseException as e:  # noqa: BLE001 - surfaced to consumer
                 with cond:

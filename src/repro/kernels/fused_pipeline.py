@@ -24,9 +24,13 @@ three stages — per row-tile, in a single grid step:
      makes the fused partial ``GroupState`` byte-identical to the
      reference fold over the filtered batch.
 
-Everything stays int32/float32 in-kernel; the same exactness arguments as
-the per-op kernels apply (integer matmuls move bit patterns verbatim, limb
-sums stay below 2^26 under ``SUM_ROW_CAP``, min/max is comparison-only).
+Everything stays int32/float32 in-kernel and lowers the same way as the
+per-op kernels: byte-plane bf16 one-hot matmuls for compaction and limb
+sums (exact, see ``filter_select`` / ``segment_reduce``), a matmul prefix
+sum instead of ``cumsum``, integer order keys for float compares and
+min/max, scalars and per-tile counts in SMEM, and the predicate planes,
+group ids and min/max columns laid out as rows so they broadcast against
+the group axis.
 Float sums are NOT folded in-kernel (f64 accumulation order matters); their
 source planes ride through the compaction output and the host folds them
 with ``np.add.at`` in row order — bit-identical to the reference.
@@ -51,38 +55,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.filter_select import _pred_mask
-from repro.kernels.project_arith import _eval_descr
+from repro.kernels.filter_select import compact, f32_order_key, onehot_dot, pred_mask, row_ids, survivors
+from repro.kernels.project_arith import eval_checked, eval_descr
+from repro.kernels.segment_reduce import I32_MAX, f32_from_keys, mm_fold, mm_init, mm_sentinels, onehot
 
 __all__ = ["fused_chain_tiles"]
 
-_I32_MAX = 2**31 - 1
-_I32_MIN = -(2**31)
 
-
-def _mm_sentinels(fns, is_float: bool):
-    if is_float:
-        return tuple(jnp.inf if fn == "min" else -jnp.inf for fn in fns)
-    return tuple(_I32_MAX if fn == "min" else _I32_MIN for fn in fns)
-
-
-def _mm_fold(out_ref, vals, onehot, fns, sentinels):
-    """Masked per-group min/max of ``vals`` (tile, M) accumulated into
-    ``out_ref`` (G, M)."""
-    cur = out_ref[...]
-    cols = []
-    for j, fn in enumerate(fns):
-        masked = jnp.where(onehot, vals[:, j][None, :], sentinels[j])  # (G, tile)
-        red = masked.min(axis=1) if fn == "min" else masked.max(axis=1)
-        cols.append(jnp.minimum(cur[:, j], red) if fn == "min" else jnp.maximum(cur[:, j], red))
-    out_ref[...] = jnp.stack(cols, axis=1)
+def _cat(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def _kernel(
     sc_ref,
     pred_ref,
-    gidx_ref,
+    grow_ref,
+    gcol_ref,
     pass_ref,
     limb_ref,
     mmf_ref,
@@ -109,74 +99,57 @@ def _kernel(
     ngroups,
     tile,
 ):
-    rows = pl.program_id(0) * tile + jax.lax.broadcasted_iota(jnp.int32, (tile,), 0)
-    valid = rows < sc_ref[0]
-    if kind == "none":
-        mask = valid
-    else:
-        mask = _pred_mask(pred_ref[...], sc_ref[1], sc_ref[2], op=op, kind=kind) & valid
+    rows = row_ids(tile)
+    mask = rows < sc_ref[0]
+    if kind != "none":
+        mask = pred_mask(pred_ref[...], sc_ref[1], sc_ref[2], op=op, kind=kind) & mask
 
     # -- projection arithmetic on pre-filter rows (element-wise == the
     #    reference's post-filter values on every surviving row)
-    fcols = [_eval_descr(d, af_ref[...]) for d in descrs_f]
-    icols = [_eval_descr(d, ai_ref[...]) for d in descrs_i]
+    checked = [eval_checked(d, af_ref[...]) for d in descrs_f]
+    icols = [eval_descr(d, ai_ref[...]) for d in descrs_i]
 
-    # -- one-hot compaction of passthrough planes + computed columns
+    # -- one-hot compaction of passthrough planes + computed columns (+ the
+    #    float envelope flag, so only surviving rows' flags reach the host)
     parts = [pass_ref[...]]
-    if fcols:
-        parts.append(jax.lax.bitcast_convert_type(jnp.stack(fcols, axis=1), jnp.int32))
-    if icols:
-        parts.append(jnp.stack(icols, axis=1))
+    parts += [jax.lax.bitcast_convert_type(v, jnp.int32) for v, _f in checked]
+    parts += icols
+    if checked:
+        flag = checked[0][1]
+        for _v, f in checked[1:]:
+            flag = flag | f
+        parts.append(jnp.where(flag, 1, 0))
     if with_gidx:
-        parts.append(gidx_ref[...][:, None])
-    ctab = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-    cols_iota = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-    p_mat = ((pos[:, None] == cols_iota) & mask[:, None]).astype(jnp.int32)
-    ctab_ref[...] = jax.lax.dot_general(
-        p_mat, ctab, (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
-    cnt_ref[0] = mask.sum(dtype=jnp.int32)
+        parts.append(gcol_ref[...])
+    ctab_ref[...] = compact(mask, _cat(parts))
+    cnt_ref[pl.program_id(0)] = survivors(mask)
 
-    sent_f = _mm_sentinels(fns_f, True)
-    sent_i = _mm_sentinels(fns_i, False)
+    sent_f = mm_sentinels(fns_f)
+    sent_i = mm_sentinels(fns_i)
 
     @pl.when(pl.program_id(0) == 0)
     def _():
         gsum_ref[...] = jnp.zeros_like(gsum_ref)
         gcnt_ref[...] = jnp.zeros_like(gcnt_ref)
-        gfirst_ref[...] = jnp.full_like(gfirst_ref, _I32_MAX)
-        gmmf_ref[...] = jnp.stack(
-            [jnp.full((ngroups,), sent_f[j], gmmf_ref.dtype) for j in range(len(fns_f))], axis=1
-        )
-        gmmi_ref[...] = jnp.stack(
-            [jnp.full((ngroups,), sent_i[j], gmmi_ref.dtype) for j in range(len(fns_i))], axis=1
-        )
+        gfirst_ref[...] = jnp.full_like(gfirst_ref, I32_MAX)
+        gmmf_ref[...] = mm_init(ngroups, sent_f)
+        gmmi_ref[...] = mm_init(ngroups, sent_i)
 
     if not segmented:
         return
 
     # -- masked segment fold (only surviving rows reach any group)
-    giota = jax.lax.broadcasted_iota(jnp.int32, (ngroups, tile), 0)
-    onehot = (gidx_ref[...][None, :] == giota) & mask[None, :]
-    oh32 = onehot.astype(jnp.int32)
-    limbs = limb_ref[...]
-    if csums:
-        extra = []
-        for k in csums:
-            v = icols[k]
-            extra += [(v >> (8 * s)) & 0xFF for s in range(3)]
-            extra.append(v >> 24)  # signed top limb (arithmetic shift)
-        limbs = jnp.concatenate([limbs, jnp.stack(extra, axis=1)], axis=1)
-    gsum_ref[...] += jax.lax.dot_general(
-        oh32, limbs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
-    gcnt_ref[...] += oh32.sum(axis=1)
-    gfirst_ref[...] = jnp.minimum(
-        gfirst_ref[...], jnp.where(onehot, rows[None, :], _I32_MAX).min(axis=1)
-    )
-    _mm_fold(gmmf_ref, mmf_ref[...], onehot, fns_f, sent_f)
-    _mm_fold(gmmi_ref, mmi_ref[...], onehot, fns_i, sent_i)
+    oh = onehot(grow_ref[...], mask, ngroups)
+    limbs = [limb_ref[...]]
+    for k in csums:
+        v = icols[k]
+        limbs += [(v >> (8 * s)) & 0xFF for s in range(3)]
+        limbs.append(v >> 24)  # signed top limb (arithmetic shift)
+    gsum_ref[...] += onehot_dot(oh, _cat(limbs))
+    gcnt_ref[...] += jnp.sum(jnp.where(oh, 1, 0), axis=1, keepdims=True)
+    gfirst_ref[...] = jnp.minimum(gfirst_ref[...], jnp.where(oh, rows, I32_MAX).min(axis=1, keepdims=True))
+    gmmf_ref[...] = mm_fold(gmmf_ref[...], mmf_ref[...], oh, fns_f, sent_f)
+    gmmi_ref[...] = mm_fold(gmmi_ref[...], mmi_ref[...], oh, fns_i, sent_i)
 
 
 def fused_chain_tiles(
@@ -213,14 +186,15 @@ def fused_chain_tiles(
         gidx      (N,)      int32  full-morsel group ids (zeros unsegmented)
         pass_tbl  (N, Dp)   int32  compaction passthrough planes
         limb_tbl  (N, L)    int32  passthrough sum-column 8-bit limb planes
-        mmf       (N, Mf)   f32    min/max float32 columns
+        mmf       (N, Mf)   f32    min/max float32 columns (no NaN, no -0.0)
         mmi       (N, Mi)   i32    min/max int columns (widened)
         af        (N, Af)   f32    projection-arithmetic input columns
         ai        (N, Ai)   i32    projection-arithmetic input columns
 
     Returns ``(ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst)``: the
     per-tile-compacted table ``[pass | computed f32 | computed i32 |
-    gidx?]`` with per-tile survivor counts, and per-group limb sums
+    f32 envelope flag (with any computed f32) | gidx?]`` with per-tile
+    survivor counts, and per-group limb sums
     ``[passthrough | in-kernel csums]``, counts, min/max extremes, and the
     minimum surviving row index (``2^31-1`` for groups with no survivors).
     """
@@ -231,7 +205,7 @@ def fused_chain_tiles(
     length = limb_tbl.shape[1]
     mf, mi = mmf.shape[1], mmi.shape[1]
     afw, aiw = af.shape[1], ai.shape[1]
-    dc = dp + len(descrs_f) + len(descrs_i) + (1 if with_gidx else 0)
+    dc = dp + len(descrs_f) + len(descrs_i) + (1 if descrs_f else 0) + (1 if with_gidx else 0)
     ls = length + 4 * len(csums)
     assert len(fns_f) == mf and len(fns_i) == mi, (fns_f, mf, fns_i, mi)
     kernel = functools.partial(
@@ -248,47 +222,44 @@ def fused_chain_tiles(
         ngroups=ngroups,
         tile=tile,
     )
-    return pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    def rows(width):  # a (width, N) row-major table, one (width, tile) block per step
+        return pl.BlockSpec((width, tile), lambda i: (0, i))
+
+    def tiles(width):  # a (N, width) column table, one (tile, width) block per step
+        return pl.BlockSpec((tile, width), lambda i: (i, 0))
+
+    def whole(width):  # a per-group accumulator resident across the grid
+        return pl.BlockSpec((ngroups, width), lambda i: (0, 0))
+
+    gidx = jnp.asarray(gidx, jnp.int32)
+    mmf_keys = f32_order_key(jax.lax.bitcast_convert_type(jnp.asarray(mmf, jnp.float32), jnp.int32))
+    ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst = pl.pallas_call(
         kernel,
         grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((4,), lambda i: (0,)),
-            pl.BlockSpec((tile, p), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile, dp), lambda i: (i, 0)),
-            pl.BlockSpec((tile, length), lambda i: (i, 0)),
-            pl.BlockSpec((tile, mf), lambda i: (i, 0)),
-            pl.BlockSpec((tile, mi), lambda i: (i, 0)),
-            pl.BlockSpec((tile, afw), lambda i: (i, 0)),
-            pl.BlockSpec((tile, aiw), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, dc), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((ngroups, ls), lambda i: (0, 0)),
-            pl.BlockSpec((ngroups,), lambda i: (0,)),
-            pl.BlockSpec((ngroups, mf), lambda i: (0, 0)),
-            pl.BlockSpec((ngroups, mi), lambda i: (0, 0)),
-            pl.BlockSpec((ngroups,), lambda i: (0,)),
-        ],
+        in_specs=[smem, rows(p), rows(1), tiles(1), tiles(dp), tiles(length), rows(mf), rows(mi), tiles(afw), tiles(aiw)],
+        out_specs=[tiles(dc), smem, whole(ls), whole(1), whole(mf), whole(mi), whole(1)],
         out_shape=[
             jax.ShapeDtypeStruct((n, dc), jnp.int32),
             jax.ShapeDtypeStruct((n // tile,), jnp.int32),
             jax.ShapeDtypeStruct((ngroups, ls), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups,), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups, mf), jnp.float32),
+            jax.ShapeDtypeStruct((ngroups, 1), jnp.int32),
+            jax.ShapeDtypeStruct((ngroups, mf), jnp.int32),
             jax.ShapeDtypeStruct((ngroups, mi), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups,), jnp.int32),
+            jax.ShapeDtypeStruct((ngroups, 1), jnp.int32),
         ],
         interpret=interpret,
     )(
         jnp.asarray(scalars, jnp.int32),
-        pred,
-        gidx,
+        jnp.asarray(pred).T,
+        gidx.reshape(1, n),
+        gidx.reshape(n, 1),
         pass_tbl,
         limb_tbl,
-        mmf,
-        mmi,
+        mmf_keys.T,
+        jnp.asarray(mmi).T,
         af,
         ai,
     )
+    return ctab, counts, gsum, gcnt[:, 0], f32_from_keys(gmmf, fns_f), gmmi, gfirst[:, 0]

@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: True off-TPU (the kernels execute via the
-Pallas interpreter for correctness tests on CPU), False on TPU (Mosaic
-compilation).  Wrappers also own the thin jnp epilogues (e.g. global
-compaction after per-tile filter_select).
+``interpret`` defaults to auto: True on the CPU (the kernels execute via
+the Pallas interpreter for the correctness tests, ``JAX_PLATFORMS=cpu``),
+False everywhere else (Mosaic compilation — a platform the kernels cannot
+compile for fails loudly instead of interpreting).  Wrappers also own the
+thin jnp epilogues (e.g. global compaction after per-tile filter_select).
 """
 
 from __future__ import annotations
@@ -41,7 +42,7 @@ __all__ = [
 
 
 def auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

@@ -4,8 +4,9 @@ predicate dtype (float32/int32/int64) and comparison (< <= > >= == !=),
 multi-dtype projections (f64/i64/u8/f16/bool ride through the bit-plane
 kernel), project arithmetic, and segment-reduce aggregation — including
 ``-0.0``, NaN payloads, and full-range int64.  Skipped cleanly when jax is
-absent (the pallas backend then falls back to numpy everywhere, making the
-comparison vacuous)."""
+absent (the pallas backend then cannot be resolved).  A kernel failure is
+never turned into a numpy answer: the last section checks that it fails
+the request instead."""
 
 import numpy as np
 import pytest
@@ -748,3 +749,141 @@ def test_fused_chain_composes_with_spill(monkeypatch):
     _assert_byte_identical(got, ref)
     assert stats.progress()["fused_launches"] > 0, "spill run did not use the fused path"
     assert stats.to_dict()["spill"]["spills"] >= 1, "budget never triggered a spill"
+
+
+# ---------------------------------------------------------------------------
+# no silent fallback: kernel failures, missing jax, bad devices, the cache
+# ---------------------------------------------------------------------------
+def _cook_dag(kind):
+    bld = Dag.build()
+    s = bld.source("dacp://h:1/d")
+    if kind == "fused":
+        node = bld.add("filter", {"predicate": col("f32_a") > 0.0}, [s])
+        node = bld.add("select", {"columns": ["f32_a", "i64_d"]}, [node])
+    elif kind == "filter_select":  # two filters: fused-ineligible, per-op kernels
+        node = bld.add("filter", {"predicate": col("f32_a") > 0.0}, [s])
+        node = bld.add("filter", {"predicate": col("i32_e") > 2}, [node])
+        node = bld.add("select", {"columns": ["f32_a", "i64_d"]}, [node])
+    elif kind == "project":  # no filter, no aggregate: per-op project
+        node = bld.add("project", {"exprs": {"y": col("f32_a") * 2.0}, "keep": True}, [s])
+    else:  # int64 min: fused-ineligible, per-op segment reduce
+        aggs = {"n": {"fn": "count"}, "lo": {"fn": "min", "column": "i64_d"}}
+        node = bld.add("aggregate", {"keys": ["i32_e"], "aggs": aggs}, [s])
+    return bld.finish(node)
+
+
+@pytest.mark.parametrize(
+    "kind,op",
+    [
+        ("fused", "fused_chain_tiles"),
+        ("filter_select", "filter_select_planes"),
+        ("project", "project_tiles"),
+        ("aggregate", "segment_sum_tiles"),
+        ("aggregate", "segment_minmax_tiles"),
+    ],
+)
+def test_kernel_failure_fails_the_cook(monkeypatch, kind, op):
+    """A kernel that raises fails the request; it never comes back as the
+    numpy answer."""
+    from repro.kernels import ops
+
+    backend = get_backend("pallas")
+    backend._ops()
+
+    def broken(*args, **kwargs):
+        raise RuntimeError(f"{op} failed")
+
+    monkeypatch.setattr(ops, op, broken)
+    batch = _random_batch(np.random.default_rng(30))
+    with pytest.raises(RuntimeError, match=f"{op} failed"):
+        _run(_cook_dag(kind), batch, "pallas")
+
+
+def test_pallas_backend_needs_jax(monkeypatch):
+    import sys
+
+    from repro.core.backend import available_backends
+
+    monkeypatch.setitem(sys.modules, "jax", None)  # import jax -> ImportError
+    assert available_backends() == ["numpy"]
+    with pytest.raises(RuntimeError, match="needs jax"):
+        get_backend("pallas")
+    assert get_backend("auto").name == "numpy"  # no jax: auto means numpy
+
+
+def test_auto_backend_surfaces_jax_init_errors(monkeypatch):
+    """A chip that fails to initialise is an error, not a silent numpy."""
+
+    def busy():
+        raise RuntimeError("TPU held by another process")
+
+    monkeypatch.setattr(jax, "default_backend", busy)
+    with pytest.raises(RuntimeError, match="held by another process"):
+        get_backend("auto")
+
+
+@pytest.mark.parametrize("via_env", [False, True])
+def test_out_of_range_device_index_raises(monkeypatch, via_env):
+    batch = _random_batch(np.random.default_rng(31))
+    dag = _cook_dag("fused")
+    if via_env:
+        monkeypatch.setenv("DACP_DEVICES", "0,99")
+        cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend="pallas")
+    else:
+        cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend="pallas", devices=(99,))
+    with pytest.raises(ValueError, match="out of range"):
+        for _ in range(2):  # the round-robin reaches the bad index
+            execute_parallel(dag, lambda n: _sdf(batch), cfg).collect()
+
+
+def test_pinned_device_counts_launches():
+    """Pinned pipelines report where their fused launches ran."""
+    from repro.core.executor import ExecutorStats
+
+    batch = _random_batch(np.random.default_rng(32))
+    stats = ExecutorStats()
+    cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend="pallas", devices=(0,))
+    got = execute_parallel(_cook_dag("fused"), lambda n: _sdf(batch), cfg, stats=stats).collect()
+    _assert_byte_identical(got, _run(_cook_dag("fused"), batch, "numpy"))
+    prog = stats.progress()
+    assert prog["device_launches"] == {jax.devices()[0].id: prog["fused_launches"]}
+
+
+def test_compile_cache_default_is_fixed_inside_checkout():
+    import pathlib
+
+    from repro.core.backend import COMPILE_CACHE_DIR
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert COMPILE_CACHE_DIR == repo / ".jax_cache"
+    ignored = (repo / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
+@pytest.mark.parametrize("env_dir", [False, True])
+def test_compile_cache_follows_env(tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins where set (jax reads it at
+    import, so this runs in a fresh interpreter); otherwise the kernels'
+    first load points the cache at the fixed checkout path."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    from repro.core.backend import COMPILE_CACHE_DIR
+
+    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = (
+        "import jax\n"
+        "from repro.core.backend import get_backend\n"
+        "get_backend('pallas')._ops()\n"
+        "print(jax.config.jax_compilation_cache_dir, jax.config.jax_persistent_cache_min_compile_time_secs)\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True, check=True)
+    cache_dir, min_secs = out.stdout.split()
+    assert cache_dir == str(tmp_path if env_dir else COMPILE_CACHE_DIR)
+    assert float(min_secs) == 0.0
