@@ -39,6 +39,7 @@ from repro.core.batch import RecordBatch
 from repro.core.errors import DacpError, PermissionDenied, TokenError, TransportError
 from repro.core.schema import Schema
 from repro.core.sdf import StreamingDataFrame
+from repro.core.trace import span
 from repro.transport import framing
 from repro.transport.channel import INBOX_FRAMES
 from repro.transport.flight import recv_sdf, send_sdf
@@ -625,14 +626,17 @@ class DacpSession:
         if ftype != framing.SCHEMA:
             raise TransportError(f"expected SCHEMA frame, got {ftype}")
         schema = Schema.from_json(header["schema"])
+        flow = header.get("flow_id")
 
         def frames():
             try:
                 while True:
-                    ft, hd, body = call.recv()
-                    if ft == framing.BATCH:
+                    with span("dacp.frame.recv", flow=flow):
+                        ft, hd, body = call.recv()
+                        batch = RecordBatch.from_buffers(schema, hd, body) if ft == framing.BATCH else None
+                    if batch is not None:
                         seq = int(hd.get("seq", -1))
-                        yield seq, RecordBatch.from_buffers(schema, hd, body)
+                        yield seq, batch
                         if not legacy:
                             try:
                                 # in-band ack: the server releases seqs < ack

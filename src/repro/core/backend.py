@@ -68,6 +68,7 @@ import numpy as np
 from repro.core.batch import Column, RecordBatch
 from repro.core.env import env_str
 from repro.core.expr import Expr
+from repro.core.trace import span
 
 __all__ = [
     "ComputeBackend",
@@ -1120,7 +1121,10 @@ class FusedChainPlan:
     ``stage`` pre-uploads a morsel's kernel inputs (double buffering: the
     H2D transfer of morsel *i+1* overlaps the compute of morsel *i*);
     staged buffers are torn down by ``clear_staged`` on pipeline exit or
-    cancel.  Per-morsel envelope violations return ``FUSED_INELIGIBLE``."""
+    cancel.  Per-morsel envelope violations return ``FUSED_INELIGIBLE``.
+    Spans split a morsel's host work: ``dacp.morsel.factorize`` (keys),
+    ``.encode`` (planes and upload), ``.launch`` (dispatch), ``.sync``
+    (waiting for the device's outputs), ``.fold`` (decode, float64 folds)."""
 
     def __init__(
         self,
@@ -1227,7 +1231,8 @@ class FusedChainPlan:
             return
         import jax
 
-        put = jax.device_put(self._encode(batch), self._dev)
+        with span("dacp.morsel.encode", rows=batch.num_rows, staged=True):
+            put = jax.device_put(self._encode(batch), self._dev)
         with self._stage_lock:
             if self._stage_closed:  # raced a CANCEL teardown: drop, don't leak
                 return
@@ -1254,6 +1259,11 @@ class FusedChainPlan:
             return len(self._staged)
 
     # -- host-side encode / decode -------------------------------------------
+    def _encode_inline(self, batch: RecordBatch) -> dict:
+        """Encode an unstaged morsel on the worker that runs it."""
+        with span("dacp.morsel.encode", rows=batch.num_rows, staged=False):
+            return self._encode(batch)
+
     def _encode(self, batch: RecordBatch) -> dict:
         n = batch.num_rows
         n_pad = self._pad(n)
@@ -1346,22 +1356,25 @@ class FusedChainPlan:
         staged = self._take_staged(batch)
         if not self._morsel_ok(batch):
             return FUSED_INELIGIBLE
-        arrs = staged if staged is not None else self._encode(batch)
         n = batch.num_rows
+        arrs = staged if staged is not None else self._encode_inline(batch)
         gidx = np.zeros(self._pad(n), np.int32)
-        out = self._launch(arrs, gidx, n, segmented=False, ngroups=8)
-        ctab, counts = np.asarray(out[0]), np.asarray(out[1])
-        self._count_launch(out, staged is not None)
-        if int(counts.sum()) == 0:
-            return None
-        compact = self._compact(ctab, counts)
-        if self._left_envelope(compact):
-            return FUSED_INELIGIBLE
-        cols = []
-        for f, ref in self._out_decode:
-            vals = self._decode_ref(compact, ref)
-            cols.append(Column(f.dtype, values=vals) if ref[0] == "pass" else Column.from_values(f.dtype, vals))
-        return RecordBatch(self._out_schema, cols)
+        with span("dacp.morsel.launch", rows=n):
+            out = self._launch(arrs, gidx, n, segmented=False, ngroups=8)
+        with span("dacp.morsel.sync"):
+            ctab, counts = np.asarray(out[0]), np.asarray(out[1])
+        with span("dacp.morsel.fold"):
+            self._count_launch(out, staged is not None)
+            if int(counts.sum()) == 0:
+                return None
+            compact = self._compact(ctab, counts)
+            if self._left_envelope(compact):
+                return FUSED_INELIGIBLE
+            cols = []
+            for f, ref in self._out_decode:
+                vals = self._decode_ref(compact, ref)
+                cols.append(Column(f.dtype, values=vals) if ref[0] == "pass" else Column.from_values(f.dtype, vals))
+            return RecordBatch(self._out_schema, cols)
 
     # -- aggregate fold --------------------------------------------------------
     def fold(self, batch: RecordBatch):
@@ -1381,60 +1394,64 @@ class FusedChainPlan:
         from repro.core.schema import Field, Schema
 
         keys = [k for k, _s in self._key_srcs]
-        if all(k == s for k, s in self._key_srcs):
-            kb = batch
-        else:
-            fields = [Field(k, batch.schema.field(s).dtype) for k, s in self._key_srcs]
-            kb = RecordBatch(Schema(fields), [batch.column(s) for _k, s in self._key_srcs])
-        tmp = GroupState(keys, {}, self._mode, kb.schema, vectorized=True)
-        gidx_full = tmp._factorize(kb)
+        n = batch.num_rows
+        with span("dacp.morsel.factorize", rows=n):
+            if all(k == s for k, s in self._key_srcs):
+                kb = batch
+            else:
+                fields = [Field(k, batch.schema.field(s).dtype) for k, s in self._key_srcs]
+                kb = RecordBatch(Schema(fields), [batch.column(s) for _k, s in self._key_srcs])
+            tmp = GroupState(keys, {}, self._mode, kb.schema, vectorized=True)
+            gidx_full = tmp._factorize(kb)
         ng = len(tmp.gids)
         if ng == 0 or ng > _SEG_GROUP_CAP:
             return FUSED_INELIGIBLE
         g_pad = max(8, -(-ng // 8) * 8)
-        arrs = staged if staged is not None else self._encode(batch)
-        n = batch.num_rows
+        arrs = staged if staged is not None else self._encode_inline(batch)
         g32 = np.zeros(self._pad(n), np.int32)
         g32[:n] = gidx_full
-        out = self._launch(arrs, g32, n, segmented=True, ngroups=g_pad)
-        ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst = [np.asarray(o) for o in out]
-        self._count_launch(out, staged is not None)
-        compact = self._compact(ctab, counts) if self._fsums or self._flag_off is not None else None
-        if compact is not None and self._left_envelope(compact):
-            return FUSED_INELIGIBLE
-        gcnt_v = gcnt[:ng]
-        alive = np.flatnonzero(gcnt_v > 0)
-        if alive.size == 0:
-            return None
-        perm = alive[np.argsort(gfirst[:ng][alive], kind="stable")]
-        st = GroupState(
-            self._agg_keys, self._aggs, self._mode, self._agg_schema, vectorized=True, backend=self._bk
-        )
-        st.key_rows = [tmp.key_rows[g] for g in perm]
-        st.gids = {kt: i for i, kt in enumerate(st.key_rows)}
-        acc: dict = {}
-        for state in self._gcnt_states:
-            acc[state] = gcnt_v[perm].astype(np.int64)
-        for i, (state, _s) in enumerate(self._limb_srcs):
-            acc[state] = _limbs_to_int64(gsum[:, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)][perm])
-        base = self._limb_base
-        for j, (state, _idx) in enumerate(self._csum_states):
-            s4 = gsum[perm, base + 4 * j : base + 4 * (j + 1)].astype(np.int64)
-            acc[state] = s4[:, 0] + (s4[:, 1] << 8) + (s4[:, 2] << 16) + (s4[:, 3] << 24)
-        for j, (state, _fn, _s) in enumerate(self._mmf):
-            acc[state] = gmmf[perm, j].astype(np.float64)
-        for j, (state, _fn, _s) in enumerate(self._mmi):
-            acc[state] = gmmi[perm, j].astype(np.int64)
-        if self._fsums:
-            g_sel = compact[:, self._gidx_off]
-            for state, ref in self._fsums:
-                vals = np.asarray(self._decode_ref(compact, ref), np.float64)
-                accf = np.zeros(ng, np.float64)
-                np.add.at(accf, g_sel, vals)
-                acc[state] = accf[perm]
-        for name, (_init, dt) in st._state_specs().items():
-            st.acc[name] = np.ascontiguousarray(np.asarray(acc[name], dt))
-        return st
+        with span("dacp.morsel.launch", rows=n):
+            out = self._launch(arrs, g32, n, segmented=True, ngroups=g_pad)
+        with span("dacp.morsel.sync"):
+            ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst = [np.asarray(o) for o in out]
+        with span("dacp.morsel.fold"):
+            self._count_launch(out, staged is not None)
+            compact = self._compact(ctab, counts) if self._fsums or self._flag_off is not None else None
+            if compact is not None and self._left_envelope(compact):
+                return FUSED_INELIGIBLE
+            gcnt_v = gcnt[:ng]
+            alive = np.flatnonzero(gcnt_v > 0)
+            if alive.size == 0:
+                return None
+            perm = alive[np.argsort(gfirst[:ng][alive], kind="stable")]
+            st = GroupState(
+                self._agg_keys, self._aggs, self._mode, self._agg_schema, vectorized=True, backend=self._bk
+            )
+            st.key_rows = [tmp.key_rows[g] for g in perm]
+            st.gids = {kt: i for i, kt in enumerate(st.key_rows)}
+            acc: dict = {}
+            for state in self._gcnt_states:
+                acc[state] = gcnt_v[perm].astype(np.int64)
+            for i, (state, _s) in enumerate(self._limb_srcs):
+                acc[state] = _limbs_to_int64(gsum[:, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)][perm])
+            base = self._limb_base
+            for j, (state, _idx) in enumerate(self._csum_states):
+                s4 = gsum[perm, base + 4 * j : base + 4 * (j + 1)].astype(np.int64)
+                acc[state] = s4[:, 0] + (s4[:, 1] << 8) + (s4[:, 2] << 16) + (s4[:, 3] << 24)
+            for j, (state, _fn, _s) in enumerate(self._mmf):
+                acc[state] = gmmf[perm, j].astype(np.float64)
+            for j, (state, _fn, _s) in enumerate(self._mmi):
+                acc[state] = gmmi[perm, j].astype(np.int64)
+            if self._fsums:
+                g_sel = compact[:, self._gidx_off]
+                for state, ref in self._fsums:
+                    vals = np.asarray(self._decode_ref(compact, ref), np.float64)
+                    accf = np.zeros(ng, np.float64)
+                    np.add.at(accf, g_sel, vals)
+                    acc[state] = accf[perm]
+            for name, (_init, dt) in st._state_specs().items():
+                st.acc[name] = np.ascontiguousarray(np.asarray(acc[name], dt))
+            return st
 
 
 def resolve_device(index: int):

@@ -44,9 +44,9 @@ output for a given N regardless of worker count) or adaptive
 (``morsel_rows="auto"``: each pipeline tunes its slice size from an EWMA of
 observed morsel latency toward ~1 ms/morsel, clamped to [4096, 262144];
 row *order* is still deterministic, but float aggregation partial sums may
-group differently run-to-run as boundaries move).  Each run's
-``ExecutorStats`` (``get_last_stats()``) reports per-pipeline morsel counts
-and the tuned size.
+group differently run-to-run as boundaries move).  The ``ExecutorStats`` a
+caller passes in (a flow's own, for STATUS) reports per-pipeline morsel
+counts, the tuned size and the run's scan counters.
 
 Memory budget: ``ExecutorConfig.memory_budget`` (env ``DACP_MEMORY_BUDGET``)
 bounds the combined bytes of all breaker build states in a run through a
@@ -63,6 +63,12 @@ results stay byte-identical to in-memory execution.  Spill counters
 Laziness contract: building the executor does no work; worker threads spin
 up on the first pull of the output SDF and wind down when it is exhausted
 or closed.
+
+Spans (``repro.core.trace``): ``dacp.morsel`` around each morsel's work,
+``dacp.scan.wait`` while the morsel source waits on a source's empty
+prefetch queue, ``dacp.merge`` around the aggregate breaker's merges.
+Workers and prefetchers run under the spawning thread's flow id
+(``trace.bind``), so their spans carry it.
 """
 
 from __future__ import annotations
@@ -103,6 +109,7 @@ from repro.core.spill import (
     collect_build,
     spilled_join_stream,
 )
+from repro.core.trace import bind, span
 
 __all__ = [
     "ExecutorConfig",
@@ -110,7 +117,6 @@ __all__ = [
     "execute_parallel",
     "prefetch_sdf",
     "default_workers",
-    "get_last_stats",
 ]
 
 DEFAULT_MORSEL_ROWS = knob_default("DACP_MORSEL_ROWS")
@@ -319,11 +325,23 @@ class ExecutorStats:
     reported live (``"live": True`` — flow STATUS progress) from their
     attached sizers.  When the run has a memory budget, ``to_dict()``
     additionally carries the shared accountant's ``"spill"`` counters
-    (budget, bytes/partitions/batches spilled, grace-hash recursion depth)."""
+    (budget, bytes/partitions/batches spilled, grace-hash recursion depth).
+
+    ``scans`` holds one scan ``report`` per source the run opens (see
+    ``ScanAdapter.scan``); ``progress()`` sums them into ``scan_bytes_read``,
+    ``scan_bytes_needed`` and ``scan_rows``."""
 
     pipelines: list = field(default_factory=list)
     accountant: MemoryAccountant | None = None
     live: list = field(default_factory=list)
+    scans: list = field(default_factory=list)
+
+    def scan_report(self) -> dict:
+        """A fresh scan ``report`` for one source of this run (one dict per
+        source: a union's sources fill theirs on their own threads)."""
+        report: dict = {}
+        self.scans.append(report)
+        return report
 
     @staticmethod
     def _entry(sizer: _MorselSizer) -> dict:
@@ -372,6 +390,9 @@ class ExecutorStats:
             "transfers_overlapped": sum(p.get("transfers_overlapped", 0) for p in done + running),
             "micromorsels_coalesced": sum(p.get("micromorsels_coalesced", 0) for p in done + running),
             "device_launches": per_device,
+            "scan_bytes_read": sum(r.get("bytes_read", 0) for r in self.scans),
+            "scan_bytes_needed": sum(r.get("bytes_needed", 0) for r in self.scans),
+            "scan_rows": sum(r.get("rows_read", 0) for r in self.scans),
         }
 
     def to_dict(self) -> dict:
@@ -379,17 +400,6 @@ class ExecutorStats:
         if self.accountant is not None:
             d["spill"] = self.accountant.to_dict()
         return d
-
-
-_last_stats: ExecutorStats | None = None
-_last_stats_lock = threading.Lock()
-
-
-def get_last_stats() -> ExecutorStats | None:
-    """Stats of the most recently *created* parallel execution (its entries
-    appear as the lazy output is consumed)."""
-    with _last_stats_lock:
-        return _last_stats
 
 
 # ---------------------------------------------------------------------------
@@ -403,19 +413,22 @@ class _Prefetch:
     Exceptions (e.g. a dead exchange pull) are re-raised to the consumer
     with their original type, so upstream resilience/retry still works.
     ``depth_fn`` (optional) makes the bound dynamic: the adaptive morsel
-    sizer shrinks source read-ahead when batches turn out expensive."""
+    sizer shrinks source read-ahead when batches turn out expensive.
+    ``source`` marks a pipeline's source (a scan or an exchange pull): the
+    consumer's waits on its empty queue are ``dacp.scan.wait`` spans."""
 
-    def __init__(self, sdf: StreamingDataFrame, depth: int, depth_fn=None):
+    def __init__(self, sdf: StreamingDataFrame, depth: int, depth_fn=None, source: bool = False):
         self._sdf = sdf
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
         self._depth_fn = depth_fn
+        self._source = source
         self._stop = False
         self._exc: BaseException | None = None
         self._thread: threading.Thread | None = None
 
     def start(self) -> None:
         if self._thread is None:
-            self._thread = threading.Thread(target=self._run, daemon=True)
+            self._thread = threading.Thread(target=bind(self._run), daemon=True)
             self._thread.start()
 
     def _run(self) -> None:
@@ -439,13 +452,28 @@ class _Prefetch:
                 continue
         return False
 
+    def _get(self):
+        """The next queued item, or None once closed."""
+        if self._stop:
+            return None
+        try:
+            return self._q.get_nowait()
+        except queue.Empty:
+            pass
+        with span("dacp.scan.wait") if self._source else contextlib.nullcontext():
+            while not self._stop:
+                try:
+                    return self._q.get(timeout=0.1)
+                except queue.Empty:
+                    continue
+        return None
+
     def __iter__(self) -> Iterator[RecordBatch]:
         self.start()
-        while not self._stop:
-            try:
-                item = self._q.get(timeout=0.1)
-            except queue.Empty:
-                continue
+        while True:
+            item = self._get()
+            if item is None:
+                return
             if item is _DONE:
                 if self._exc is not None:
                     raise self._exc
@@ -477,14 +505,17 @@ def prefetch_sdf(sdf: StreamingDataFrame, depth: int = 4) -> StreamingDataFrame:
 # ordered morsel runs
 # ---------------------------------------------------------------------------
 class _Branch:
-    """One pipeline input: a source SDF plus the op specs applied to its
-    morsels.  Unions contribute several branches to the same stage."""
+    """One pipeline input: an SDF plus the op specs applied to its morsels.
+    Unions contribute several branches to the same stage.  ``source`` marks
+    an SDF the resolver opened (a scan or an exchange pull), as opposed to
+    an upstream breaker's output."""
 
-    __slots__ = ("sdf", "specs")
+    __slots__ = ("sdf", "specs", "source")
 
-    def __init__(self, sdf: StreamingDataFrame, specs: list | None = None):
+    def __init__(self, sdf: StreamingDataFrame, specs: list | None = None, source: bool = False):
         self.sdf = sdf
         self.specs = specs if specs is not None else []
+        self.source = source
 
 
 def _apply_ops(cops, batch: RecordBatch) -> RecordBatch | None:
@@ -631,7 +662,7 @@ def _run_ordered(
                     if cancel is not None and cancel.is_set():
                         raise FlowCancelled("execution cancelled")
                     t0 = time.perf_counter()
-                    with _on_device(dev):
+                    with _on_device(dev), span("dacp.morsel", rows=m.num_rows):
                         out = make_item(cops, m)
                     sizer.observe(m.num_rows, time.perf_counter() - t0)
                     if out is not None:
@@ -644,7 +675,7 @@ def _run_ordered(
         return
 
     depth_fn = (lambda: sizer.prefetch_depth) if cfg.auto_morsels else None
-    prefetchers = [_Prefetch(br.sdf, cfg.prefetch_batches, depth_fn=depth_fn) for br, _ in compiled]
+    prefetchers = [_Prefetch(br.sdf, cfg.prefetch_batches, depth_fn, br.source) for br, _ in compiled]
     for pf in prefetchers:
         pf.start()  # all sources (incl. every exchange pull) activate now
 
@@ -691,7 +722,7 @@ def _run_ordered(
                 state["assigned"] = seq + 1
             try:
                 t0 = time.perf_counter()
-                with _on_device(dev):
+                with _on_device(dev), span("dacp.morsel", rows=m.num_rows):
                     out = make_item(cops, m)
                 sizer.observe(m.num_rows, time.perf_counter() - t0)
             except BaseException as e:  # noqa: BLE001 - surfaced to consumer
@@ -704,7 +735,7 @@ def _run_ordered(
                 state["buf"][seq] = out
                 cond.notify_all()
 
-    threads = [threading.Thread(target=worker, daemon=True) for _ in range(cfg.num_workers)]
+    threads = [threading.Thread(target=bind(worker), daemon=True) for _ in range(cfg.num_workers)]
     for t in threads:
         t.start()
     try:
@@ -854,17 +885,17 @@ class _Compiler:
         if memo is not None:
             branches, schema = memo
             # consumers mutate spec lists; hand each its own copy
-            return [_Branch(br.sdf, list(br.specs)) for br in branches], schema
+            return [_Branch(br.sdf, list(br.specs), br.source) for br in branches], schema
         out = self._compile_node(self.dag.nodes[nid])
         self._memo[nid] = out
         branches, schema = out
-        return [_Branch(br.sdf, list(br.specs)) for br in branches], schema
+        return [_Branch(br.sdf, list(br.specs), br.source) for br in branches], schema
 
     def _compile_node(self, node: Node) -> tuple:
         op = node.op
         if op in ("source", "exchange"):
             sdf = self.resolver(node)
-            return [_Branch(sdf)], sdf.schema
+            return [_Branch(sdf, source=True)], sdf.schema
         if op in _STREAMING_OPS:
             branches, schema = self._stream(node.inputs[0])
             spec, schema = self._streaming_spec(node, schema)
@@ -962,7 +993,8 @@ class _Compiler:
                     if spiller is not None:
                         spiller.spill_state(st)
                         continue
-                    total.merge(st)
+                    with span("dacp.merge"):
+                        total.merge(st)
                     if spillable:
                         nb = total.approx_nbytes()
                         acct.adjust(nb - reserved)
@@ -1087,18 +1119,15 @@ def execute_parallel(
 
     Semantics match ``operators.execute`` (same rows, same order for a given
     morsel size); execution is lazy — workers start on the first pull.
-    ``stats`` (or ``get_last_stats()``) collects per-pipeline morsel counts
-    and the tuned morsel size as the output is consumed.  ``cancel`` (a
+    ``stats`` collects per-pipeline morsel counts and the tuned morsel size
+    as the output is consumed.  ``cancel`` (a
     ``threading.Event``) is the flow-lifecycle cancellation hook: setting it
     makes every stage raise ``FlowCancelled`` and release its workers,
     prefetchers, and spill state within a bounded delay."""
-    global _last_stats
     cfg = config or ExecutorConfig()
     backend = get_backend(cfg.backend)
     if stats is None:
         stats = ExecutorStats()
     acct = MemoryAccountant(cfg.memory_budget)
     stats.accountant = acct
-    with _last_stats_lock:
-        _last_stats = stats
     return _Compiler(dag, source_resolver, cfg, backend, stats, acct, cancel).compile()

@@ -145,7 +145,9 @@ class ScanAdapter:
     ):
         """Stream the source as RecordBatches (superset semantics, see the
         module docstring).  ``report``, when given, is filled with scan
-        accounting (rows/bytes emitted, regions skipped) for benchmarks."""
+        accounting (rows/bytes emitted, regions skipped); the caller names
+        the columns it needs in ``report["columns_needed"]``, against which
+        the columnar adapter counts ``bytes_needed`` beside ``bytes_read``."""
         raise NotImplementedError
 
     # -- helpers ------------------------------------------------------------

@@ -3,6 +3,12 @@
 file is the ``part_range`` split unit — batches never span part files, so
 disjoint contiguous ranges concatenated in order reproduce the full scan
 byte-identically (the partition-parallel planner's contract).
+
+A scan reads every member of each part file it opens (the adapter declares
+no column projection).  Its ``report`` counts ``bytes_read`` (the members'
+bytes), ``bytes_needed`` (those of the members of ``columns_needed``, the
+columns the plan asked for) and ``rows_read``; spans ``dacp.scan.part``
+time one part file's read and ``dacp.scan.batch`` one batch's build.
 """
 
 from __future__ import annotations
@@ -17,6 +23,7 @@ import numpy as np
 from repro.core.batch import Column, RecordBatch
 from repro.core.schema import Schema
 from repro.core.sdf import StreamingDataFrame
+from repro.core.trace import bind, span
 from repro.server.adapters.base import DEFAULT_BATCH_ROWS, Capabilities, ScanAdapter
 from repro.server.adapters.structured import npz_arrays_sdf
 
@@ -29,6 +36,14 @@ def is_columnar_dataset(path: str) -> bool:
 
 def columnar_parts(root: str) -> list:
     return sorted(p for p in os.listdir(root) if p.startswith("part-") and p.endswith(".npz"))
+
+
+def _member_column(member: str) -> str:
+    """The column a part file's member holds (``c``, ``c__offsets``, ``c__data``)."""
+    for suffix in ("__offsets", "__data"):
+        if member.endswith(suffix):
+            return member[: -len(suffix)]
+    return member
 
 
 class ColumnarAdapter(ScanAdapter):
@@ -64,6 +79,7 @@ class ColumnarAdapter(ScanAdapter):
         batch_rows=DEFAULT_BATCH_ROWS,
         scan_workers: int = 1,
         part_range=None,
+        report: dict | None = None,
         **_kw,
     ):
         root = self.path
@@ -72,6 +88,10 @@ class ColumnarAdapter(ScanAdapter):
         if part_range is not None:
             lo, hi = int(part_range[0]), int(part_range[1])
             parts = parts[lo:hi]
+        if report is not None:
+            needed = set(report.get("columns_needed", schema.names))
+            for key in ("bytes_read", "bytes_needed", "rows_read"):
+                report.setdefault(key, 0)
 
         def _cast(batch: RecordBatch) -> RecordBatch:
             # npz inference loses STRING-vs-BINARY and column order; restore both
@@ -83,15 +103,34 @@ class ColumnarAdapter(ScanAdapter):
                 cols.append(c)
             return RecordBatch(schema, cols)
 
-        def _load(p: str) -> dict:
+        def _load(p: str) -> tuple:
             with np.load(os.path.join(root, p), mmap_mode="r") as z:
-                return {k: z[k] for k in z.files}
+                sizes = {i.filename.removesuffix(".npy"): i.file_size for i in z.zip.infolist()}
+                with span("dacp.scan.part", part=p, bytes=sum(sizes.values())):
+                    return {k: z[k] for k in z.files}, sizes
+
+        def _batches(loaded: tuple):
+            # runs on the consuming thread, the report's one writer
+            arrays, sizes = loaded
+            if report is not None:
+                report["bytes_read"] += sum(sizes.values())
+                report["bytes_needed"] += sum(n for m, n in sizes.items() if _member_column(m) in needed)
+            it = npz_arrays_sdf(arrays, batch_rows).iter_batches()
+            while True:
+                with span("dacp.scan.batch"):
+                    b = next(it, None)
+                    if b is not None:
+                        b = _cast(b)
+                if b is None:
+                    return
+                if report is not None:
+                    report["rows_read"] += b.num_rows
+                yield b
 
         def gen():
             if scan_workers <= 1 or len(parts) <= 1:
                 for p in parts:
-                    for b in npz_arrays_sdf(_load(p), batch_rows).iter_batches():
-                        yield _cast(b)
+                    yield from _batches(_load(p))
                 return
             # bounded read-ahead: up to scan_workers part files decode in
             # background threads while earlier parts stream out, in part order
@@ -99,15 +138,14 @@ class ColumnarAdapter(ScanAdapter):
                 pending: deque = deque()
                 it = iter(parts)
                 for p in it:
-                    pending.append(pool.submit(_load, p))
+                    pending.append(pool.submit(bind(_load), p))
                     if len(pending) >= scan_workers:
                         break
                 while pending:
-                    arrays = pending.popleft().result()
+                    loaded = pending.popleft().result()
                     nxt = next(it, None)
                     if nxt is not None:
-                        pending.append(pool.submit(_load, nxt))
-                    for b in npz_arrays_sdf(arrays, batch_rows).iter_batches():
-                        yield _cast(b)
+                        pending.append(pool.submit(bind(_load), nxt))
+                    yield from _batches(loaded)
 
         return StreamingDataFrame(schema, gen)

@@ -33,6 +33,7 @@ from repro.core.env import env_int
 from repro.core.errors import ResourceNotFound, SchemaError
 from repro.core.expr import Expr
 from repro.core.sdf import StreamingDataFrame
+from repro.core.trace import span
 from repro.server import adapters
 from repro.server.adapters import (
     DEFAULT_BATCH_ROWS,
@@ -97,7 +98,13 @@ def scan_path(
     ``part_ranges`` capability ignore it.
 
     ``report``, when given, is filled with the adapter's scan accounting
-    (regions skipped, rows/files read) — the benchmark harness reads it.
+    (regions skipped, rows/files/bytes read); ``report["columns_needed"]``
+    names the columns the caller needs (output plus residual predicate), so
+    an adapter can count the bytes it read beyond them.  The executor keeps
+    one per source on the run's ``ExecutorStats``.
+
+    Span ``dacp.scan.filter`` times the residual re-filter and projection
+    of one batch.
     """
     if not os.path.exists(path):
         raise ResourceNotFound(f"no such path: {path}")
@@ -120,12 +127,16 @@ def scan_path(
 
     residual = adapter.residual_predicate(predicate) if predicate is not None else None
 
+    # the projection plus whatever the residual re-filter needs
+    need = set(schema.names) if out_cols is None else set(out_cols)
+    if residual is not None:
+        need |= residual.referenced_columns()
     native_cols = None
     if caps.column_projection and out_cols is not None:
-        # the adapter materializes the projection plus whatever the residual
-        # re-filter needs; the extra columns are dropped again below
-        need = set(out_cols) | (residual.referenced_columns() if residual is not None else set())
+        # the adapter materializes just these; the extra columns are dropped again below
         native_cols = [c for c in schema.names if c in need]
+    if report is not None:
+        report["columns_needed"] = [c for c in schema.names if c in need]
 
     sdf = adapter.scan(
         columns=native_cols,
@@ -146,17 +157,21 @@ def _finalize(sdf: StreamingDataFrame, out_cols, residual: Expr | None) -> Strea
     if residual is None and (out_cols is None or list(out_cols) == list(schema.names)):
         return sdf
 
+    def refine(b):
+        if residual is not None:
+            mask = np.asarray(residual.evaluate(b), bool)
+            if not mask.any():
+                return None
+            if not mask.all():
+                b = b.filter(mask)
+        return b.select(out_cols) if out_cols is not None else b
+
     def gen():
         for b in sdf.iter_batches():
-            if residual is not None:
-                mask = np.asarray(residual.evaluate(b), bool)
-                if not mask.any():
-                    continue
-                if not mask.all():
-                    b = b.filter(mask)
-            if out_cols is not None:
-                b = b.select(out_cols)
-            yield b
+            with span("dacp.scan.filter", rows=b.num_rows):
+                b = refine(b)
+            if b is not None:
+                yield b
 
     return StreamingDataFrame(out_schema, gen)
 

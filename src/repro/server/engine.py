@@ -72,6 +72,7 @@ class SDFEngine:
         batch_rows: int | None = None,
         strict_columns: bool = True,
         part_range=None,
+        report: dict | None = None,
     ) -> StreamingDataFrame:
         uri = parse_uri(uri_str)
         if uri.segments and uri.segments[0] == ".flow":
@@ -91,6 +92,7 @@ class SDFEngine:
             strict_columns=strict_columns,
             scan_workers=self.executor.scan_workers,
             part_range=part_range,
+            report=report,
             **kwargs,
         )
 
@@ -101,7 +103,8 @@ class SDFEngine:
         ``stats`` collects this run's executor observability (flows pass a
         per-flow instance so STATUS reports live progress); ``cancel`` is
         the flow-lifecycle cancellation event threaded into every pipeline
-        stage of the parallel executor."""
+        stage of the parallel executor.  Each local source's scan fills a
+        ``report`` of ``stats`` (the flow's scan counters)."""
         dag = optimize(dag)
 
         def resolver(node: Node) -> StreamingDataFrame:
@@ -116,6 +119,7 @@ class SDFEngine:
                     predicate=node.params.get("predicate"),
                     strict_columns=False,  # optimizer-pruned hints, not user input
                     part_range=node.params.get("part_range"),
+                    report=stats.scan_report() if stats is not None else None,
                 )
             if node.op == "exchange":
                 return self._remote(node)

@@ -43,6 +43,7 @@ import queue
 import threading
 import time
 
+from repro.core import trace
 from repro.core.dag import Dag
 from repro.core.env import env_int, env_str
 from repro.core.errors import DacpError, PermissionDenied, ResourceNotFound, TokenError, TransportError
@@ -315,11 +316,13 @@ class FairdServer:
             subject = self._authorize(header, "COOK")
             self.stats["cook"] += 1
             dag = Dag.from_bytes(bytes(body))
-            fl, _shared = self._start_flow(subject, dag, header)
-            try:
-                self.stats["rows_out"] += self._serve_flow_stream(channel, fl, 0, ack_on_send=True)
-            finally:
-                self.flows.release_cook(fl, network=self.network)
+            with trace.span("dacp.cook", verb="COOK") as sp:
+                fl, _shared = self._start_flow(subject, dag, header)
+                sp.set_metadata(flow=fl.flow_id)
+                try:
+                    self.stats["rows_out"] += self._serve_flow_stream(channel, fl, 0, ack_on_send=True)
+                finally:
+                    self.flows.release_cook(fl, network=self.network)
             return False
         if verb == "START":
             # asynchronous COOK: return a flow handle immediately.  The
@@ -328,8 +331,10 @@ class FairdServer:
             subject = self._authorize(header, "COOK")
             self.stats["start"] += 1
             dag = Dag.from_bytes(bytes(body))
-            fl, shared = self._start_flow(subject, dag, header)
-            channel.send(framing.OK, {"flow_id": fl.flow_id, "state": fl.state, "shared": shared})
+            with trace.span("dacp.cook", verb="START") as sp:
+                fl, shared = self._start_flow(subject, dag, header)
+                sp.set_metadata(flow=fl.flow_id)
+                channel.send(framing.OK, {"flow_id": fl.flow_id, "state": fl.state, "shared": shared})
             return False
         if verb == "FETCH":
             self.stats["fetch"] += 1
@@ -439,19 +444,20 @@ class FairdServer:
         laid out."""
         from repro.server.scheduler import CrossDomainScheduler
 
-        dag = optimize(dag)
-        placement = self.mesh.choose_domain if self.mesh is not None else None
-        the_plan = plan_dag(dag, client_domain=self.authority, placement=placement)
-        k = env_int("DACP_PARTITION_PARALLEL")
-        if k >= 2 and self.network is not None:
-            # partition-parallel SUBMIT: split eligible columnar scans into
-            # K child flows over disjoint part ranges (byte-identical merge
-            # through the ordered partition union — see planner.partition_plan)
-            the_plan = partition_plan(the_plan, self._part_count, k)
-        sched = CrossDomainScheduler(coordinator=self, network=self.network, cancel=cancel)
-        if attach is not None:
-            attach(sched)
-        return sched.run(the_plan, stats=stats), sched
+        with trace.span("dacp.plan"):
+            dag = optimize(dag)
+            placement = self.mesh.choose_domain if self.mesh is not None else None
+            the_plan = plan_dag(dag, client_domain=self.authority, placement=placement)
+            k = env_int("DACP_PARTITION_PARALLEL")
+            if k >= 2 and self.network is not None:
+                # partition-parallel SUBMIT: split eligible columnar scans into
+                # K child flows over disjoint part ranges (byte-identical merge
+                # through the ordered partition union — see planner.partition_plan)
+                the_plan = partition_plan(the_plan, self._part_count, k)
+            sched = CrossDomainScheduler(coordinator=self, network=self.network, cancel=cancel)
+            if attach is not None:
+                attach(sched)
+            return sched.run(the_plan, stats=stats), sched
 
     def _part_count(self, uri_str: str) -> int | None:
         """Split-unit count of a part-splittable source (columnar dataset
@@ -541,7 +547,8 @@ class FairdServer:
             fl.consumers += 1  # idle-reap exemption while this loop serves
         finished = False
         try:
-            rows, finished = self._serve_flow_frames(channel, fl, from_seq, ack_on_send, cid)
+            with trace.flow(fl.flow_id):
+                rows, finished = self._serve_flow_frames(channel, fl, from_seq, ack_on_send, cid)
             return rows
         finally:
             with fl.cond:
@@ -569,7 +576,8 @@ class FairdServer:
             try:
                 if kind == "batch":
                     _k, hdr, parts, nrows = item
-                    channel.send(framing.BATCH, hdr, parts)
+                    with trace.span("dacp.frame.send", rows=nrows):
+                        channel.send(framing.BATCH, hdr, parts)
                     cursor += 1
                     rows += nrows
                     if ack_on_send:

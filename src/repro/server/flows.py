@@ -82,6 +82,7 @@ import time
 from repro.core.batch import RecordBatch
 from repro.core.env import env_bytes, env_float
 from repro.core.errors import DacpError, FlowCancelled, ResourceNotFound
+from repro.core import trace
 from repro.core.executor import ExecutorStats
 from repro.server.admission import AdmissionController
 from repro.server.plancache import PlanCache
@@ -450,7 +451,8 @@ class FlowManager:
     # ------------------------------------------------------------------ producer
     def _produce(self, fl: FlowRecord, runner) -> None:
         try:
-            self._produce_inner(fl, runner)
+            with trace.flow(fl.flow_id):  # the plan's spans and threads carry the flow id
+                self._produce_inner(fl, runner)
         finally:
             self._settle_cache(fl)
             if fl.kind != "submit":

@@ -3,7 +3,9 @@
 ``send_sdf`` frames: SCHEMA, BATCH*, END.  ``recv_sdf`` returns a one-shot
 StreamingDataFrame whose batches materialize lazily as frames arrive — the
 receiver's compute starts on beta_0 without waiting for beta_{k+1}
-(paper §III-A streaming semantics).
+(paper §III-A streaming semantics).  Each frame's receipt and decode is a
+``dacp.frame.recv`` span (``repro.core.trace``), with the stream's flow id
+when the SCHEMA frame names one.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ from repro.core.batch import RecordBatch
 from repro.core.errors import DacpError, TransportError
 from repro.core.schema import Schema
 from repro.core.sdf import StreamingDataFrame
+from repro.core.trace import span
 from repro.transport import framing
 
 __all__ = ["send_sdf", "recv_sdf", "send_error"]
@@ -48,12 +51,15 @@ def recv_sdf(channel, timeout: float | None = None) -> StreamingDataFrame:
     if ftype != framing.SCHEMA:
         raise TransportError(f"expected SCHEMA frame, got {ftype}")
     schema = Schema.from_json(header["schema"])
+    flow = header.get("flow_id")  # a flow's stream (COOK, FETCH); None for GET
 
     def batches() -> Iterator[RecordBatch]:
         while True:
-            ft, hd, body = channel.recv(timeout=timeout)
-            if ft == framing.BATCH:
-                yield RecordBatch.from_buffers(schema, hd, body)
+            with span("dacp.frame.recv", flow=flow):
+                ft, hd, body = channel.recv(timeout=timeout)
+                batch = RecordBatch.from_buffers(schema, hd, body) if ft == framing.BATCH else None
+            if batch is not None:
+                yield batch
             elif ft == framing.END:
                 return
             elif ft == framing.ERROR:
