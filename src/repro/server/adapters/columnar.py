@@ -4,11 +4,15 @@ file is the ``part_range`` split unit — batches never span part files, so
 disjoint contiguous ranges concatenated in order reproduce the full scan
 byte-identically (the partition-parallel planner's contract).
 
-A scan reads every member of each part file it opens (the adapter declares
-no column projection).  Its ``report`` counts ``bytes_read`` (the members'
-bytes), ``bytes_needed`` (those of the members of ``columns_needed``, the
-columns the plan asked for) and ``rows_read``; spans ``dacp.scan.part``
-time one part file's read and ``dacp.scan.batch`` one batch's build.
+The adapter projects natively: ``scan(columns=[...])`` reads only the
+members of those columns (``c``, or ``c__offsets`` and ``c__data`` for a
+string column) from each part file it opens, and streams them in the order
+given.  ``columns=None`` or an empty list reads every member, as Parquet's
+``names or None`` does, so a zero-column plan keeps its row count.  Its
+``report`` counts ``bytes_read`` (the bytes of the members read),
+``bytes_needed`` (those of the members of ``columns_needed``, the columns the
+plan asked for) and ``rows_read``; spans ``dacp.scan.part`` time one part
+file's read and ``dacp.scan.batch`` one batch's build.
 """
 
 from __future__ import annotations
@@ -50,7 +54,7 @@ class ColumnarAdapter(ScanAdapter):
     format = "columnar"
 
     def capabilities(self) -> Capabilities:
-        return Capabilities(part_ranges=True)
+        return Capabilities(column_projection=True, part_ranges=True)
 
     def schema(self) -> Schema:
         with open(os.path.join(self.path, "_schema.json")) as f:
@@ -83,13 +87,15 @@ class ColumnarAdapter(ScanAdapter):
         **_kw,
     ):
         root = self.path
-        schema = self.schema()
+        full = self.schema()
+        schema = full.select(columns) if columns else full
+        wanted = set(schema.names)
         parts = columnar_parts(root)
         if part_range is not None:
             lo, hi = int(part_range[0]), int(part_range[1])
             parts = parts[lo:hi]
         if report is not None:
-            needed = set(report.get("columns_needed", schema.names))
+            needed = set(report.get("columns_needed", full.names))
             for key in ("bytes_read", "bytes_needed", "rows_read"):
                 report.setdefault(key, 0)
 
@@ -106,14 +112,15 @@ class ColumnarAdapter(ScanAdapter):
         def _load(p: str) -> tuple:
             with np.load(os.path.join(root, p), mmap_mode="r") as z:
                 sizes = {i.filename.removesuffix(".npy"): i.file_size for i in z.zip.infolist()}
-                with span("dacp.scan.part", part=p, bytes=sum(sizes.values())):
-                    return {k: z[k] for k in z.files}, sizes
+                members = [k for k in z.files if _member_column(k) in wanted]
+                with span("dacp.scan.part", part=p, bytes=sum(sizes[k] for k in members)):
+                    return {k: z[k] for k in members}, sizes
 
         def _batches(loaded: tuple):
             # runs on the consuming thread, the report's one writer
             arrays, sizes = loaded
             if report is not None:
-                report["bytes_read"] += sum(sizes.values())
+                report["bytes_read"] += sum(sizes[k] for k in arrays)
                 report["bytes_needed"] += sum(n for m, n in sizes.items() if _member_column(m) in needed)
             it = npz_arrays_sdf(arrays, batch_rows).iter_batches()
             while True:

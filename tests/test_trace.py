@@ -174,6 +174,17 @@ def test_traced_run_carries_the_flow_id_on_every_thread(tmp_path, backend):
     assert sum(st["bytes"] for st in parts) == stats.progress()["scan_bytes_read"]
 
 
+def _members(path) -> dict:
+    """Bytes of each part-file member, summed over the dataset's parts."""
+    members = {}
+    for part in sorted(os.listdir(path)):
+        if part.endswith(".npz"):
+            with np.load(os.path.join(path, part)) as z:
+                for info in z.zip.infolist():
+                    members[info.filename] = members.get(info.filename, 0) + info.file_size
+    return members
+
+
 def test_scan_counters_count_what_the_scan_read_and_needed(tmp_path):
     path = _dataset(tmp_path)
     stats = ExecutorStats()
@@ -185,23 +196,25 @@ def test_scan_counters_count_what_the_scan_read_and_needed(tmp_path):
 
     execute_parallel(_agg_dag(), resolver, cfg, stats=stats).collect()
     prog = stats.progress()
-    members = {}
-    for part in sorted(os.listdir(path)):
-        if part.endswith(".npz"):
-            with np.load(os.path.join(path, part)) as z:
-                for info in z.zip.infolist():
-                    members[info.filename] = members.get(info.filename, 0) + info.file_size
+    members = _members(path)
     assert prog["scan_rows"] == PARTS * PART_ROWS
-    assert prog["scan_bytes_read"] == sum(members.values())
-    assert prog["scan_bytes_needed"] == members["k.npy"] + members["x.npy"]
-    assert prog["scan_bytes_read"] > prog["scan_bytes_needed"] > 0
+    # the columnar adapter projects: it reads just the members the plan needs
+    assert prog["scan_bytes_read"] == prog["scan_bytes_needed"] == members["k.npy"] + members["x.npy"]
+    assert 0 < prog["scan_bytes_read"] < sum(members.values())
+
+    # a scan that names no columns still reads, and needs, every member
+    stats = ExecutorStats()
+    rows = sum(b.num_rows for b in scan_path(path, report=stats.scan_report()).iter_batches())
+    prog = stats.progress()
+    assert rows == prog["scan_rows"] == PARTS * PART_ROWS
+    assert prog["scan_bytes_read"] == prog["scan_bytes_needed"] == sum(members.values())
 
 
 def test_scan_counters_reach_ping_and_status(tmp_path):
     from repro.client import LocalNetwork
     from repro.server import FairdServer
 
-    _dataset(tmp_path)
+    members = _members(_dataset(tmp_path))
     net = LocalNetwork()
     srv = FairdServer("h1:3101", executor=ExecutorConfig(num_workers=2, backend="numpy"))
     srv.catalog.register_path("ds", str(tmp_path))
@@ -212,10 +225,11 @@ def test_scan_counters_reach_ping_and_status(tmp_path):
     ex = client.ping()["executor"]
     # a union's two sources add into the one flow's totals
     assert ex["scan_rows"] == 2 * PARTS * PART_ROWS
-    assert ex["scan_bytes_read"] > ex["scan_bytes_needed"] > 0
+    # each side reads just its pruned columns ("k", plus "x" under the filter)
+    assert ex["scan_bytes_read"] == ex["scan_bytes_needed"] == 2 * members["k.npy"] + members["x.npy"]
 
     fl = client.open("dacp://h1:3101/ds/tbl").group_by("k").agg(n="count").start()
     fl.collect()
     st = fl.status()["executor"]
     assert st["scan_rows"] == PARTS * PART_ROWS
-    assert 0 < st["scan_bytes_needed"] < st["scan_bytes_read"]
+    assert st["scan_bytes_read"] == st["scan_bytes_needed"] == members["k.npy"]
