@@ -46,7 +46,11 @@ Dispatchable ops:
                     explicit **f64-accumulating reference path** (host-side
                     — kernel lanes are 32-bit — counted in
                     ``PallasBackend.f64_folds``) instead of silently falling
-                    back; ≤ 256 groups per morsel
+                    back.  Up to 256 groups a morsel the one-hot fold spans
+                    every group; above that the rows are sorted by group
+                    and the fused kernel folds them window by window (256
+                    groups a window), so the device cost grows with rows,
+                    not rows x groups (wide min/max then stay with numpy)
 
 ``get_backend("auto")`` selects pallas only when jax reports a real TPU;
 interpret-mode Pallas on CPU is for correctness tests, not speed.  The
@@ -103,9 +107,25 @@ def register_kernel(backend: str, op: str):
 
 
 class ComputeBackend:
-    """Kernel dispatch facade.  Instances are stateless and thread-safe."""
+    """Kernel dispatch facade.  Instances are thread-safe; their only state
+    is counters.
+
+    ``agg_morsels`` counts the grouped morsels the executor folded with this
+    backend and ``agg_host_s`` the host seconds they spent mapping keys to
+    group ids and merging into the request's state (``GroupState.map_s``
+    and the breaker's merge)."""
 
     name = "numpy"
+
+    def __init__(self):
+        self._counter_lock = threading.Lock()
+        self.agg_morsels = 0
+        self.agg_host_s = 0.0
+
+    def count(self, counter: str, k=1) -> None:
+        """Add ``k`` to a counter, from any thread."""
+        with self._counter_lock:
+            setattr(self, counter, getattr(self, counter) + k)
 
     def kernel(self, op: str) -> Callable:
         impl = KERNELS.get(self.name, {}).get(op)
@@ -252,6 +272,7 @@ class PallasBackend(ComputeBackend):
     tile = 256
 
     def __init__(self):
+        super().__init__()
         self._kernel_mod = None
         self._lock = threading.Lock()
         self.kernel_calls = 0  # observability: kernel dispatch count
@@ -263,6 +284,9 @@ class PallasBackend(ComputeBackend):
         # (host-side; the kernels' 32-bit lanes cannot hold f64) — the
         # explicit, counted successor of the old silent fallback
         self.f64_folds = 0
+        # grouped morsels whose float key held a NaN or -0.0, folded by the
+        # per-op path instead of the fused one
+        self.key_rejects = 0
 
     def _ops(self):
         """Import the jit'd kernel wrappers once and place the compile
@@ -693,7 +717,7 @@ def _wide_decode(hi: np.ndarray, lo_s: np.ndarray) -> np.ndarray:
 @register_kernel("pallas", "segment_reduce")
 def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
     kernel_ops = bk._ops()
-    if ngroups == 0 or ngroups > _SEG_GROUP_CAP or n_rows > kernel_ops.SUM_ROW_CAP or n_rows == 0:
+    if ngroups == 0 or n_rows > kernel_ops.SUM_ROW_CAP or n_rows == 0:
         return {}
     sums: list = []  # (state name, values)
     fsums: list = []  # (state name, f64 values) — host f64 reference path
@@ -718,6 +742,11 @@ def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
                     wides.append((name, fn, wide[0], wide[1]))
     if not (sums or count_names or mms["f32"] or mms["i32"] or wides or fsums):
         return {}
+    if ngroups > _SEG_GROUP_CAP:
+        out = _windowed_segment_reduce(bk, kernel_ops, np.asarray(gidx, np.int64)[:n_rows], ngroups, sums, count_names, mms)
+        if fsums:
+            out.update(_f64_fold(bk, gidx, ngroups, fsums))
+        return out
     tile = bk.tile
     n_pad = -(-n_rows // tile) * tile
     g_pad = -(-ngroups // 8) * 8
@@ -778,17 +807,92 @@ def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
             keys64 = _wide_decode(h_res[:ngroups, j], np.ascontiguousarray(l_res[:ngroups, j]))
             out[name] = decode(keys64, fn)
         kernel_used = True
+    if kernel_used:
+        bk.kernel_calls += 1
+    out.update(_f64_fold(bk, gidx, ngroups, fsums))
+    return out
+
+
+def _f64_fold(bk: PallasBackend, gidx, ngroups: int, fsums: list) -> dict:
+    """The f64-accumulating reference path of float sums: bit-identical to
+    the numpy scatter because a fresh state's accumulators start at +0.0
+    and np.add.at adds this morsel's values in the same row order."""
+    out = {}
     for name, values in fsums:
-        # f64-accumulating reference path: bit-identical to the numpy
-        # scatter because a fresh state's accumulators start at +0.0 and
-        # np.add.at adds this morsel's values in the same row order
         acc = np.zeros(ngroups, np.float64)
         np.add.at(acc, np.asarray(gidx, np.int64), np.asarray(values, np.float64))
         out[name] = acc
-    if kernel_used:
-        bk.kernel_calls += 1
     if fsums:
         bk.f64_folds += len(fsums)
+    return out
+
+
+def _windowed_segment_reduce(bk: PallasBackend, kernel_ops, gidx, ngroups: int, sums, count_names, mms) -> dict:
+    """Counts, integer sums and int32 / float32 min/max of more than
+    ``_SEG_GROUP_CAP`` groups: the rows sorted by group, through the fused
+    kernel's windowed fold with no filter (wide min/max stay with numpy).
+    Groups without rows here get the identities (0, sentinels)."""
+    if not (sums or count_names or mms["f32"] or mms["i32"]):
+        return {}
+    tile = bk.tile
+    n = gidx.size
+    n_pad = -(-n // tile) * tile
+    present, local = np.unique(gidx, return_inverse=True)
+    order = np.argsort(local, kind="stable")
+    g32 = np.zeros(n_pad, np.int32)
+    g32[:n] = local[order]
+    steps, live = _window_steps(g32[:n], n_pad, tile)
+    limb = np.zeros((n_pad, max(1, _SUM_LIMBS * len(sums))), np.int32)
+    for i, (_name, values) in enumerate(sums):
+        for k, plane in enumerate(_sum_limbs(values[order])):
+            limb[:n, _SUM_LIMBS * i + k] = plane
+    tables = {}
+    for kind, dt in (("f32", np.float32), ("i32", np.int32)):
+        tables[kind] = np.zeros((n_pad, max(1, len(mms[kind]))), dt)
+        for j, (_name, _fn, col) in enumerate(mms[kind]):
+            tables[kind][:n, j] = col[order]
+    fns = {kind: tuple(fn for _n, fn, _c in mms[kind]) or ("min",) for kind in tables}
+    dummy = np.zeros((n_pad, 1), np.int32)
+    res = kernel_ops.fused_chain_tiles(
+        np.asarray([n, 0, 0, live], np.int32),
+        dummy,
+        g32,
+        dummy,
+        limb,
+        tables["f32"],
+        tables["i32"],
+        dummy.astype(np.float32),
+        dummy,
+        steps,
+        op="gt",
+        kind="none",
+        descrs_f=(),
+        descrs_i=(),
+        csums=(),
+        fns_f=fns["f32"],
+        fns_i=fns["i32"],
+        with_gidx=False,
+        segmented=True,
+        ngroups=tile,
+        tile=tile,
+    )
+    bk.kernel_calls += 1
+    _ctab, _counts, gsum, gcnt, gmmf, gmmi, _gfirst = [np.asarray(r)[: present.size] for r in res]
+    out: dict = {}
+    for i, (name, _values) in enumerate(sums):
+        out[name] = np.zeros(ngroups, np.int64)
+        out[name][present] = _limbs_to_int64(gsum[:, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)])
+    for name in count_names:
+        out[name] = np.zeros(ngroups, np.int64)
+        out[name][present] = gcnt
+    for kind, got in (("f32", gmmf), ("i32", gmmi)):
+        for j, (name, fn, _col) in enumerate(mms[kind]):
+            if kind == "f32":
+                ident = np.float32(np.inf if fn == "min" else -np.inf)
+            else:
+                ident = np.int32(2**31 - 1 if fn == "min" else -(2**31))
+            out[name] = np.full(ngroups, ident, got.dtype)
+            out[name][present] = got[:, j]
     return out
 
 
@@ -801,7 +905,9 @@ def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
 # path for that morsel only.
 FUSED_INELIGIBLE = object()
 
-_FLOAT_NAMES = {"float16", "float32", "float64"}
+# float keys the fused fold does not take: its NaN / -0.0 check per morsel
+# (see ``FusedChainPlan.fold``) is written for float32
+_WIDE_FLOAT_KEYS = {"float16", "float64"}
 
 
 def _lit_value(v, group: str):
@@ -905,9 +1011,11 @@ def plan_fused_chain(specs: list, in_schema, agg=None, backend=None):
     partial aggregate in the same launch: counts, integer sums (8-bit-limb
     passthrough / 4-limb in-kernel for computed int32), f32 + narrow-int
     min/max, and float sums via compacted planes + the host's f64 fold.
-    Float-keyed aggregates are ineligible (the pre-filter factorization
-    could pick a different -0.0/NaN representative than the reference's
-    post-filter one); wide min/max and var-width outputs are ineligible.
+    float32 keys are eligible: a morsel whose float key column holds a NaN
+    or a ``-0.0`` (where the pre-filter factorization could pick another
+    representative than the reference's post-filter one) runs the per-op
+    path instead, counted in ``PallasBackend.key_rejects``.  float64 keys,
+    wide min/max and var-width outputs are ineligible.
     """
     if backend is None or getattr(backend, "name", None) != "pallas":
         return None
@@ -1011,7 +1119,7 @@ def plan_fused_chain(specs: list, in_schema, agg=None, backend=None):
             m = mapping.get(k)
             if m is None or m[0] != "src":
                 return None
-            if in_schema.field(m[1]).dtype.name in _FLOAT_NAMES:
+            if in_schema.field(m[1]).dtype.name in _WIDE_FLOAT_KEYS:
                 return None
             key_srcs.append((k, m[1]))
 
@@ -1259,42 +1367,49 @@ class FusedChainPlan:
             return len(self._staged)
 
     # -- host-side encode / decode -------------------------------------------
-    def _encode_inline(self, batch: RecordBatch) -> dict:
+    def _encode_inline(self, batch: RecordBatch, order: np.ndarray | None = None) -> dict:
         """Encode an unstaged morsel on the worker that runs it."""
         with span("dacp.morsel.encode", rows=batch.num_rows, staged=False):
-            return self._encode(batch)
+            return self._encode(batch, order)
 
-    def _encode(self, batch: RecordBatch) -> dict:
+    def _encode(self, batch: RecordBatch, order: np.ndarray | None = None) -> dict:
+        """The morsel's kernel input tables; ``order`` lays the rows out in
+        that order (a windowed fold's rows sorted by group id)."""
         n = batch.num_rows
         n_pad = self._pad(n)
         sch = batch.schema
+
+        def values(name):
+            v = np.asarray(batch.column(name).values)
+            return v if order is None else v[order]
+
         if self._kind == "none":
             pred = np.zeros((n_pad, 1), np.int32)
         else:
-            planes = _col_planes(batch.column(self._pred_src).values, sch.field(self._pred_src).dtype.name)
+            planes = _col_planes(values(self._pred_src), sch.field(self._pred_src).dtype.name)
             pred = np.zeros((n_pad, len(planes)), np.int32)
             for j, p in enumerate(planes):
                 pred[:n, j] = p
         pass_tbl = np.zeros((n_pad, self._dp), np.int32)
         for s, dtype, start, _k in self._pass_fields:
-            for j, p in enumerate(_col_planes(batch.column(s).values, dtype.name)):
+            for j, p in enumerate(_col_planes(values(s), dtype.name)):
                 pass_tbl[:n, start + j] = p
         limb = np.zeros((n_pad, self._limb_base), np.int32)
         for i, (_state, s) in enumerate(self._limb_srcs):
-            for k, plane in enumerate(_sum_limbs(np.asarray(batch.column(s).values))):
+            for k, plane in enumerate(_sum_limbs(values(s))):
                 limb[:n, _SUM_LIMBS * i + k] = plane
         mmf = np.zeros((n_pad, max(1, len(self._mmf))), np.float32)
         for j, (_state, _fn, s) in enumerate(self._mmf):
-            mmf[:n, j] = batch.column(s).values
+            mmf[:n, j] = values(s)
         mmi = np.zeros((n_pad, max(1, len(self._mmi))), np.int32)
         for j, (_state, _fn, s) in enumerate(self._mmi):
-            mmi[:n, j] = np.asarray(batch.column(s).values).astype(np.int32)
+            mmi[:n, j] = values(s).astype(np.int32)
         af = np.zeros((n_pad, max(1, len(self._af_cols))), np.float32)
         for j, s in enumerate(self._af_cols):
-            af[:n, j] = batch.column(s).values
+            af[:n, j] = values(s)
         ai = np.zeros((n_pad, max(1, len(self._ai_cols))), np.int32)
         for j, s in enumerate(self._ai_cols):
-            ai[:n, j] = batch.column(s).values
+            ai[:n, j] = values(s)
         return {"pred": pred, "pass": pass_tbl, "limb": limb, "mmf": mmf, "mmi": mmi, "af": af, "ai": ai}
 
     def _compact(self, ctab: np.ndarray, counts: np.ndarray) -> np.ndarray:
@@ -1320,12 +1435,12 @@ class FusedChainPlan:
         col = np.ascontiguousarray(compact[:, off])
         return col.view(np.float32) if tag == "f32" else col
 
-    def _launch(self, arrs: dict, gidx: np.ndarray, n: int, segmented: bool, ngroups: int):
-        scalars = np.asarray([n, self._t_hi, self._t_lo, 0], np.int32)
+    def _launch(self, arrs: dict, gidx: np.ndarray, n: int, segmented: bool, ngroups: int, steps=None, live: int = 0):
+        scalars = np.asarray([n, self._t_hi, self._t_lo, live], np.int32)
         if self._dev is not None:  # unstaged morsels too run on the pinned chip
             import jax
 
-            scalars, arrs, gidx = jax.device_put((scalars, arrs, gidx), self._dev)
+            scalars, arrs, gidx, steps = jax.device_put((scalars, arrs, gidx, steps), self._dev)
         return self._kernel_ops.fused_chain_tiles(
             scalars,
             arrs["pred"],
@@ -1336,6 +1451,7 @@ class FusedChainPlan:
             arrs["mmi"],
             arrs["af"],
             arrs["ai"],
+            steps,
             op=self._op,
             kind=self._kind,
             descrs_f=self._descrs_f,
@@ -1383,19 +1499,25 @@ class FusedChainPlan:
         filtered morsel, None (no surviving rows), or ``FUSED_INELIGIBLE``.
         Group ids come from factorizing the PRE-filter morsel; the kernel's
         per-group minimum surviving row index reorders the survivors into
-        first-seen-filtered order, matching the reference interning."""
+        first-seen-filtered order, matching the reference interning.  Up to
+        ``_SEG_GROUP_CAP`` groups the one-hot spans every group; above it
+        the fold is windowed (:func:`_window_steps`), its rows laid out in
+        group-id order."""
         staged = self._take_staged(batch)
         if not self._morsel_ok(batch):
             return FUSED_INELIGIBLE
         for _state, _fn, s in self._mmf:
             if not _f32_mm_ok(batch.column(s).values):
                 return FUSED_INELIGIBLE
-        from repro.core.operators import GroupState
+        from repro.core.operators import GroupState, _odd_float
         from repro.core.schema import Field, Schema
 
         keys = [k for k, _s in self._key_srcs]
         n = batch.num_rows
         with span("dacp.morsel.factorize", rows=n):
+            if any(_odd_float(batch.column(s).values) for _k, s in self._key_srcs):
+                self._bk.count("key_rejects")
+                return FUSED_INELIGIBLE
             if all(k == s for k, s in self._key_srcs):
                 kb = batch
             else:
@@ -1403,19 +1525,33 @@ class FusedChainPlan:
                 kb = RecordBatch(Schema(fields), [batch.column(s) for _k, s in self._key_srcs])
             tmp = GroupState(keys, {}, self._mode, kb.schema, vectorized=True)
             gidx_full = tmp._factorize(kb)
-        ng = len(tmp.gids)
-        if ng == 0 or ng > _SEG_GROUP_CAP:
+        ng = tmp.ngroups
+        if ng == 0:
             return FUSED_INELIGIBLE
-        g_pad = max(8, -(-ng // 8) * 8)
-        arrs = staged if staged is not None else self._encode_inline(batch)
+        order = steps = None
+        live = 0
         g32 = np.zeros(self._pad(n), np.int32)
-        g32[:n] = gidx_full
+        if ng <= _SEG_GROUP_CAP:
+            g_pad = max(8, -(-ng // 8) * 8)
+            g32[:n] = gidx_full
+        else:
+            g_pad = self._tile
+            if (np.diff(gidx_full) < 0).any():
+                order = np.argsort(gidx_full, kind="stable")
+                g32[:n] = gidx_full[order]
+            else:
+                g32[:n] = gidx_full
+            steps, live = _window_steps(g32[:n], self._pad(n), self._tile)
+        if staged is None or order is not None:
+            arrs = self._encode_inline(batch, order)
+        else:
+            arrs = staged
         with span("dacp.morsel.launch", rows=n):
-            out = self._launch(arrs, g32, n, segmented=True, ngroups=g_pad)
+            out = self._launch(arrs, g32, n, segmented=True, ngroups=g_pad, steps=steps, live=live)
         with span("dacp.morsel.sync"):
             ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst = [np.asarray(o) for o in out]
         with span("dacp.morsel.fold"):
-            self._count_launch(out, staged is not None)
+            self._count_launch(out, staged is not None and order is None)
             compact = self._compact(ctab, counts) if self._fsums or self._flag_off is not None else None
             if compact is not None and self._left_envelope(compact):
                 return FUSED_INELIGIBLE
@@ -1423,17 +1559,20 @@ class FusedChainPlan:
             alive = np.flatnonzero(gcnt_v > 0)
             if alive.size == 0:
                 return None
-            perm = alive[np.argsort(gfirst[:ng][alive], kind="stable")]
+            first = gfirst[:ng][alive]
+            if order is not None:
+                first = order[first]  # rows of the sorted layout back to the morsel's rows
+            perm = alive[np.argsort(first, kind="stable")]
             st = GroupState(
                 self._agg_keys, self._aggs, self._mode, self._agg_schema, vectorized=True, backend=self._bk
             )
-            st.key_rows = [tmp.key_rows[g] for g in perm]
-            st.gids = {kt: i for i, kt in enumerate(st.key_rows)}
+            st.set_keys(tmp, perm)
+            st.map_s = tmp.map_s
             acc: dict = {}
             for state in self._gcnt_states:
                 acc[state] = gcnt_v[perm].astype(np.int64)
             for i, (state, _s) in enumerate(self._limb_srcs):
-                acc[state] = _limbs_to_int64(gsum[:, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)][perm])
+                acc[state] = _limbs_to_int64(gsum[perm, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)])
             base = self._limb_base
             for j, (state, _idx) in enumerate(self._csum_states):
                 s4 = gsum[perm, base + 4 * j : base + 4 * (j + 1)].astype(np.int64)
@@ -1443,15 +1582,39 @@ class FusedChainPlan:
             for j, (state, _fn, _s) in enumerate(self._mmi):
                 acc[state] = gmmi[perm, j].astype(np.int64)
             if self._fsums:
+                # row order within each group, from +0.0: np.add.at's sums, bit for bit
                 g_sel = compact[:, self._gidx_off]
                 for state, ref in self._fsums:
                     vals = np.asarray(self._decode_ref(compact, ref), np.float64)
-                    accf = np.zeros(ng, np.float64)
-                    np.add.at(accf, g_sel, vals)
-                    acc[state] = accf[perm]
+                    acc[state] = np.bincount(g_sel, weights=vals, minlength=ng)[perm]
             for name, (_init, dt) in st._state_specs().items():
                 st.acc[name] = np.ascontiguousarray(np.asarray(acc[name], dt))
             return st
+
+
+def _window_steps(gidx: np.ndarray, n_pad: int, tile: int) -> tuple:
+    """The step table of a windowed fold over rows sorted by group id:
+    ``(steps, live)``, ``steps`` the row tile then the group window (of
+    ``tile`` groups) of each of ``2 * n_pad / tile`` steps, ordered by
+    window and then tile, ``live`` how many are real (the rest repeat the
+    last).  Every group id below the largest has a row, so the rows of one
+    tile span at most ``tile`` consecutive ids: two windows at most, and a
+    tile in two windows is folded once into each, at consecutive steps."""
+    n = gidx.size
+    nt = n_pad // tile
+    lo = gidx[0:n:tile] // tile
+    hi = gidx[np.minimum(np.arange(1, nt + 1) * tile, n) - 1] // tile
+    two = np.flatnonzero(hi > lo)
+    t = np.concatenate([np.arange(nt), two])
+    w = np.concatenate([lo, hi[two]])
+    o = np.lexsort((t, w))
+    live = o.size
+    steps = np.empty(4 * nt, np.int32)
+    steps[:live] = t[o]
+    steps[live : 2 * nt] = t[o[-1]]
+    steps[2 * nt : 2 * nt + live] = w[o]
+    steps[2 * nt + live :] = w[o[-1]]
+    return steps, live
 
 
 def resolve_device(index: int):

@@ -329,9 +329,11 @@ class ExecutorStats:
 
     ``scans`` holds one scan ``report`` per source the run opens (see
     ``ScanAdapter.scan``); ``progress()`` sums them into ``scan_bytes_read``,
-    ``scan_bytes_needed`` and ``scan_rows``."""
+    ``scan_bytes_needed`` and ``scan_rows``.  ``groups`` counts the rows
+    (groups) the run's aggregates produced."""
 
     pipelines: list = field(default_factory=list)
+    groups: int = 0  # rows the run's aggregates produced
     accountant: MemoryAccountant | None = None
     live: list = field(default_factory=list)
     scans: list = field(default_factory=list)
@@ -390,6 +392,7 @@ class ExecutorStats:
             "transfers_overlapped": sum(p.get("transfers_overlapped", 0) for p in done + running),
             "micromorsels_coalesced": sum(p.get("micromorsels_coalesced", 0) for p in done + running),
             "device_launches": per_device,
+            "groups": self.groups,
             "scan_bytes_read": sum(r.get("bytes_read", 0) for r in self.scans),
             "scan_bytes_needed": sum(r.get("bytes_needed", 0) for r in self.scans),
             "scan_rows": sum(r.get("rows_read", 0) for r in self.scans),
@@ -990,12 +993,16 @@ class _Compiler:
             reserved = 0
             try:
                 for st in _run_ordered(branches, cfg, backend, fold, stats, cancel, agg=(keys, aggs, mode, in_schema)):
+                    t0 = time.perf_counter()
                     if spiller is not None:
                         spiller.spill_state(st)
-                        continue
-                    with span("dacp.merge"):
-                        total.merge(st)
-                    if spillable:
+                    else:
+                        with span("dacp.merge"):
+                            total.merge(st)
+                    if keys:
+                        backend.count("agg_morsels")
+                        backend.count("agg_host_s", st.map_s + time.perf_counter() - t0)
+                    if spiller is None and spillable:
                         nb = total.approx_nbytes()
                         acct.adjust(nb - reserved)
                         reserved = nb
@@ -1016,10 +1023,10 @@ class _Compiler:
                             total = None
                             acct.adjust(-reserved)
                             reserved = 0
-                if spiller is None:
-                    yield total.result(out_schema)
-                else:
-                    yield spiller.result()
+                out = total.result(out_schema) if spiller is None else spiller.result()
+                if stats is not None:
+                    stats.groups += out.num_rows
+                yield out
             finally:
                 acct.adjust(-reserved)
                 if spiller is not None:

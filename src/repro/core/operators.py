@@ -23,6 +23,7 @@ and writes so the pushdown optimizer can reorder filters around it.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Callable, Iterator
 
@@ -35,6 +36,7 @@ from repro.core.errors import PlanError, SchemaError
 from repro.core.expr import Expr
 from repro.core.schema import Field, Schema
 from repro.core.sdf import StreamingDataFrame
+from repro.core.trace import span
 
 __all__ = [
     "MapFn",
@@ -356,14 +358,79 @@ def _agg_src(out: str, spec: dict, mode: str) -> str:
     return spec.get("column")
 
 
+def _odd_float(a: np.ndarray) -> bool:
+    """Whether a float key column holds a NaN or a ``-0.0``: keys whose bit
+    images do not follow Python equality (NaN is unequal to itself, ``-0.0``
+    equals ``+0.0``), so they group through the reference row loop."""
+    return a is not None and a.dtype.kind == "f" and bool(np.isnan(a).any() or ((a == 0) & np.signbit(a)).any())
+
+
+def _key_image(arrs: list, n: int) -> np.ndarray:
+    """One comparable value per row for a set of fixed-width key columns:
+    their bit patterns side by side (signed integers with the sign bit
+    flipped), as one uint64 when they fit in 8 bytes, else as one
+    fixed-size void.  Equal images are equal keys whenever no key is a NaN
+    or ``-0.0`` (:func:`_odd_float`)."""
+    widths = [a.dtype.itemsize for a in arrs]
+    if sum(widths) <= 8:
+        img = np.zeros(n, np.uint64)
+        for a, w in zip(arrs, widths):
+            u = a.view(f"u{w}").astype(np.uint64)
+            if a.dtype.kind == "i":  # sign bit flipped: small signed ranges stay contiguous
+                u ^= np.uint64(1 << (8 * w - 1))
+            if w < 8:
+                img <<= np.uint64(8 * w)
+                img |= u
+            else:
+                img = u  # the only key: nothing to shift
+        return img
+    buf = np.empty((n, sum(widths)), np.uint8)
+    pos = 0
+    for a, w in zip(arrs, widths):
+        buf[:, pos : pos + w] = a.view(np.uint8).reshape(n, w)
+        pos += w
+    return buf.view(f"V{pos}").reshape(n)
+
+
+def _first_seen(img: np.ndarray) -> tuple:
+    """``(first, inv)``: the row of each distinct image's first occurrence,
+    in first-seen order, and each row's index into it.  Small integer
+    ranges take a sort-free first-occurrence table; others ``np.unique``."""
+    n = img.size
+    if img.dtype == np.uint64 and n:
+        lo = img.min()
+        span = int(img.max() - lo) + 1
+        if span <= max(1024, 4 * n):
+            off = (img - lo).astype(np.intp)
+            first_of = np.full(span, n, np.intp)
+            first_of[off[::-1]] = np.arange(n - 1, -1, -1)  # the smallest row wins
+            present = np.flatnonzero(first_of < n)
+            order = np.argsort(first_of[present], kind="stable")
+            lut = np.empty(span, np.intp)
+            lut[present[order]] = np.arange(order.size)
+            return first_of[present[order]], lut[off]
+    _uniq, first, inv = np.unique(img, return_index=True, return_inverse=True)
+    order = np.argsort(first, kind="stable")  # np.unique sorts; rank by first occurrence
+    rank = np.empty(order.size, np.intp)
+    rank[order] = np.arange(order.size)
+    return first[order], rank[inv.reshape(-1)]
+
+
 class GroupState:
     """Incremental hash-aggregation state across batches (streaming: the
     input is consumed batch-by-batch, never concatenated).
 
-    ``vectorized=True`` (the parallel executor's mode) factorizes fixed-width
-    key columns with ``np.unique`` — the python loop shrinks from per-row to
-    per-distinct-group-per-batch.  Var-width keys keep the reference row loop
-    so first-seen group order is preserved for string keys either way.
+    ``vectorized=True`` (the parallel executor's mode) keeps fixed-width
+    keys as **columns**, one array per key in group order, and maps a
+    batch's keys to group ids with vectorized numpy over the keys' bit
+    images (:func:`_key_image`): distinct keys in first-seen row order, then
+    a binary search in the table's sorted images; only new groups are
+    appended, and nothing runs per group in Python.  The reference is the
+    row loop over key tuples (a dict from tuple to group id), which the
+    state switches to for good when a batch's keys have a validity mask or a
+    float key holds a NaN or ``-0.0``; var-width keys and
+    ``vectorized=False`` use it from the start.  Either way groups come out
+    in first-seen row order.
 
     Partial states combine with ``merge`` — the morsel driver builds one
     state per morsel and merges them in morsel order, so the grouped output
@@ -374,6 +441,9 @@ class GroupState:
     eligible aggregates (counts, integer sums, int32/finite-f32 min/max)
     fold on the accelerator, the rest scatter with numpy — bit-identical
     either way, so a ``None`` backend is the reference semantics.
+
+    ``map_s`` is the host time spent mapping keys to group ids (span
+    ``dacp.agg.map``).
     """
 
     def __init__(
@@ -391,8 +461,16 @@ class GroupState:
         self.in_schema = in_schema
         self.backend = backend
         self.vectorized = vectorized and all(not in_schema.field(k).dtype.is_varwidth for k in keys)
-        self.gids: dict = {}  # key tuple -> group id
-        self.key_rows: list = []  # representative key values per group
+        self.ngroups = 0
+        self.map_s = 0.0
+        # columnar keys: one array per key in group order, their images, and
+        # a lazily built (sorted images, group ids) lookup index
+        self._kcols = [np.zeros(0, in_schema.field(k).dtype.np_dtype) for k in keys] if self.vectorized else None
+        self._img = None
+        self._index = None
+        # the row loop's keys: key tuple -> group id, and tuples in group order
+        self._gids = None if self.vectorized else {}
+        self._rows = None if self.vectorized else []
         # state name -> numpy accumulator (grown as groups appear)
         self.acc: dict = {name: np.zeros(0, dt) for name, (_, dt) in self._state_specs().items()}
 
@@ -427,82 +505,130 @@ class GroupState:
                     specs[out] = (init, np.float64)
         return specs
 
-    def _intern_groups(self, key_tuples) -> np.ndarray:
-        """Map key tuples to (new or existing) group ids."""
+    # -- keys -----------------------------------------------------------------
+    @property
+    def key_rows(self) -> list:
+        """Key tuples in group order (``None`` for a null key)."""
+        if self._rows is not None:
+            return list(self._rows)
+        if not self.keys:
+            return [()] * self.ngroups
+        return list(zip(*[c.tolist() for c in self._kcols]))
+
+    def key_columns(self) -> list:
+        """The key columns in group order, one ``Column`` per key."""
+        fields = [self.in_schema.field(k) for k in self.keys]
+        if self._rows is None:
+            return [Column(f.dtype, values=np.ascontiguousarray(c)) for f, c in zip(fields, self._kcols)]
+        return [self._key_column(f, [row[i] for row in self._rows]) for i, f in enumerate(fields)]
+
+    def set_keys(self, other: "GroupState", take: np.ndarray) -> None:
+        """Make this (empty) state's groups ``other``'s groups ``take``, in
+        that order."""
+        if other._rows is None and self._rows is None:
+            self._kcols = [c[take] for c in other._kcols]
+            self._img = None if other._img is None else other._img[take]
+        else:
+            self._to_rows()
+            rows = other.key_rows
+            self._rows = [rows[g] for g in take.tolist()]
+            self._gids = {kt: i for i, kt in enumerate(self._rows)}
+        self.ngroups = len(take)
+
+    def load_keys(self, cols: list) -> None:
+        """Make this (empty) state's groups the rows of the key columns
+        ``cols``: distinct keys, in group order."""
+        n = len(cols[0]) if cols else 0
+        if self._rows is None and all(c.validity is None for c in cols) and not any(_odd_float(c.values) for c in cols):
+            self._kcols = [np.ascontiguousarray(c.values) for c in cols]
+        else:
+            self._to_rows()
+            self._rows = list(zip(*[c.to_pylist() for c in cols]))
+            self._gids = {kt: i for i, kt in enumerate(self._rows)}
+        self.ngroups = n
+
+    def _images(self) -> np.ndarray:
+        if self._img is None:
+            self._img = _key_image(self._kcols, self.ngroups)
+        return self._img
+
+    def _to_rows(self) -> None:
+        """Switch to the row loop for good (its dict is the reference)."""
+        if self._rows is None:
+            self._rows = self.key_rows
+            self._gids = {kt: i for i, kt in enumerate(self._rows)}
+            self._kcols = self._img = self._index = None
+
+    def _intern_rows(self, key_tuples) -> np.ndarray:
+        """Row loop: map key tuples to (new or existing) group ids."""
         out = np.empty(len(key_tuples), dtype=np.int64)
-        gids = self.gids
+        gids = self._gids
         for i, kt in enumerate(key_tuples):
             g = gids.get(kt)
             if g is None:
                 g = len(gids)
                 gids[kt] = g
-                self.key_rows.append(kt)
+                self._rows.append(kt)
             out[i] = g
+        self.ngroups = len(self._rows)
         return out
 
-    def _factorize_dense(self, a: np.ndarray):
-        """Sort-free factorization for a single integer key over a small
-        value range: one scatter builds a first-occurrence LUT instead of
-        ``np.unique``'s full-array argsort (the hot path of the aggregate
-        fold).  Returns per-row group ids, or None when ineligible."""
-        if a.dtype.kind not in "iu" or len(a) == 0:
-            return None
-        mn, mx = int(a.min()), int(a.max())
-        span = mx - mn + 1
-        if span > max(1024, 4 * len(a)):
-            return None  # LUT would dwarf the batch; np.unique wins
-        if a.dtype.kind == "u":
-            # native unsigned subtract is exact (every value >= mn) and keeps
-            # uint64 keys above 2^63 out of lossy int64 territory
-            off = (a - mn).astype(np.int64) if mn else a.astype(np.int64)
+    def _map(self, arrs: list, img: np.ndarray, distinct: bool) -> np.ndarray:
+        """Columnar keys → group ids, appending new groups in first-seen
+        order.  ``distinct``: every row's key differs (a partial state's)."""
+        n, g = img.size, self.ngroups
+        if n == g and np.array_equal(img, self._images()):
+            return np.arange(n)  # the table's own keys, in its order
+        if distinct:
+            first, inv = np.arange(n), None
         else:
-            # widen BEFORE subtracting: narrow signed dtypes (int8 keys
-            # spanning -100..100) would wrap in native arithmetic
-            off = a.astype(np.int64) - mn
-        first = np.full(span, -1, np.int64)
-        first[off[::-1]] = np.arange(len(a) - 1, -1, -1, dtype=np.int64)
-        vals_off = np.flatnonzero(first >= 0)
-        order = np.argsort(first[vals_off], kind="stable")  # first-seen rank
-        rank = np.empty(len(order), np.int64)
-        rank[order] = np.arange(len(order))
-        lut = np.empty(span, np.int64)
-        lut[vals_off] = rank
-        uniq_keys = [(int(v) + mn,) for v in vals_off[order].tolist()]
-        return self._intern_groups(uniq_keys)[lut[off]]
+            first, inv = _first_seen(img)
+        seen = img[first]
+        if g == 0:
+            ids = np.arange(seen.size)
+            new = ids
+        else:
+            if self._index is None:
+                order = np.argsort(self._images(), kind="stable")
+                self._index = (self._img[order], order)
+            sorted_img, sorted_gid = self._index
+            pos = np.minimum(np.searchsorted(sorted_img, seen), g - 1)
+            hit = sorted_img[pos] == seen
+            ids = np.where(hit, sorted_gid[pos], -1)
+            new = np.flatnonzero(~hit)
+            ids[new] = np.arange(g, g + new.size)
+        if new.size:
+            rows = first[new]
+            self._img = np.concatenate([self._images(), seen[new]])
+            self._kcols = [np.concatenate([c, a[rows]]) for c, a in zip(self._kcols, arrs)]
+            self._index = None
+            self.ngroups = g + new.size
+        return ids if inv is None else ids[inv]
 
     def _factorize(self, batch: RecordBatch) -> np.ndarray:
-        """Per-row group ids for one batch.  The vectorized path matches the
-        reference row loop exactly: new groups intern in first-seen row
-        order, and any validity mask on a key column falls back to the row
-        loop (null keys must stay distinct from the sentinel value)."""
-        key_cols = [batch.column(k) for k in self.keys]
-        if self.vectorized and all(c.validity is None for c in key_cols):
-            arrs = [np.ascontiguousarray(c.values) for c in key_cols]
-            if len(arrs) == 1:
-                dense = self._factorize_dense(arrs[0])
-                if dense is not None:
-                    return dense
-                uniq, first_idx, inv = np.unique(arrs[0], return_index=True, return_inverse=True)
-            else:
-                comb = np.empty(batch.num_rows, dtype=[(f"k{i}", a.dtype) for i, a in enumerate(arrs)])
-                for i, a in enumerate(arrs):
-                    comb[f"k{i}"] = a
-                uniq, first_idx, inv = np.unique(comb, return_index=True, return_inverse=True)
-            # np.unique sorts; re-rank uniques by first occurrence so group
-            # ids come out in first-seen row order (reference parity)
-            order = np.argsort(first_idx, kind="stable")
-            rank = np.empty(len(order), np.int64)
-            rank[order] = np.arange(len(order))
-            uniq = uniq[order]
-            uniq_keys = [(v,) for v in uniq.tolist()] if len(arrs) == 1 else [tuple(v) for v in uniq.tolist()]
-            return self._intern_groups(uniq_keys)[rank[inv.reshape(-1)]]
-        # reference path: factorize the key tuple per row
-        key_lists = [c.to_pylist() for c in key_cols]
-        return self._intern_groups(list(zip(*key_lists)))
+        """Per-row group ids for one batch, new groups interned in
+        first-seen row order.  Key columns with a validity mask (null keys
+        must stay distinct from the sentinel value) or a NaN / ``-0.0`` float
+        key send the state to the row loop."""
+        t0 = time.perf_counter()
+        n = batch.num_rows
+        with span("dacp.agg.map", rows=n):
+            key_cols = [batch.column(k) for k in self.keys]
+            gidx = None
+            if self._rows is None and all(c.validity is None for c in key_cols):
+                arrs = [np.ascontiguousarray(c.values) for c in key_cols]
+                if not any(_odd_float(a) for a in arrs):
+                    gidx = self._map(arrs, _key_image(arrs, n), distinct=False)
+            if gidx is None:
+                self._to_rows()
+                key_lists = [c.to_pylist() for c in key_cols]
+                gidx = self._intern_rows(list(zip(*key_lists)) if key_cols else [()] * n)
+        self.map_s += time.perf_counter() - t0
+        return gidx
 
     def _grow(self) -> None:
         """Grow every accumulator to the current group count in one shot."""
-        ngroups = len(self.gids)
+        ngroups = self.ngroups
         for name, (init, dt) in self._state_specs().items():
             cur = self.acc[name]
             if len(cur) < ngroups:
@@ -551,10 +677,10 @@ class GroupState:
         n = batch.num_rows
         if n == 0:
             return
-        fresh = not self.gids
+        fresh = self.ngroups == 0
         gidx = self._factorize(batch)
         self._grow()
-        ngroups = len(self.gids)
+        ngroups = self.ngroups
         kres: dict = {}
         if self.backend is not None:
             kres = self.backend.segment_reduce(gidx, ngroups, self._kernel_specs(batch, fresh), n) or {}
@@ -622,20 +748,34 @@ class GroupState:
     def merge_indexed(self, other: "GroupState") -> np.ndarray:
         """``merge``, returning the group index of each of ``other``'s groups
         in this state (the spill path maps per-group metadata through it)."""
-        m = len(other.key_rows)
+        m = other.ngroups
         if m == 0:
             return np.zeros(0, np.int64)
-        idx = self._intern_groups(other.key_rows)
+        t0 = time.perf_counter()
+        with span("dacp.agg.map", rows=m):
+            if self._rows is None and other._rows is None:
+                if self.ngroups == 0:
+                    self.set_keys(other, np.arange(m))
+                    idx = np.arange(m)
+                else:
+                    idx = self._map(other._kcols, other._images(), distinct=True)
+            else:
+                self._to_rows()
+                idx = self._intern_rows(other.key_rows)
+        self.map_s += time.perf_counter() - t0
         self._grow()
+        # groups in the same order (the common case: partials of one grid)
+        # combine through a slice, in place
+        at = slice(0, m) if m == self.ngroups and np.array_equal(idx, np.arange(m)) else idx
         for out, spec in self.aggs.items():
             fn = spec["fn"]
             if fn == "mean":
                 for part in (f"{out}__psum", f"{out}__pcnt"):
-                    self.acc[part][idx] += other.acc[part][:m]
+                    self.acc[part][at] += other.acc[part][:m]
             else:
                 op = {"sum": np.add, "count": np.add, "min": np.minimum, "max": np.maximum}[fn]
                 cur = self.acc[out]
-                cur[idx] = op(cur[idx], other.acc[out][:m])
+                cur[at] = op(cur[at], other.acc[out][:m])
         return idx
 
     def approx_nbytes(self) -> int:
@@ -648,7 +788,7 @@ class GroupState:
         for k in self.keys:
             dt = self.in_schema.field(k).dtype
             per_group += 24 if dt.is_varwidth else dt.width + 8
-        return acc + len(self.key_rows) * per_group
+        return acc + self.ngroups * per_group
 
     def _key_column(self, f, vals: list) -> Column:
         """Key output column; null keys (masked input rows) materialize as a
@@ -662,10 +802,8 @@ class GroupState:
         return c
 
     def result(self, out_schema: Schema) -> RecordBatch:
-        ngroups = len(self.key_rows)
-        data = {}
-        for i, k in enumerate(self.keys):
-            data[k] = [row[i] for row in self.key_rows]
+        ngroups = self.ngroups
+        data = dict(zip(self.keys, self.key_columns()))
         for out, spec in self.aggs.items():
             fn = spec["fn"]
             if fn == "mean":
@@ -683,10 +821,7 @@ class GroupState:
         cols = []
         for f in out_schema:
             vals = data[f.name]
-            if f.name in self.keys and not isinstance(vals, np.ndarray):
-                cols.append(self._key_column(f, vals))
-            else:
-                cols.append(Column.from_values(f.dtype, vals if not isinstance(vals, np.ndarray) else np.asarray(vals, f.dtype.np_dtype)))
+            cols.append(vals if isinstance(vals, Column) else Column.from_values(f.dtype, np.asarray(vals, f.dtype.np_dtype)))
         return RecordBatch(out_schema, cols)
 
 

@@ -431,11 +431,8 @@ class GraceHashAggregate:
 
     # -- state <-> batch ----------------------------------------------------
     def _state_batch(self, st: GroupState, fs: np.ndarray) -> RecordBatch:
-        ngroups = len(st.key_rows)
-        cols = []
-        for i, k in enumerate(self.keys):
-            f = self.in_schema.field(k)
-            cols.append(st._key_column(f, [row[i] for row in st.key_rows]))
+        ngroups = st.ngroups
+        cols = st.key_columns()
         for name, (_init, dt) in st._state_specs().items():
             cols.append(Column(dtypes.from_numpy(np.dtype(dt)), values=np.ascontiguousarray(st.acc[name][:ngroups])))
         cols.append(Column.from_values(dtypes.resolve("int64"), np.ascontiguousarray(fs[:ngroups])))
@@ -444,10 +441,8 @@ class GraceHashAggregate:
     def _state_from_batch(self, batch: RecordBatch) -> GroupState:
         """Rehydrate a spilled state batch into a GroupState shell so the
         partition fold reuses the exact in-memory ``merge`` arithmetic."""
-        st = GroupState(self.keys, self.aggs, self.mode, self.in_schema)
-        key_cols = [batch.column(k) for k in self.keys]
-        st.key_rows = list(zip(*[c.to_pylist() for c in key_cols]))
-        st.gids = {kt: i for i, kt in enumerate(st.key_rows)}
+        st = GroupState(self.keys, self.aggs, self.mode, self.in_schema, vectorized=True)
+        st.load_keys([batch.column(k) for k in self.keys])
         for name in st.acc:
             st.acc[name] = np.asarray(batch.column(name).values)
         return st
@@ -456,7 +451,7 @@ class GraceHashAggregate:
     def spill_state(self, st: GroupState) -> None:
         """Scatter one partial state (morsel fold or the in-memory prefix)
         to the level-0 partitions, assigning monotone first-seen ids."""
-        ngroups = len(st.key_rows)
+        ngroups = st.ngroups
         if ngroups == 0:
             return
         fs = np.arange(self._fs_next, self._fs_next + ngroups, dtype=np.int64)
@@ -478,7 +473,7 @@ class GraceHashAggregate:
         other = self._state_from_batch(batch)
         bfs = np.asarray(batch.column(FS_COL).values)
         idx = total.merge_indexed(other)
-        grow = len(total.gids) - len(fs)
+        grow = total.ngroups - len(fs)
         if grow > 0:
             fs = np.concatenate([fs, np.full(grow, _I64MAX, np.int64)])
         np.minimum.at(fs, idx, bfs)
@@ -499,7 +494,7 @@ class GraceHashAggregate:
                 nb = total.approx_nbytes()
                 self.acct.adjust(nb - reserved)
                 reserved = nb
-                if self.acct.over() and level + 1 < SPILL_MAX_DEPTH and len(total.gids) > 1:
+                if self.acct.over() and level + 1 < SPILL_MAX_DEPTH and total.ngroups > 1:
                     sub = self._new_set(level + 1)
                     sub.scatter(self._state_batch(total, fs))
                     total = None

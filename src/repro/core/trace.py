@@ -50,6 +50,7 @@ SPANS = (
     "dacp.morsel.launch",
     "dacp.morsel.sync",
     "dacp.morsel.fold",
+    "dacp.agg.map",
     "dacp.merge",
     "dacp.frame.send",
     "dacp.frame.recv",

@@ -45,7 +45,12 @@ Static plan parameters (the lru-cached kernel signature):
     with_gidx      append the group-id column to the compaction table
     segmented      run the segment fold (False = streaming chain: the group
                    outputs are zero-filled dummies)
-    ngroups        padded group count (multiple of 8)
+    ngroups        padded group count (multiple of 8); with a step table,
+                   the height of one group window
+
+A step table (the ``steps`` argument, traced data) makes the fold
+windowed, for morsels with more groups than a one-hot over all of them
+should span: see :func:`fused_chain_tiles`.
 """
 
 from __future__ import annotations
@@ -57,7 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.filter_select import compact, f32_order_key, onehot_dot, pred_mask, row_ids, survivors
+from repro.kernels.filter_select import compact, f32_order_key, onehot_dot, pred_mask, survivors
 from repro.kernels.project_arith import eval_checked, eval_descr
 from repro.kernels.segment_reduce import I32_MAX, f32_from_keys, mm_fold, mm_init, mm_sentinels, onehot
 
@@ -68,7 +73,28 @@ def _cat(parts):
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
-def _kernel(
+def _kernel(*refs, windowed, **static):
+    """One grid step.  A plain launch folds row tile ``i`` at step ``i``.  A
+    windowed launch reads its step table first (scalar prefetch): step
+    ``s`` folds row tile ``steps[s]`` into group window ``steps[S + s]``;
+    steps past the table's end (``s >= scalars[3]``) repeat its last one
+    and change nothing."""
+    if not windowed:
+        i = pl.program_id(0)
+        _step(*refs, tile_no=i, win=None, first=i == 0, **static)
+        return
+    steps_ref, *refs = refs
+    s = pl.program_id(0)
+    half = steps_ref.shape[0] // 2
+    win = steps_ref[half + s]
+    first = (s == 0) | (win != steps_ref[half + jnp.maximum(s - 1, 0)])
+
+    @pl.when(s < refs[0][3])
+    def _():
+        _step(*refs, tile_no=steps_ref[s], win=win, first=first, **static)
+
+
+def _step(
     sc_ref,
     pred_ref,
     grow_ref,
@@ -98,8 +124,11 @@ def _kernel(
     segmented,
     ngroups,
     tile,
+    tile_no,
+    win,
+    first,
 ):
-    rows = row_ids(tile)
+    rows = tile_no * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
     mask = rows < sc_ref[0]
     if kind != "none":
         mask = pred_mask(pred_ref[...], sc_ref[1], sc_ref[2], op=op, kind=kind) & mask
@@ -122,12 +151,12 @@ def _kernel(
     if with_gidx:
         parts.append(gcol_ref[...])
     ctab_ref[...] = compact(mask, _cat(parts))
-    cnt_ref[pl.program_id(0)] = survivors(mask)
+    cnt_ref[tile_no] = survivors(mask)
 
     sent_f = mm_sentinels(fns_f)
     sent_i = mm_sentinels(fns_i)
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(first)
     def _():
         gsum_ref[...] = jnp.zeros_like(gsum_ref)
         gcnt_ref[...] = jnp.zeros_like(gcnt_ref)
@@ -138,8 +167,10 @@ def _kernel(
     if not segmented:
         return
 
-    # -- masked segment fold (only surviving rows reach any group)
-    oh = onehot(grow_ref[...], mask, ngroups)
+    # -- masked segment fold (only surviving rows reach any group); a
+    #    windowed step folds the rows whose group lies in its window
+    gids = grow_ref[...] if win is None else grow_ref[...] - win * ngroups
+    oh = onehot(gids, mask, ngroups)
     limbs = [limb_ref[...]]
     for k in csums:
         v = icols[k]
@@ -162,6 +193,7 @@ def fused_chain_tiles(
     mmi,
     af,
     ai,
+    steps=None,
     *,
     op: str,
     kind: str,
@@ -181,7 +213,7 @@ def fused_chain_tiles(
     Inputs (all row tables padded to a multiple of ``tile``; unused tables
     are width-1 zero dummies):
 
-        scalars   (4,)      int32  [n_rows, t_hi bits, t_lo bits, 0]
+        scalars   (4,)      int32  [n_rows, t_hi bits, t_lo bits, live steps]
         pred      (N, P)    int32  filter-column bit-planes
         gidx      (N,)      int32  full-morsel group ids (zeros unsegmented)
         pass_tbl  (N, Dp)   int32  compaction passthrough planes
@@ -190,17 +222,31 @@ def fused_chain_tiles(
         mmi       (N, Mi)   i32    min/max int columns (widened)
         af        (N, Af)   f32    projection-arithmetic input columns
         ai        (N, Ai)   i32    projection-arithmetic input columns
+        steps     (2S,)     int32  windowed fold only: row tile, then group
+                                   window, of each of S grid steps
+
+    Without ``steps`` the fold's one-hot spans all ``ngroups`` groups, so
+    its cost is rows x groups.  With ``steps`` (rows sorted by group id) the
+    group table is N rows tall and each step folds one row tile into one
+    window of ``ngroups`` consecutive groups, the window's block resident
+    while consecutive steps name it: the cost is rows x ``ngroups``
+    whatever the morsel's group count.  ``scalars[3]`` is the number of
+    live steps.
 
     Returns ``(ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst)``: the
     per-tile-compacted table ``[pass | computed f32 | computed i32 |
     f32 envelope flag (with any computed f32) | gidx?]`` with per-tile
     survivor counts, and per-group limb sums
     ``[passthrough | in-kernel csums]``, counts, min/max extremes, and the
-    minimum surviving row index (``2^31-1`` for groups with no survivors).
+    minimum surviving row index (``2^31-1`` for groups with no survivors;
+    a windowed fold leaves the groups of windows no step names undefined).
     """
     n, dp = pass_tbl.shape
     assert n % tile == 0, (n, tile)
     assert ngroups % 8 == 0 and ngroups > 0, ngroups
+    windowed = steps is not None
+    if windowed:
+        assert n % ngroups == 0, (n, ngroups)
     p = pred.shape[1]
     length = limb_tbl.shape[1]
     mf, mi = mmf.shape[1], mmi.shape[1]
@@ -210,6 +256,7 @@ def fused_chain_tiles(
     assert len(fns_f) == mf and len(fns_i) == mi, (fns_f, mf, fns_i, mi)
     kernel = functools.partial(
         _kernel,
+        windowed=windowed,
         op=op,
         kind=kind,
         descrs_f=descrs_f,
@@ -223,34 +270,61 @@ def fused_chain_tiles(
         tile=tile,
     )
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if windowed:
+        half = steps.shape[0] // 2
+
+        def tile_of(s, st):
+            return st[s]
+
+        def win_of(s, st):
+            return st[half + s]
+
+    else:
+
+        def tile_of(i):
+            return i
+
+        def win_of(i):
+            return 0
 
     def rows(width):  # a (width, N) row-major table, one (width, tile) block per step
-        return pl.BlockSpec((width, tile), lambda i: (0, i))
+        return pl.BlockSpec((width, tile), lambda *a: (0, tile_of(*a)))
 
     def tiles(width):  # a (N, width) column table, one (tile, width) block per step
-        return pl.BlockSpec((tile, width), lambda i: (i, 0))
+        return pl.BlockSpec((tile, width), lambda *a: (tile_of(*a), 0))
 
-    def whole(width):  # a per-group accumulator resident across the grid
-        return pl.BlockSpec((ngroups, width), lambda i: (0, 0))
+    def groups(width):  # a per-group accumulator: the whole table, or the step's window of it
+        return pl.BlockSpec((ngroups, width), lambda *a: (win_of(*a), 0))
 
+    height = n if windowed else ngroups
+    in_specs = [smem, rows(p), rows(1), tiles(1), tiles(dp), tiles(length), rows(mf), rows(mi), tiles(afw), tiles(aiw)]
+    out_specs = [tiles(dc), smem, groups(ls), groups(1), groups(mf), groups(mi), groups(1)]
+    if windowed:
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(half,), in_specs=in_specs, out_specs=out_specs
+        )
+        call_kw = {"grid_spec": grid_spec}
+        lead = (jnp.asarray(steps, jnp.int32),)
+    else:
+        call_kw = {"grid": (n // tile,), "in_specs": in_specs, "out_specs": out_specs}
+        lead = ()
     gidx = jnp.asarray(gidx, jnp.int32)
     mmf_keys = f32_order_key(jax.lax.bitcast_convert_type(jnp.asarray(mmf, jnp.float32), jnp.int32))
     ctab, counts, gsum, gcnt, gmmf, gmmi, gfirst = pl.pallas_call(
         kernel,
-        grid=(n // tile,),
-        in_specs=[smem, rows(p), rows(1), tiles(1), tiles(dp), tiles(length), rows(mf), rows(mi), tiles(afw), tiles(aiw)],
-        out_specs=[tiles(dc), smem, whole(ls), whole(1), whole(mf), whole(mi), whole(1)],
         out_shape=[
             jax.ShapeDtypeStruct((n, dc), jnp.int32),
             jax.ShapeDtypeStruct((n // tile,), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups, ls), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups, 1), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups, mf), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups, mi), jnp.int32),
-            jax.ShapeDtypeStruct((ngroups, 1), jnp.int32),
+            jax.ShapeDtypeStruct((height, ls), jnp.int32),
+            jax.ShapeDtypeStruct((height, 1), jnp.int32),
+            jax.ShapeDtypeStruct((height, mf), jnp.int32),
+            jax.ShapeDtypeStruct((height, mi), jnp.int32),
+            jax.ShapeDtypeStruct((height, 1), jnp.int32),
         ],
         interpret=interpret,
+        **call_kw,
     )(
+        *lead,
         jnp.asarray(scalars, jnp.int32),
         jnp.asarray(pred).T,
         gidx.reshape(1, n),

@@ -114,6 +114,8 @@ def fused_chain_tiles(
     mmi,
     af,
     ai,
+    steps=None,
+    *,
     op: str,
     kind: str,
     descrs_f: tuple,
@@ -126,8 +128,10 @@ def fused_chain_tiles(
     ngroups: int,
     tile: int = 256,
 ):
-    # scalars[0:3] = [n_rows, t_hi bits, t_lo bits] ride as traced data:
-    # a new predicate literal / morsel row count reuses the compiled chain
+    # scalars = [n_rows, t_hi bits, t_lo bits, live steps] ride as traced
+    # data: a new predicate literal / morsel row count reuses the compiled
+    # chain, and so does a windowed fold's step table (its length is 2x the
+    # row tiles, whatever the morsel's group count)
     return _fused_chain_tiles(
         scalars,
         pred,
@@ -138,6 +142,7 @@ def fused_chain_tiles(
         mmi,
         af,
         ai,
+        steps,
         op=op,
         kind=kind,
         descrs_f=descrs_f,

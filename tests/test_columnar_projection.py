@@ -142,7 +142,8 @@ def test_part_span_bytes_are_the_projected_members(dataset, monkeypatch):
     report = {}
     list(scan_path(path, columns=["x"], predicate=col("k") > 2, report=report).iter_batches())
     sizes = _member_sizes(path)
-    assert [st["part"] for st in seen] == [f"part-{i:05d}.npz" for i in range(PARTS)]
+    # the reader pool opens part spans in whatever order its threads start
+    assert sorted(st["part"] for st in seen) == [f"part-{i:05d}.npz" for i in range(PARTS)]
     assert sum(st["bytes"] for st in seen) == report["bytes_read"] == sizes["x"] + sizes["k"]
 
 

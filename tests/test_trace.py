@@ -233,3 +233,58 @@ def test_scan_counters_reach_ping_and_status(tmp_path):
     st = fl.status()["executor"]
     assert st["scan_rows"] == PARTS * PART_ROWS
     assert st["scan_bytes_read"] == st["scan_bytes_needed"] == members["k.npy"]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_agg_map_span_opens_under_the_morsel(tmp_path, backend):
+    """``dacp.agg.map`` (keys to group ids) is a named span, and a traced
+    grouped run opens it inside a ``dacp.morsel`` on the same thread."""
+    import jax
+    from jax.profiler import ProfileData
+
+    assert "dacp.agg.map" in trace.SPANS
+    path = _dataset(tmp_path)
+    cfg = ExecutorConfig(num_workers=2, morsel_rows=1024, backend=backend)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        with trace.flow("flow-9"):
+            execute_parallel(_agg_dag(), lambda n: scan_path(path, columns=n.params["columns"]), cfg).collect()
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = glob.glob(os.path.join(str(tmp_path / "prof"), "**", "*.xplane.pb"), recursive=True)
+    nested = 0
+    for plane in ProfileData.from_file(xplane).planes:
+        for line in plane.lines if plane.name.startswith("/host:") else []:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events]
+            morsels = [(s, e) for n, s, e in evs if n == "dacp.morsel"]
+            for n, s, e in evs:
+                if n == "dacp.agg.map" and any(ms <= s and e <= me for ms, me in morsels):
+                    nested += 1
+    assert nested >= PARTS
+
+
+def test_agg_counters_move_on_a_grouped_cook_only(tmp_path):
+    """``agg_morsels`` and ``agg_host_s`` count a grouped COOK's morsels and
+    their key mapping and merge time; a COOK without keys leaves them."""
+    from repro.core.backend import get_backend
+
+    path = _dataset(tmp_path)
+    bk = get_backend("numpy")
+    cfg = ExecutorConfig(num_workers=2, morsel_rows=1024, backend="numpy")
+    stats = ExecutorStats()
+    before = (bk.agg_morsels, bk.agg_host_s)
+    execute_parallel(_agg_dag(), lambda n: scan_path(path, columns=n.params["columns"]), cfg, stats=stats).collect()
+    assert bk.agg_morsels - before[0] == stats.progress()["morsels_done"] > 0
+    assert bk.agg_host_s > before[1]
+    assert stats.progress()["groups"] == 5
+
+    bld = Dag.build()
+    s = bld.add("source", {"uri": "dacp://h:1/tbl", "columns": ["x"]})
+    dag = bld.finish(bld.add("aggregate", {"keys": [], "aggs": {"sx": {"fn": "sum", "column": "x"}}}, [s]))
+    before = (bk.agg_morsels, bk.agg_host_s)
+    out = execute_parallel(dag, lambda n: scan_path(path, columns=n.params["columns"]), cfg).collect()
+    assert out.num_rows == 1
+    assert (bk.agg_morsels, bk.agg_host_s) == before
