@@ -329,7 +329,8 @@ class ExecutorStats:
 
     ``scans`` holds one scan ``report`` per source the run opens (see
     ``ScanAdapter.scan``); ``progress()`` sums them into ``scan_bytes_read``,
-    ``scan_bytes_needed`` and ``scan_rows``.  ``groups`` counts the rows
+    ``scan_bytes_needed``, ``scan_rows``, ``scan_members_mapped`` and
+    ``scan_members_copied``.  ``groups`` counts the rows
     (groups) the run's aggregates produced."""
 
     pipelines: list = field(default_factory=list)
@@ -396,6 +397,8 @@ class ExecutorStats:
             "scan_bytes_read": sum(r.get("bytes_read", 0) for r in self.scans),
             "scan_bytes_needed": sum(r.get("bytes_needed", 0) for r in self.scans),
             "scan_rows": sum(r.get("rows_read", 0) for r in self.scans),
+            "scan_members_mapped": sum(r.get("members_mapped", 0) for r in self.scans),
+            "scan_members_copied": sum(r.get("members_copied", 0) for r in self.scans),
         }
 
     def to_dict(self) -> dict:

@@ -8,17 +8,32 @@ The adapter projects natively: ``scan(columns=[...])`` reads only the
 members of those columns (``c``, or ``c__offsets`` and ``c__data`` for a
 string column) from each part file it opens, and streams them in the order
 given.  ``columns=None`` or an empty list reads every member, as Parquet's
-``names or None`` does, so a zero-column plan keeps its row count.  Its
-``report`` counts ``bytes_read`` (the bytes of the members read),
-``bytes_needed`` (those of the members of ``columns_needed``, the columns the
-plan asked for) and ``rows_read``; spans ``dacp.scan.part`` time one part
-file's read and ``dacp.scan.batch`` one batch's build.
+``names or None`` does, so a zero-column plan keeps its row count.
+
+A part file is mapped, not read: each stored (uncompressed) member becomes
+an array that views the mapping in place, after one CRC-32 pass over its
+bytes against the zip directory.  Members that are compressed or
+encrypted, hold objects, or are multi-dimensional Fortran-ordered arrays go
+through ``np.load``, which copies them.  Part files are only ever replaced
+(``os.replace``), never rewritten in place, so a mapping stays valid.
+
+Its ``report`` counts ``bytes_read`` (the bytes of the members read, all of
+which the CRC pass reads), ``bytes_needed`` (those of the members of
+``columns_needed``, the columns the plan asked for), ``rows_read``,
+``members_mapped`` and ``members_copied``; spans ``dacp.scan.part`` time
+one part file's read (stat ``mapped``: its members mapped) and
+``dacp.scan.batch`` one batch's build.
 """
 
 from __future__ import annotations
 
 import json
+import math
+import mmap
 import os
+import struct
+import zipfile
+import zlib
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
@@ -48,6 +63,61 @@ def _member_column(member: str) -> str:
         if member.endswith(suffix):
             return member[: -len(suffix)]
     return member
+
+
+# a zip local file header: signature, 22 bytes of fields the central
+# directory repeats, then the lengths of the name and the extra field
+_LOCAL_HEADER = struct.Struct("<4s22xHH")
+
+
+def _read_members(f, z, infos: list) -> tuple[dict, int]:
+    """The arrays of the members ``infos`` of the part file open as ``f``
+    (``z`` its ``np.load``), keyed as ``z`` keys them, and how many of them
+    were mapped.
+
+    A stored member is a view of the file mapped copy-on-write: writes to
+    it go to private pages, never to the file, and the mapping lives as long
+    as any array that views it, so a part replaced (``os.replace``) while
+    its arrays are out leaves them intact.  Each mapped member's bytes are
+    checked against the directory's CRC-32 in one ``zlib.crc32`` call (which
+    releases the interpreter lock); a mismatch raises ``zipfile.BadZipFile``,
+    as ``np.load`` does.  A member that is compressed or encrypted, holds
+    objects or is a Fortran-ordered array of more than one dimension goes
+    through ``np.load``, which reads, checks and copies it."""
+    mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_COPY)
+    arrays, mapped = {}, 0
+    for info in infos:
+        key = info.filename.removesuffix(".npy")
+        a = _mapped_member(mm, f, info)
+        if a is None:
+            a = z[key]
+        else:
+            mapped += 1
+        arrays[key] = a
+    return arrays, mapped
+
+
+def _mapped_member(mm: mmap.mmap, f, info: zipfile.ZipInfo):
+    """``info``'s array as a view of ``mm``, CRC-checked; None when the
+    member has to go through ``np.load``."""
+    if info.compress_type != zipfile.ZIP_STORED or info.flag_bits & 0x1:
+        return None
+    sig, name_len, extra_len = _LOCAL_HEADER.unpack_from(mm, info.header_offset)
+    if sig != b"PK\x03\x04":
+        raise zipfile.BadZipFile(f"Bad magic number for file header of {info.filename!r}")
+    start = info.header_offset + _LOCAL_HEADER.size + name_len + extra_len
+    end = start + info.file_size
+    f.seek(start)
+    # np.load's own header reader (format versions 1.0, 2.0 and 3.0)
+    shape, fortran_order, dtype = np.lib.format._read_array_header(f, np.lib.format.read_magic(f))
+    offset, count = f.tell(), math.prod(shape)
+    if dtype.hasobject or (fortran_order and len(shape) > 1) or offset + count * dtype.itemsize > end:
+        return None
+    with memoryview(mm) as view:
+        crc = zlib.crc32(view[start:end])
+    if crc != info.CRC:
+        raise zipfile.BadZipFile(f"Bad CRC-32 for file {info.filename!r}")
+    return np.frombuffer(mm, dtype, count, offset).reshape(shape)
 
 
 class ColumnarAdapter(ScanAdapter):
@@ -96,7 +166,7 @@ class ColumnarAdapter(ScanAdapter):
             parts = parts[lo:hi]
         if report is not None:
             needed = set(report.get("columns_needed", full.names))
-            for key in ("bytes_read", "bytes_needed", "rows_read"):
+            for key in ("bytes_read", "bytes_needed", "rows_read", "members_mapped", "members_copied"):
                 report.setdefault(key, 0)
 
         def _cast(batch: RecordBatch) -> RecordBatch:
@@ -110,18 +180,22 @@ class ColumnarAdapter(ScanAdapter):
             return RecordBatch(schema, cols)
 
         def _load(p: str) -> tuple:
-            with np.load(os.path.join(root, p), mmap_mode="r") as z:
-                sizes = {i.filename.removesuffix(".npy"): i.file_size for i in z.zip.infolist()}
-                members = [k for k in z.files if _member_column(k) in wanted]
-                with span("dacp.scan.part", part=p, bytes=sum(sizes[k] for k in members)):
-                    return {k: z[k] for k in members}, sizes
+            with open(os.path.join(root, p), "rb") as f, np.load(f) as z:
+                infos = {i.filename.removesuffix(".npy"): i for i in z.zip.infolist()}
+                members = [infos[k] for k in z.files if _member_column(k) in wanted]
+                with span("dacp.scan.part", part=p, bytes=sum(i.file_size for i in members)) as sp:
+                    arrays, mapped = _read_members(f, z, members)
+                    sp.set_metadata(mapped=mapped)
+            return arrays, {k: i.file_size for k, i in infos.items()}, mapped
 
         def _batches(loaded: tuple):
             # runs on the consuming thread, the report's one writer
-            arrays, sizes = loaded
+            arrays, sizes, mapped = loaded
             if report is not None:
                 report["bytes_read"] += sum(sizes[k] for k in arrays)
                 report["bytes_needed"] += sum(n for m, n in sizes.items() if _member_column(m) in needed)
+                report["members_mapped"] += mapped
+                report["members_copied"] += len(arrays) - mapped
             it = npz_arrays_sdf(arrays, batch_rows).iter_batches()
             while True:
                 with span("dacp.scan.batch"):

@@ -108,15 +108,26 @@ def test_adapter_streams_the_columns_in_the_order_given(dataset):
 
 
 def test_members_outside_the_projection_are_never_read(dataset, monkeypatch):
+    from repro.server.adapters import columnar
+
     path, _full = dataset
     accessed = []
     orig = np.lib.npyio.NpzFile.__getitem__
+    orig_mapped = columnar._mapped_member
 
     def spy(self, key):
         accessed.append(key)
         return orig(self, key)
 
+    def spy_mapped(mm, f, info):
+        a = orig_mapped(mm, f, info)
+        if a is not None:
+            accessed.append(info.filename.removesuffix(".npy"))
+        return a
+
+    # a member is read either mapped in place or through np.load
     monkeypatch.setattr(np.lib.npyio.NpzFile, "__getitem__", spy)
+    monkeypatch.setattr(columnar, "_mapped_member", spy_mapped)
     report = {}
     rows = sum(b.num_rows for b in scan_path(path, columns=["k", "note"], scan_workers=2, report=report).iter_batches())
     assert rows == PARTS * PART_ROWS
