@@ -1,6 +1,5 @@
 """Read-amplification: predicate + projection pushdown vs full transfer
-(paper §I-A, §III-B) — measured in BYTES ON THE WIRE, plus the fused
-filter_select Pallas kernel vs its jnp oracle.
+(paper §I-A, §III-B) — measured in BYTES ON THE WIRE.
 
     full scan      — GET everything, filter client-side
     pushdown       — GET with (columns, predicate); server filters in-situ
@@ -15,7 +14,6 @@ from __future__ import annotations
 import json
 import os
 import tempfile
-import time
 
 import numpy as np
 
@@ -68,33 +66,11 @@ def run(rows: int = 100_000, selectivity: float = 0.02, verbose: bool = True) ->
     results["amplification"] = results["full_bytes"] / max(results["pushdown_bytes"], 1)
     results["speedup"] = results["full_s"] / results["pushdown_s"]
 
-    # ---- bit-plane filter_select kernel vs oracle (host-side, interpret) ----
-    from repro.kernels import ops, ref
-
-    table = np.random.default_rng(0).normal(size=(8192, 8)).astype(np.float32)
-    import jax.numpy as jnp
-
-    jt = jnp.asarray(table)
-    planes = jnp.asarray(table.view(np.int32))
-    scalars = jnp.asarray([table.shape[0], 0, 0], jnp.int32)  # x[:, 0] > 0.0
-    ops.filter_select_planes(planes[:, :1], planes, scalars, "gt", "f32", tile=256)  # warm
-    t0 = time.perf_counter()
-    for _ in range(5):
-        ops.filter_select_planes(planes[:, :1], planes, scalars, "gt", "f32", tile=256)[0].block_until_ready()
-    k_us = (time.perf_counter() - t0) / 5 * 1e6
-    t0 = time.perf_counter()
-    for _ in range(5):
-        ref.filter_select_ref(jt, 0, 0.0, tuple(range(8)), 256)[0].block_until_ready()
-    r_us = (time.perf_counter() - t0) / 5 * 1e6
-    results["filter_select_kernel_us"] = k_us
-    results["filter_select_ref_us"] = r_us
-
     if verbose:
         emit("pushdown.full_scan", results["full_s"] * 1e6, f"{results['full_bytes']}B")
         emit("pushdown.pushdown", results["pushdown_s"] * 1e6, f"{results['pushdown_bytes']}B")
         emit("pushdown.cook", results["cook_s"] * 1e6, f"{results['cook_bytes']}B")
         emit("pushdown.amplification", 0.0, f"{results['amplification']:.1f}x")
-        emit("pushdown.filter_select_kernel", k_us, f"ref={r_us:.0f}us")
     return results
 
 

@@ -6,7 +6,7 @@
 One section per paper figure/claim:
     structured    — Fig. 4 (DACP vs FTP, structured rows, up+down)
     unstructured  — Fig. 5 (mixed blob workload, BLOB/Binary/FTP)
-    pushdown      — §I-A/§III-B read amplification + filter_select kernel
+    pushdown      — §I-A/§III-B read amplification
     cook_insitu   — §III-D/§VI-C move-operators-not-data
     session_reuse — §III-C phased interaction: v2 multiplexed session vs
                     channel-per-request for N small GETs
@@ -14,7 +14,6 @@ One section per paper figure/claim:
                     numpy vs pallas backend, rows/s on a COOK pipeline
     flows         — flow lifecycle: time-to-first-batch for START+FETCH vs
                     blocking COOK, and START-ack latency
-    kernels       — §IV-B hot-spot kernels (interpret-mode indicative)
     mesh          — federated catalog mesh: LIST scatter/cache latency +
                     partition-parallel scan vs the single-flow plan
     datasource    — adapter-native pushdown: SQL compilation, parquet
@@ -40,7 +39,6 @@ def main() -> None:
         datasource_bench,
         executor,
         flows_bench,
-        kernels_bench,
         mesh_bench,
         pushdown,
         session_reuse,
@@ -57,7 +55,6 @@ def main() -> None:
     out["session_reuse"] = session_reuse.run(n_gets=40 if quick else 200)
     out["executor"] = executor.run(rows=100_000 if quick else 400_000)
     out["flows"] = flows_bench.run(rows=50_000 if quick else 200_000)
-    out["kernels"] = kernels_bench.run()
     out["mesh"] = mesh_bench.run(rows=50_000 if quick else 200_000)
     out["datasource"] = datasource_bench.run(rows=20_000 if quick else 100_000)
 

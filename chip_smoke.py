@@ -13,8 +13,9 @@ full-range int64 and int32) are written to a temporary directory.  A
 server with the default ``ExecutorConfig`` (backend ``auto``, which must
 resolve to ``pallas``) answers PING, DESCRIBE, a streaming
 filter→project→select COOK (``FusedChainPlan.run``), an aggregate COOK
-(``FusedChainPlan.fold``), a group-by with int64 min/max (the per-op
-segment-reduce kernels), the same over the adversarial table, and a
+(``FusedChainPlan.fold``), a group-by with int64 min/max (declined by the
+fused plan, so it runs the numpy operators), the same over the
+adversarial table, and a
 cross-domain union of two servers followed by a group-by (partial
 aggregates exchanged between domains).  Every answer must be
 byte-identical to the same request on ``backend="numpy"`` servers over the
@@ -185,8 +186,8 @@ def aggregate_dag(*uris: str):
 
 
 def minmax_dag(uri: str):
-    """int64 min/max: outside the fused envelope, so the per-op
-    segment-reduce kernels fold it."""
+    """int64 min/max: outside the fused envelope, so the fused plan
+    declines it and the numpy operators fold it."""
     from repro.core.dag import Dag
 
     bld = Dag.build()
@@ -372,24 +373,24 @@ def _one_chip_requests(smoke: Smoke, servers: dict, net) -> None:
 
         smoke.phase(name, run)
 
-    def per_op(name, table):
+    def declined(name, table):
         def run():
-            before = pallas.kernel_calls
             note = compare(
                 name,
                 cook(client, minmax_dag(f"dacp://{auth_a}/ds/{table}")),
                 cook(ref_client, minmax_dag(f"dacp://{auth_a_ref}/ds/{table}")),
             )
-            grew = pallas.kernel_calls - before
-            assert grew > 0, f"{name} launched no per-op kernel"
-            return f"{note}, per-op kernel calls +{grew}"
+            stats = a.engine.executor_stats()
+            assert stats["morsels_done"] > 0, f"{name} ran no morsel"
+            assert stats["fused_launches"] == 0, f"{name} ran {stats['fused_launches']} fused launches"
+            return f"{note}, declined by the fused plan ({stats['morsels_done']} morsels on numpy)"
 
         smoke.phase(name, run)
 
     for table in ("main", "adv"):
         fused(f"stream COOK [{table}]", stream_dag, table)
         fused(f"aggregate COOK [{table}]", aggregate_dag, table)
-        per_op(f"int64 min/max group-by [{table}]", table)
+        declined(f"int64 min/max group-by [{table}]", table)
 
     def repeat_compiles():
         counter = count_compiles()

@@ -1,56 +1,44 @@
-"""Pluggable compute backends for the morsel executor (paper §III-D).
+"""Compute backends for the morsel executor (paper §III-D).
 
-A *backend* supplies the vectorized kernels that operator evaluators run on
-each morsel: predicate evaluation, filtering, the fused filter+select that
-the executor peepholes out of adjacent Filter→Select pairs, projection
-arithmetic, and per-morsel segment reductions for partial aggregation.
-Backends are looked up in a **kernel registry** keyed ``(backend name,
-op name)``; resolution falls back to the numpy reference kernels, so a
-backend only overrides the ops it accelerates and everything else keeps
-reference semantics bit-for-bit.
+A backend names where a pipeline's morsels run and carries the counters
+that say how they ran.  Two ship in-tree:
 
-Two backends ship in-tree:
+  * ``numpy``  — the reference: every morsel runs the numpy operators of
+    ``repro.core.operators``.
+  * ``pallas`` — one device path.  :func:`plan_fused_chain` compiles a
+    pipeline's filter / select / project chain, and an aggregate's
+    per-morsel fold, into a :class:`FusedChainPlan` that runs each morsel
+    as ONE ``fused_chain_tiles`` launch (``repro.kernels.fused_pipeline``).
+    Columns cross into the kernel as **int32 bit-planes** (one plane per 4
+    bytes of width), so compaction moves bit patterns exactly: ``-0.0``,
+    NaN payloads, Inf and full-range int64 come back verbatim.
 
-  * ``numpy``  — the reference implementation (always present).
-  * ``pallas`` — dispatches eligible morsels to the JAX/Pallas kernels in
-    ``repro.kernels``.  Columns cross into the kernels as **int32
-    bit-planes** (one plane per 4 bytes of width), so compaction and
-    reduction matmuls move bit patterns exactly — the kernels are
-    bit-identical to numpy for every fixed-width dtype, including
-    ``-0.0``, NaN payloads, Inf, and full-range int64.  Eligibility is
-    decided per morsel *and per column*, by explicit checks before a
-    launch; anything outside a kernel's envelope — var-width columns,
-    validity masks, unsupported literal / column dtype pairings — runs the
-    numpy kernel, so results are identical either way.  A kernel that fails
-    to compile or run fails the request: no error turns into a silent numpy
-    answer, and resolving ``pallas`` without jax installed raises.
+The fused envelope, decided per chain at plan time:
 
-Dispatchable ops:
+    filter     at most one ``col <cmp> lit``, ``<cmp>`` in {<, <=, >, >=,
+               ==, !=}, on a float32 / int32 / int64 column, with a literal
+               whose numpy promotion the kernel reproduces
+    project    float32 / int32 ``+ - *`` over columns and python-scalar
+               literals (float32 ``mul`` under ``add``/``sub`` only by a
+               power of two), and dtype-preserving renames
+    select     any fixed-width columns (var-width outputs decline)
+    aggregate  keys of any dtype but float16 / float64; counts, integer
+               sums (8-bit limbs, wraparound-identical), float sums and
+               mean partial sums (float64 on the host, in row order),
+               float32 and narrow-integer min/max.  Up to 256 groups a
+               morsel the one-hot fold spans every group; above that the
+               rows are sorted by group and folded window by window (256
+               groups a window)
 
-    filter_select   predicate ``col <cmp> lit`` with ``<cmp>`` in
-                    {<, <=, >, >=, ==, !=}; predicate column float32 /
-                    int32 / int64; projected columns any fixed-width dtype
-    filter          the unfused form (projects every column)
-    project         arithmetic Expr chains (+ - * over float32 or int32
-                    columns, python-scalar literals)
-    segment_reduce  per-group partial folds: count always, sum for integer
-                    columns (8-bit-limb exact, wraparound-identical to
-                    numpy), min/max for finite float32 without ``-0.0``,
-                    int32-safe integer,
-                    and the wide dtypes int64 / uint32 / uint64 / float64
-                    via a two-word hi/lo compare — two masked-reduce kernel
-                    passes over an order-preserving int64 key image (uint64:
-                    top-bit flip; float64: sign-magnitude fold, NaN and
-                    -0.0 ineligible), exact over the full 64-bit range;
-                    float sums and mean partial sums fold through an
-                    explicit **f64-accumulating reference path** (host-side
-                    — kernel lanes are 32-bit — counted in
-                    ``PallasBackend.f64_folds``) instead of silently falling
-                    back.  Up to 256 groups a morsel the one-hot fold spans
-                    every group; above that the rows are sorted by group
-                    and the fused kernel folds them window by window (256
-                    groups a window), so the device cost grows with rows,
-                    not rows x groups (wide min/max then stay with numpy)
+and per morsel at run time: a validity mask on a column the kernel reads,
+float32 arithmetic that leaves the exact envelope (inf, NaN, subnormal,
+flushed underflow: ``envelope_rejects``), a float32 min/max input holding
+a NaN or ``-0.0``, or a float32 key holding one (``key_rejects``) returns
+:data:`FUSED_INELIGIBLE`.  A chain the plan declines, and a morsel it
+gives back, run the numpy operators — the same answer, byte for byte.  A
+kernel that fails to compile or run fails the request: no error turns into
+a silent numpy answer, and resolving ``pallas`` without jax installed
+raises.
 
 ``get_backend("auto")`` selects pallas only when jax reports a real TPU;
 interpret-mode Pallas on CPU is for correctness tests, not speed.  The
@@ -65,7 +53,6 @@ import importlib.util
 import math
 import threading
 from pathlib import Path
-from typing import Callable
 
 import numpy as np
 
@@ -76,8 +63,6 @@ from repro.core.trace import span
 
 __all__ = [
     "ComputeBackend",
-    "KERNELS",
-    "register_kernel",
     "get_backend",
     "available_backends",
     "BACKENDS",
@@ -90,25 +75,9 @@ __all__ = [
 ]
 
 
-# ---------------------------------------------------------------------------
-# kernel registry
-# ---------------------------------------------------------------------------
-KERNELS: dict = {"numpy": {}, "pallas": {}}
-
-
-def register_kernel(backend: str, op: str):
-    """Register ``fn(backend_instance, ...)`` as ``op`` for ``backend``."""
-
-    def deco(fn: Callable) -> Callable:
-        KERNELS.setdefault(backend, {})[op] = fn
-        return fn
-
-    return deco
-
-
 class ComputeBackend:
-    """Kernel dispatch facade.  Instances are thread-safe; their only state
-    is counters.
+    """A backend's name and counters.  Instances are thread-safe; their only
+    state is counters.
 
     ``agg_morsels`` counts the grouped morsels the executor folded with this
     backend and ``agg_host_s`` the host seconds they spent mapping keys to
@@ -126,77 +95,6 @@ class ComputeBackend:
         """Add ``k`` to a counter, from any thread."""
         with self._counter_lock:
             setattr(self, counter, getattr(self, counter) + k)
-
-    def kernel(self, op: str) -> Callable:
-        impl = KERNELS.get(self.name, {}).get(op)
-        if impl is None:
-            impl = KERNELS["numpy"][op]
-        return impl
-
-    # -- morsel-level entry points (used by operator evaluators) ------------
-    def eval_predicate(self, batch: RecordBatch, predicate: Expr) -> np.ndarray:
-        return self.kernel("eval_predicate")(self, batch, predicate)
-
-    def filter(self, batch: RecordBatch, predicate: Expr):
-        """Apply a predicate; returns the surviving rows or ``None`` when the
-        whole morsel is filtered out (no empty frames downstream)."""
-        return self.kernel("filter")(self, batch, predicate)
-
-    def filter_select(self, batch: RecordBatch, predicate: Expr, columns: list):
-        """Fused filter + column projection (the executor's peephole)."""
-        return self.kernel("filter_select")(self, batch, predicate, columns)
-
-    def project(self, batch: RecordBatch, exprs: dict, out_schema):
-        """Projection arithmetic over one morsel (shaped to ``out_schema``)."""
-        return self.kernel("project")(self, batch, exprs, out_schema)
-
-    def segment_reduce(self, gidx: np.ndarray, ngroups: int, specs: list, n_rows: int) -> dict:
-        """Per-group partial reductions for one factorized morsel.
-
-        ``specs`` is ``[(state_name, fn, values), ...]`` with ``fn`` in
-        {count, sum, fsum, min, max} (``values`` is None for count; ``fsum``
-        marks a float sum from a fresh state, foldable in the backend's
-        f64-accumulating reference path).  Returns a dict mapping the state
-        names the backend accelerated to per-group arrays of length
-        ``ngroups``; callers scatter the rest with numpy.  The numpy
-        backend accelerates nothing (``{}``)."""
-        return self.kernel("segment_reduce")(self, gidx, ngroups, specs, n_rows)
-
-
-# ---------------------------------------------------------------------------
-# numpy reference kernels
-# ---------------------------------------------------------------------------
-@register_kernel("numpy", "eval_predicate")
-def _np_eval_predicate(bk, batch: RecordBatch, predicate: Expr) -> np.ndarray:
-    return np.asarray(predicate.evaluate(batch), dtype=bool)
-
-
-@register_kernel("numpy", "filter")
-def _np_filter(bk, batch: RecordBatch, predicate: Expr):
-    mask = _np_eval_predicate(bk, batch, predicate)
-    if mask.all():
-        return batch
-    if not mask.any():
-        return None
-    return batch.filter(mask)
-
-
-@register_kernel("numpy", "filter_select")
-def _np_filter_select(bk, batch: RecordBatch, predicate: Expr, columns: list):
-    out = _np_filter(bk, batch, predicate)
-    return None if out is None else out.select(columns)
-
-
-@register_kernel("numpy", "project")
-def _np_project(bk, batch: RecordBatch, exprs: dict, out_schema):
-    from repro.core.operators import project_morsel
-
-    return project_morsel(batch, exprs, out_schema)
-
-
-@register_kernel("numpy", "segment_reduce")
-def _np_segment_reduce(bk, gidx, ngroups, specs, n_rows) -> dict:
-    return {}  # reference path: GroupState scatters with numpy ufuncs
 
 
 class NumpyBackend(ComputeBackend):
@@ -275,18 +173,17 @@ class PallasBackend(ComputeBackend):
         super().__init__()
         self._kernel_mod = None
         self._lock = threading.Lock()
-        self.kernel_calls = 0  # observability: kernel dispatch count
-        # morsels whose float32 arithmetic left the kernels' exact envelope
+        # morsels whose float32 arithmetic left the kernel's exact envelope
         # (inf, NaN, subnormal, flushed underflow) and were recomputed by
-        # the numpy reference
+        # the numpy operators
         self.envelope_rejects = 0
-        # float sums folded through the f64-accumulating reference path
-        # (host-side; the kernels' 32-bit lanes cannot hold f64) — the
-        # explicit, counted successor of the old silent fallback
-        self.f64_folds = 0
-        # grouped morsels whose float key held a NaN or -0.0, folded by the
-        # per-op path instead of the fused one
+        # grouped morsels whose float key held a NaN or -0.0, folded on the
+        # numpy path instead of the fused one
         self.key_rejects = 0
+        # never incremented; kept because the chip benchmark's harness
+        # reads them
+        self.kernel_calls = 0
+        self.f64_folds = 0
 
     def _ops(self):
         """Import the jit'd kernel wrappers once and place the compile
@@ -301,7 +198,7 @@ class PallasBackend(ComputeBackend):
         return self._kernel_mod
 
 
-# -- fused filter+select ----------------------------------------------------
+# -- filter predicates -------------------------------------------------------
 _CMP_OPS = {"lt", "le", "gt", "ge", "eq", "ne"}
 _PRED_KINDS = {"float32": "f32", "int32": "i32", "int64": "i64"}
 _INT32_SIGN = 0x80000000
@@ -363,86 +260,7 @@ def _normalize_threshold(t, dtype_name: str, op: str):
     return None
 
 
-def _fused_plan(batch: RecordBatch, predicate: Expr, columns: list):
-    """Eligibility check for the Pallas fused kernel.  Returns
-    ``(op, kind, t_hi, t_lo, pred_name)`` or ``None`` (→ numpy fallback)."""
-    if not (
-        isinstance(predicate, Expr)
-        and predicate.op in _CMP_OPS
-        and isinstance(predicate.args[0], Expr)
-        and predicate.args[0].op == "col"
-        and isinstance(predicate.args[1], Expr)
-        and predicate.args[1].op == "lit"
-    ):
-        return None
-    pred_name = predicate.args[0].args[0]
-    schema = batch.schema
-    if pred_name not in schema:
-        return None
-    pf = schema.field(pred_name)
-    if pf.dtype.name not in _PRED_KINDS or batch.column(pred_name).validity is not None:
-        return None
-    norm = _normalize_threshold(predicate.args[1].args[0], pf.dtype.name, predicate.op)
-    if norm is None:
-        return None
-    kind, op, t_hi, t_lo = norm
-    for name in columns:
-        if name not in schema:
-            return None
-        f = schema.field(name)
-        if f.dtype.is_varwidth or batch.column(name).validity is not None:
-            return None
-    return op, kind, t_hi, t_lo, pred_name
-
-
-@register_kernel("pallas", "filter_select")
-def _pl_filter_select(bk: PallasBackend, batch: RecordBatch, predicate: Expr, columns: list):
-    plan = _fused_plan(batch, predicate, columns)
-    if plan is None or batch.num_rows == 0:
-        return _np_filter_select(bk, batch, predicate, columns)
-    op, kind, t_hi, t_lo, pred_name = plan
-    tile = bk.tile
-    n = batch.num_rows
-    n_pad = -(-n // tile) * tile
-    out_schema = batch.schema.select(columns)
-    pred_planes = _col_planes(batch.column(pred_name).values, batch.schema.field(pred_name).dtype.name)
-    pred_arr = np.zeros((n_pad, len(pred_planes)), np.int32)
-    for j, p in enumerate(pred_planes):
-        pred_arr[:n, j] = p
-    spans = []  # (plane start, plane count) per output column
-    pos = 0
-    for f in out_schema:
-        k = _plane_count(f.dtype.name)
-        spans.append((pos, k))
-        pos += k
-    table = np.zeros((n_pad, pos), np.int32)
-    for f, (start, _k) in zip(out_schema, spans):
-        for j, p in enumerate(_col_planes(batch.column(f.name).values, f.dtype.name)):
-            table[:n, start + j] = p
-    t_hi_bits = int(np.array([t_hi], np.float32).view(np.int32)[0]) if kind == "f32" else int(t_hi)
-    scalars = np.asarray([n, t_hi_bits, int(t_lo)], np.int32)
-    out, counts = bk._ops().filter_select_planes(pred_arr, table, scalars, op, kind, tile=tile)
-    bk.kernel_calls += 1
-    counts = np.asarray(counts)
-    n_sel = int(counts.sum())
-    if n_sel == 0:
-        return None
-    out = np.asarray(out)
-    compact = np.concatenate([out[i * tile : i * tile + int(c)] for i, c in enumerate(counts) if c])
-    cols = [
-        Column(f.dtype, values=_planes_to_values(compact[:, start : start + k], f.dtype))
-        for f, (start, k) in zip(out_schema, spans)
-    ]
-    return RecordBatch(out_schema, cols)
-
-
-@register_kernel("pallas", "filter")
-def _pl_filter(bk: PallasBackend, batch: RecordBatch, predicate: Expr):
-    # the unfused form projects every column through the plane kernel
-    return _pl_filter_select(bk, batch, predicate, list(batch.schema.names))
-
-
-# -- fused project arithmetic ----------------------------------------------
+# -- project arithmetic ------------------------------------------------------
 # int div/mod promote to float64 in numpy; a TPU's float32 division is not
 # correctly rounded (off by an ulp on about a third of ordinary operands)
 _ARITH = {"add", "sub", "mul"}
@@ -494,108 +312,7 @@ def _is_pow2_f32(v) -> bool:
     return v32 != 0.0 and math.isfinite(v32) and abs(math.frexp(v32)[0]) == 0.5
 
 
-def _arith_descr(e, batch: RecordBatch, group: str, col_idx: dict):
-    """Lower an Expr subtree to a kernel descriptor, interning column
-    indices into ``col_idx``.  Returns None when any node falls outside the
-    kernel envelope for ``group`` ("float32" | "int32")."""
-    if not isinstance(e, Expr):
-        return None
-    if e.op == "col":
-        name = e.args[0]
-        if name not in batch.schema:
-            return None
-        f = batch.schema.field(name)
-        if f.dtype.name != group or batch.column(name).validity is not None:
-            return None
-        if name not in col_idx:
-            col_idx[name] = len(col_idx)
-        return ("col", col_idx[name])
-    if e.op == "lit":
-        v = e.args[0]
-        if isinstance(v, (bool, np.bool_)):
-            return None
-        if group == "float32":
-            v = _f32_lit(v)
-            return None if v is None else ("lit", v)
-        if isinstance(v, (int, np.integer)) and not isinstance(v, np.uint64):
-            vi = int(v)
-            if isinstance(v, np.int64) or not (-(2**31) <= vi <= 2**31 - 1):
-                return None  # would promote to int64 (or raise) in numpy
-            return ("lit", vi)
-        return None
-    if e.op not in _ARITH or len(e.args) != 2:
-        return None
-    a = _arith_descr(e.args[0], batch, group, col_idx)
-    if a is None:
-        return None
-    b = _arith_descr(e.args[1], batch, group, col_idx)
-    if b is None:
-        return None
-    if group == "float32" and not _contraction_safe(e.op, a, b):
-        return None
-    return (e.op, a, b)
-
-
-@register_kernel("pallas", "project")
-def _pl_project(bk: PallasBackend, batch: RecordBatch, exprs: dict, out_schema):
-    from repro.core.operators import project_morsel
-
-    if batch.num_rows == 0:
-        return project_morsel(batch, exprs, out_schema)
-    # plan each expression independently (per-column eligibility)
-    groups: dict = {}  # group dtype -> (col_idx, [(out name, descr)])
-    for name, e in exprs.items():
-        f = out_schema.field(name)
-        if f.dtype.name not in ("float32", "int32"):
-            continue
-        group = f.dtype.name
-        col_idx = groups.setdefault(group, ({}, []))[0]
-        snapshot = dict(col_idx)
-        descr = _arith_descr(e, batch, group, col_idx)
-        if descr is None or descr[0] in ("col", "lit"):
-            col_idx.clear()
-            col_idx.update(snapshot)  # drop columns interned by the failed plan
-            continue
-        groups[group][1].append((name, descr))
-    planned = {name: None for g in groups.values() for name, _ in g[1]}
-    if not planned:
-        return project_morsel(batch, exprs, out_schema)
-    n = batch.num_rows
-    tile = bk.tile
-    n_pad = -(-n // tile) * tile
-    kernel_ops = bk._ops()
-    for group, (col_idx, outs) in groups.items():
-        if not outs:
-            continue
-        np_dt = np.dtype(group)
-        table = np.zeros((n_pad, max(1, len(col_idx))), np_dt)
-        for cname, j in col_idx.items():
-            table[:n, j] = batch.column(cname).values
-        res = np.asarray(kernel_ops.project_tiles(table, tuple(d for _, d in outs), tile=tile))
-        bk.kernel_calls += 1
-        if res[:n, -1].any():  # float32 arithmetic left the exact envelope
-            bk.envelope_rejects += 1
-            return project_morsel(batch, exprs, out_schema)
-        for j, (name, _d) in enumerate(outs):
-            planned[name] = np.ascontiguousarray(res[:n, j])
-    # assemble exactly like the reference evaluator: kernel outputs for the
-    # planned exprs, numpy evaluation (+dtype coercion) for the rest
-    new_cols = {}
-    for name, e in exprs.items():
-        f = out_schema.field(name)
-        vals = planned.get(name)
-        if vals is None:
-            vals = np.asarray(e.evaluate(batch))
-            if vals.ndim == 0:
-                vals = np.full(batch.num_rows, vals[()])
-            if not f.dtype.is_varwidth and vals.dtype != f.dtype.np_dtype:
-                vals = vals.astype(f.dtype.np_dtype)
-        new_cols[name] = Column.from_values(f.dtype, vals)
-    cols = [new_cols[f.name] if f.name in new_cols else batch.column(f.name) for f in out_schema]
-    return RecordBatch(out_schema, cols)
-
-
-# -- segment reductions (partial aggregation) -------------------------------
+# -- aggregate folds ---------------------------------------------------------
 _SEG_GROUP_CAP = 256
 _SUM_LIMBS = 8  # 8-bit limbs, int64 coverage
 
@@ -623,286 +340,13 @@ def _f32_mm_ok(values: np.ndarray) -> bool:
     return bool(np.isfinite(values).all()) and not ((values == 0.0) & np.signbit(values)).any()
 
 
-def _mm_eligible(values: np.ndarray, kind: str):
-    """Kernel-ready min/max column or None.  float32 must pass
-    :func:`_f32_mm_ok`; integers must fit int32."""
-    dt = values.dtype
-    if dt == np.float32:
-        return values if _f32_mm_ok(values) else None
-    if dt.kind == "b" or (dt.kind == "i" and dt.itemsize <= 4) or (dt.kind == "u" and dt.itemsize <= 2):
-        return values.astype(np.int32)
-    return None
-
-
-_I64_MAX = np.int64(2**63 - 1)
-_I64_MIN = np.int64(-(2**63))
-_U64_TOP = np.uint64(1 << 63)
-_F64_LOW63 = np.int64(0x7FFFFFFFFFFFFFFF)
-
-
-def _decode_i64(arr: np.ndarray, fn: str) -> np.ndarray:
-    return arr  # empty-group sentinels (int64 extremes) ARE the identities
-
-
-def _decode_u64(arr: np.ndarray, fn: str) -> np.ndarray:
-    # inverse of the top-bit flip; the min sentinel int64-max decodes to
-    # uint64-max and the max sentinel int64-min to 0 — the uint64 identities
-    return arr.view(np.uint64) ^ _U64_TOP
-
-
-def _decode_f64(arr: np.ndarray, fn: str) -> np.ndarray:
-    # empty-group sentinels are unreachable from (non-NaN) float bits —
-    # substitute the float identities before inverting the order map
-    arr = arr.copy()
-    if fn == "min":
-        sent = arr == _I64_MAX
-        inf = np.float64(np.inf)
-    else:
-        sent = arr == _I64_MIN
-        inf = np.float64(-np.inf)
-    bits = np.where(arr >= 0, arr, arr ^ _F64_LOW63)
-    out = bits.view(np.float64).copy()
-    out[sent] = inf
-    return out
-
-
-def _mm_wide_eligible(values: np.ndarray):
-    """``(int64 order keys, decoder)`` for the two-word min/max path, or
-    None.  The keys are an order-preserving int64 image of the column, fed
-    through two ``segment_minmax_tiles`` passes (signed hi words, then
-    sign-flipped lo words); the decoder maps group extremes (and the
-    empty-group sentinels) back to the column dtype:
-
-      * int64   — identity (sentinels are already the int64 identities)
-      * uint32  — widens exactly into int64
-      * uint64  — top-bit flip: ``u ^ 2^63`` viewed signed orders as uint64
-      * float64 — sign-magnitude fold: non-negative bit patterns order as
-        floats already; negative ones have all low 63 bits flipped.  NaN is
-        ineligible (total order ≠ numpy's NaN propagation) and so is -0.0
-        (bitwise total order would distinguish it from +0.0 where numpy's
-        min/max result depends on operand order); ±Inf are fine.
-    """
-    dt = values.dtype
-    if dt.kind == "i" and dt.itemsize == 8:
-        return values, _decode_i64
-    if dt.kind == "u" and dt.itemsize == 4:
-        return values.astype(np.int64), _decode_i64
-    if dt.kind == "u" and dt.itemsize == 8:
-        return (values ^ _U64_TOP).view(np.int64), _decode_u64
-    if dt == np.float64:
-        if np.isnan(values).any() or ((values == 0.0) & np.signbit(values)).any():
-            return None
-        b = values.view(np.int64)
-        return np.where(b >= 0, b, b ^ _F64_LOW63), _decode_f64
-    return None
-
-
-_LO_SIGN = np.uint32(0x80000000)
-
-
-def _wide_words(v64: np.ndarray):
-    """(hi, lo') int32 words of an int64 column whose lexicographic
-    (signed hi, signed lo') order equals the int64 order: hi is the signed
-    top word, lo' the sign-flipped low word."""
-    hi = (v64 >> np.int64(32)).astype(np.int32)
-    lo = ((v64 & np.int64(0xFFFFFFFF)).astype(np.uint32) ^ _LO_SIGN).view(np.int32)
-    return hi, lo
-
-
-def _wide_decode(hi: np.ndarray, lo_s: np.ndarray) -> np.ndarray:
-    lo_u = (lo_s.view(np.uint32) ^ _LO_SIGN).astype(np.int64)
-    return (hi.astype(np.int64) << np.int64(32)) | lo_u
-
-
-@register_kernel("pallas", "segment_reduce")
-def _pl_segment_reduce(bk: PallasBackend, gidx, ngroups, specs, n_rows) -> dict:
-    kernel_ops = bk._ops()
-    if ngroups == 0 or n_rows > kernel_ops.SUM_ROW_CAP or n_rows == 0:
-        return {}
-    sums: list = []  # (state name, values)
-    fsums: list = []  # (state name, f64 values) — host f64 reference path
-    mms: dict = {"f32": [], "i32": []}  # kind -> [(state name, fn, col)]
-    wides: list = []  # (state name, fn, int64 keys, decoder) — two-word min/max
-    count_names: list = []
-    for name, fn, values in specs:
-        if fn == "count":
-            count_names.append(name)
-        elif fn == "fsum":
-            fsums.append((name, values))
-        elif fn == "sum":
-            if values is not None and values.dtype.kind in "iub":
-                sums.append((name, values))
-        elif fn in ("min", "max") and values is not None:
-            col = _mm_eligible(values, fn)
-            if col is not None:
-                mms["f32" if col.dtype == np.float32 else "i32"].append((name, fn, col))
-            else:
-                wide = _mm_wide_eligible(values)
-                if wide is not None:
-                    wides.append((name, fn, wide[0], wide[1]))
-    if not (sums or count_names or mms["f32"] or mms["i32"] or wides or fsums):
-        return {}
-    if ngroups > _SEG_GROUP_CAP:
-        out = _windowed_segment_reduce(bk, kernel_ops, np.asarray(gidx, np.int64)[:n_rows], ngroups, sums, count_names, mms)
-        if fsums:
-            out.update(_f64_fold(bk, gidx, ngroups, fsums))
-        return out
-    tile = bk.tile
-    n_pad = -(-n_rows // tile) * tile
-    g_pad = -(-ngroups // 8) * 8
-    g32 = np.zeros(n_pad, np.int32)
-    g32[:n_rows] = np.asarray(gidx, np.int64)[:n_rows]
-    out: dict = {}
-    kernel_used = False
-    if sums or count_names:
-        limb_tbl = np.zeros((n_pad, max(1, _SUM_LIMBS * len(sums))), np.int32)
-        for i, (_name, values) in enumerate(sums):
-            for k, limb in enumerate(_sum_limbs(values)):
-                limb_tbl[:n_rows, _SUM_LIMBS * i + k] = limb
-        s_res, c_res = kernel_ops.segment_sum_tiles(g32, limb_tbl, n_rows, g_pad, tile=tile)
-        s_res, c_res = np.asarray(s_res), np.asarray(c_res)
-        for i, (name, _values) in enumerate(sums):
-            out[name] = _limbs_to_int64(s_res[:ngroups, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)])
-        for name in count_names:
-            out[name] = c_res[:ngroups].astype(np.int64)
-        kernel_used = True
-    for kind, entries in mms.items():
-        if not entries:
-            continue
-        np_dt = np.float32 if kind == "f32" else np.int32
-        tbl = np.zeros((n_pad, len(entries)), np_dt)
-        for j, (_name, _fn, col) in enumerate(entries):
-            tbl[:n_rows, j] = col
-        fns = tuple(fn for _n, fn, _c in entries)
-        res = np.asarray(kernel_ops.segment_minmax_tiles(g32, tbl, n_rows, g_pad, fns, tile=tile))
-        for j, (name, _fn, _c) in enumerate(entries):
-            out[name] = np.ascontiguousarray(res[:ngroups, j])
-        kernel_used = True
-    if wides:
-        # two-word compare: pass 1 reduces the signed hi words; pass 2
-        # reduces the sign-flipped lo words among only the rows whose hi
-        # word equals their group's extreme (others masked to the
-        # identity sentinel).  Lexicographic (hi, lo') == int64 order on
-        # the order-preserving keys; each column's decoder maps the
-        # extremes (and the empty-group sentinels) back to the source
-        # dtype — int64/uint32 directly, uint64/float64 by inverting
-        # their monotone int64 image (see ``_mm_wide_eligible``).
-        fns = tuple(fn for _n, fn, _c, _d in wides)
-        hi_tbl = np.zeros((n_pad, len(wides)), np.int32)
-        lo_cols = []
-        for j, (_name, _fn, col, _dec) in enumerate(wides):
-            hi, lo = _wide_words(col)
-            hi_tbl[:n_rows, j] = hi
-            lo_cols.append((hi, lo))
-        h_res = np.asarray(kernel_ops.segment_minmax_tiles(g32, hi_tbl, n_rows, g_pad, fns, tile=tile))
-        lo_tbl = np.empty((n_pad, len(wides)), np.int32)
-        for j, (_name, fn, _col, _dec) in enumerate(wides):
-            sent = np.int32(2**31 - 1) if fn == "min" else np.int32(-(2**31))
-            lo_tbl[:, j] = sent
-            hi, lo = lo_cols[j]
-            at_extreme = hi == h_res[:, j][g32[:n_rows]]
-            lo_tbl[:n_rows, j] = np.where(at_extreme, lo, sent)
-        l_res = np.asarray(kernel_ops.segment_minmax_tiles(g32, lo_tbl, n_rows, g_pad, fns, tile=tile))
-        for j, (name, fn, _col, decode) in enumerate(wides):
-            keys64 = _wide_decode(h_res[:ngroups, j], np.ascontiguousarray(l_res[:ngroups, j]))
-            out[name] = decode(keys64, fn)
-        kernel_used = True
-    if kernel_used:
-        bk.kernel_calls += 1
-    out.update(_f64_fold(bk, gidx, ngroups, fsums))
-    return out
-
-
-def _f64_fold(bk: PallasBackend, gidx, ngroups: int, fsums: list) -> dict:
-    """The f64-accumulating reference path of float sums: bit-identical to
-    the numpy scatter because a fresh state's accumulators start at +0.0
-    and np.add.at adds this morsel's values in the same row order."""
-    out = {}
-    for name, values in fsums:
-        acc = np.zeros(ngroups, np.float64)
-        np.add.at(acc, np.asarray(gidx, np.int64), np.asarray(values, np.float64))
-        out[name] = acc
-    if fsums:
-        bk.f64_folds += len(fsums)
-    return out
-
-
-def _windowed_segment_reduce(bk: PallasBackend, kernel_ops, gidx, ngroups: int, sums, count_names, mms) -> dict:
-    """Counts, integer sums and int32 / float32 min/max of more than
-    ``_SEG_GROUP_CAP`` groups: the rows sorted by group, through the fused
-    kernel's windowed fold with no filter (wide min/max stay with numpy).
-    Groups without rows here get the identities (0, sentinels)."""
-    if not (sums or count_names or mms["f32"] or mms["i32"]):
-        return {}
-    tile = bk.tile
-    n = gidx.size
-    n_pad = -(-n // tile) * tile
-    present, local = np.unique(gidx, return_inverse=True)
-    order = np.argsort(local, kind="stable")
-    g32 = np.zeros(n_pad, np.int32)
-    g32[:n] = local[order]
-    steps, live = _window_steps(g32[:n], n_pad, tile)
-    limb = np.zeros((n_pad, max(1, _SUM_LIMBS * len(sums))), np.int32)
-    for i, (_name, values) in enumerate(sums):
-        for k, plane in enumerate(_sum_limbs(values[order])):
-            limb[:n, _SUM_LIMBS * i + k] = plane
-    tables = {}
-    for kind, dt in (("f32", np.float32), ("i32", np.int32)):
-        tables[kind] = np.zeros((n_pad, max(1, len(mms[kind]))), dt)
-        for j, (_name, _fn, col) in enumerate(mms[kind]):
-            tables[kind][:n, j] = col[order]
-    fns = {kind: tuple(fn for _n, fn, _c in mms[kind]) or ("min",) for kind in tables}
-    dummy = np.zeros((n_pad, 1), np.int32)
-    res = kernel_ops.fused_chain_tiles(
-        np.asarray([n, 0, 0, live], np.int32),
-        dummy,
-        g32,
-        dummy,
-        limb,
-        tables["f32"],
-        tables["i32"],
-        dummy.astype(np.float32),
-        dummy,
-        steps,
-        op="gt",
-        kind="none",
-        descrs_f=(),
-        descrs_i=(),
-        csums=(),
-        fns_f=fns["f32"],
-        fns_i=fns["i32"],
-        with_gidx=False,
-        segmented=True,
-        ngroups=tile,
-        tile=tile,
-    )
-    bk.kernel_calls += 1
-    _ctab, _counts, gsum, gcnt, gmmf, gmmi, _gfirst = [np.asarray(r)[: present.size] for r in res]
-    out: dict = {}
-    for i, (name, _values) in enumerate(sums):
-        out[name] = np.zeros(ngroups, np.int64)
-        out[name][present] = _limbs_to_int64(gsum[:, _SUM_LIMBS * i : _SUM_LIMBS * (i + 1)])
-    for name in count_names:
-        out[name] = np.zeros(ngroups, np.int64)
-        out[name][present] = gcnt
-    for kind, got in (("f32", gmmf), ("i32", gmmi)):
-        for j, (name, fn, _col) in enumerate(mms[kind]):
-            if kind == "f32":
-                ident = np.float32(np.inf if fn == "min" else -np.inf)
-            else:
-                ident = np.int32(2**31 - 1 if fn == "min" else -(2**31))
-            out[name] = np.full(ngroups, ident, got.dtype)
-            out[name][present] = got[:, j]
-    return out
-
-
 # ---------------------------------------------------------------------------
 # whole-chain fused pipelines: one launch per morsel
 # ---------------------------------------------------------------------------
 # Sentinel returned by FusedChainPlan.run/.fold when THIS morsel falls
-# outside the compiled envelope (validity mask appeared, row/group caps
-# exceeded, non-finite min/max input); the caller falls back to the per-op
-# path for that morsel only.
+# outside the compiled envelope (validity mask appeared, row cap exceeded,
+# non-finite min/max input); the caller runs the numpy operators on that
+# morsel only.
 FUSED_INELIGIBLE = object()
 
 # float keys the fused fold does not take: its NaN / -0.0 check per morsel
@@ -911,8 +355,8 @@ _WIDE_FLOAT_KEYS = {"float16", "float64"}
 
 
 def _lit_value(v, group: str):
-    """Literal eligibility for fused arithmetic — same envelope as
-    ``_arith_descr`` (numpy promotion parity for the given group dtype)."""
+    """A fused arithmetic literal for the given group dtype, or None where
+    the kernel could not keep numpy's promotion."""
     if isinstance(v, (bool, np.bool_)):
         return None
     if group == "float32":
@@ -920,7 +364,7 @@ def _lit_value(v, group: str):
     if isinstance(v, (int, np.integer)) and not isinstance(v, np.uint64):
         vi = int(v)
         if isinstance(v, np.int64) or not (-(2**31) <= vi <= 2**31 - 1):
-            return None
+            return None  # would promote to int64 (or raise) in numpy
         return vi
     return None
 
@@ -999,7 +443,7 @@ def _intern_tree(tree, idx: dict):
 def plan_fused_chain(specs: list, in_schema, agg=None, backend=None):
     """Compile a pipeline's op-spec chain into a :class:`FusedChainPlan`
     (one ``fused_chain_tiles`` launch per morsel), or None when any link
-    falls outside the kernel envelope (→ the per-op path runs unchanged).
+    falls outside the kernel envelope (→ the numpy operators run it).
 
     ``specs`` is the executor's ``[(kind, args), ...]`` chain.  Eligible
     chains are any combination of at most one ``filter`` (predicate
@@ -1013,9 +457,9 @@ def plan_fused_chain(specs: list, in_schema, agg=None, backend=None):
     min/max, and float sums via compacted planes + the host's f64 fold.
     float32 keys are eligible: a morsel whose float key column holds a NaN
     or a ``-0.0`` (where the pre-filter factorization could pick another
-    representative than the reference's post-filter one) runs the per-op
-    path instead, counted in ``PallasBackend.key_rejects``.  float64 keys,
-    wide min/max and var-width outputs are ineligible.
+    representative than the reference's post-filter one) runs the numpy
+    operators instead, counted in ``PallasBackend.key_rejects``.  float64
+    keys, wide min/max and var-width outputs are ineligible.
     """
     if backend is None or getattr(backend, "name", None) != "pallas":
         return None
@@ -1225,7 +669,7 @@ class FusedChainPlan:
     """Runtime for a compiled device-resident pipeline (see
     :func:`plan_fused_chain`).  ``run`` streams one morsel through the
     filter/project chain; ``fold`` additionally produces the per-morsel
-    partial ``GroupState`` — byte-identical to the reference per-op fold.
+    partial ``GroupState`` — byte-identical to the numpy fold.
     ``stage`` pre-uploads a morsel's kernel inputs (double buffering: the
     H2D transfer of morsel *i+1* overlaps the compute of morsel *i*);
     staged buffers are torn down by ``clear_staged`` on pipeline exit or
@@ -1419,8 +863,7 @@ class FusedChainPlan:
 
     def _left_envelope(self, compact: np.ndarray) -> bool:
         """Whether a surviving row's float32 arithmetic left the kernels'
-        exact envelope; the morsel then runs the per-op path, where the
-        projection falls to the numpy reference."""
+        exact envelope; the morsel then runs the numpy operators."""
         if self._flag_off is None or not compact[:, self._flag_off].any():
             return False
         self._bk.envelope_rejects += 1
@@ -1495,8 +938,8 @@ class FusedChainPlan:
     # -- aggregate fold --------------------------------------------------------
     def fold(self, batch: RecordBatch):
         """Per-morsel partial aggregate in one launch.  Returns a
-        ``GroupState`` byte-identical to the reference per-op fold over the
-        filtered morsel, None (no surviving rows), or ``FUSED_INELIGIBLE``.
+        ``GroupState`` byte-identical to the numpy fold over the filtered
+        morsel, None (no surviving rows), or ``FUSED_INELIGIBLE``.
         Group ids come from factorizing the PRE-filter morsel; the kernel's
         per-group minimum surviving row index reorders the survivors into
         first-seen-filtered order, matching the reference interning.  Up to
@@ -1563,9 +1006,7 @@ class FusedChainPlan:
             if order is not None:
                 first = order[first]  # rows of the sorted layout back to the morsel's rows
             perm = alive[np.argsort(first, kind="stable")]
-            st = GroupState(
-                self._agg_keys, self._aggs, self._mode, self._agg_schema, vectorized=True, backend=self._bk
-            )
+            st = GroupState(self._agg_keys, self._aggs, self._mode, self._agg_schema, vectorized=True)
             st.set_keys(tmp, perm)
             st.map_s = tmp.map_s
             acc: dict = {}

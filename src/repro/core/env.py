@@ -108,8 +108,8 @@ _register(
     "devices",
     None,
     "Jax device indices that pallas pipelines round-robin across: fused "
-    "launches, staged uploads and per-op kernels (default: jax's default "
-    "device; an index past the host's devices fails the run).",
+    "launches and staged uploads (default: jax's default device; an index "
+    "past the host's devices fails the run).",
 )
 _register(
     "DACP_SCAN_WORKERS",

@@ -32,12 +32,12 @@ at stage activation, so scans and cross-domain exchange pulls overlap with
 compute — and union branches pull their exchanges concurrently instead of
 serially (the scheduler's network/compute overlap).
 
-Compute is delegated to a pluggable backend (``repro.core.backend``):
-adjacent Filter→Select pairs are peephole-fused into the backend's
-``filter_select`` kernel, projection arithmetic runs through the backend's
-``project`` kernel, and aggregate folds hand factorized morsels to the
-backend's ``segment_reduce`` kernel — the pallas backend dispatches each to
-the TPU kernels in ``repro.kernels`` when the morsel is eligible.
+Compute is delegated to a backend (``repro.core.backend``): on pallas,
+a pipeline whose chain (and, for an aggregate, its per-morsel fold) fits
+the fused kernel's envelope runs each morsel as one ``fused_chain_tiles``
+launch on the TPU; a chain the plan declines, and a morsel it gives back,
+run the numpy operators of ``repro.core.operators``, as on the numpy
+backend.
 
 Morsel sizing is either static (``morsel_rows=N``: byte-deterministic
 output for a given N regardless of worker count) or adaptive
@@ -97,6 +97,7 @@ from repro.core.operators import (
     join_probe_morsel,
     join_schema,
     map_morsel,
+    project_morsel,
     project_schema,
     select_morsel,
 )
@@ -160,8 +161,8 @@ class ExecutorConfig:
                   temp dir; env ``DACP_SPILL_DIR``).
     spill_fanout  partitions per grace-hash level (≥ 2).
     devices       jax device indices that pallas pipelines round-robin
-                  across: each pipeline's fused launches, staged uploads
-                  and per-op kernels run on its device (None = jax's
+                  across: each pipeline's fused launches and staged
+                  uploads run on its device (None = jax's
                   default device; env ``DACP_DEVICES`` as a comma-separated
                   list).  An index past the host's devices fails the run.
     """
@@ -527,7 +528,7 @@ class _Branch:
 def _apply_ops(cops, batch: RecordBatch) -> RecordBatch | None:
     """Apply a compiled ``(ops, plan)`` chain to one morsel.  A fused plan
     runs the whole chain in one device launch; a morsel outside the kernel
-    envelope (nulls, overflow rows) falls back to the per-op closures,
+    envelope (nulls, overflow rows) runs the numpy closures instead,
     byte-identically."""
     ops, plan = cops
     if plan is not None:
@@ -606,20 +607,11 @@ def _branch_items(cops, batches, sizer: _MorselSizer, cfg: ExecutorConfig, do_st
 _device_rr = itertools.count()  # round-robin cursor over cfg.devices
 
 
-def _next_device(cfg: ExecutorConfig, backend: ComputeBackend):
-    """The next pipeline's jax device, or None (jax's default device)."""
-    if not cfg.devices or backend.name != "pallas":
+def _next_device(cfg: ExecutorConfig):
+    """The next fused pipeline's jax device, or None (jax's default device)."""
+    if not cfg.devices:
         return None
     return resolve_device(cfg.devices[next(_device_rr) % len(cfg.devices)])
-
-
-def _on_device(dev):
-    """Run a morsel's per-op kernels on its pipeline's device."""
-    if dev is None:
-        return contextlib.nullcontext()
-    import jax
-
-    return jax.default_device(dev)
 
 
 def _run_ordered(
@@ -653,22 +645,20 @@ def _run_ordered(
         window=cfg.effective_window(),
         prefetch=cfg.prefetch_batches,
     )
-    devices = [_next_device(cfg, backend) for _ in compiled]
     plans = [cops[1] for _, cops in compiled if cops[1] is not None]
-    for (_, cops), dev in zip(compiled, devices):
-        if cops[1] is not None:
-            cops[1].bind(sizer, dev)
+    for plan in plans:
+        plan.bind(sizer, _next_device(cfg))
     if stats is not None:
         stats.attach(sizer)  # live progress (flow STATUS) before the stage ends
 
     if cfg.num_workers <= 1:
         try:
-            for (br, cops), dev in zip(compiled, devices):
+            for br, cops in compiled:
                 for m in _branch_items(cops, br.sdf.iter_batches(), sizer, cfg, do_stage=False):
                     if cancel is not None and cancel.is_set():
                         raise FlowCancelled("execution cancelled")
                     t0 = time.perf_counter()
-                    with _on_device(dev), span("dacp.morsel", rows=m.num_rows):
+                    with span("dacp.morsel", rows=m.num_rows):
                         out = make_item(cops, m)
                     sizer.observe(m.num_rows, time.perf_counter() - t0)
                     if out is not None:
@@ -686,9 +676,9 @@ def _run_ordered(
         pf.start()  # all sources (incl. every exchange pull) activate now
 
     def morsels():
-        for (_, cops), pf, dev in zip(compiled, prefetchers, devices):
+        for (_, cops), pf in zip(compiled, prefetchers):
             for m in _branch_items(cops, pf, sizer, cfg, do_stage=True):
-                yield cops, m, dev
+                yield cops, m
 
     it = morsels()
     src_lock = threading.Lock()
@@ -711,7 +701,7 @@ def _run_ordered(
                 if state["total"] is not None:
                     return
                 try:
-                    cops, m, dev = next(it)
+                    cops, m = next(it)
                 except StopIteration:
                     state["total"] = state["assigned"]
                     with cond:
@@ -728,7 +718,7 @@ def _run_ordered(
                 state["assigned"] = seq + 1
             try:
                 t0 = time.perf_counter()
-                with _on_device(dev), span("dacp.morsel", rows=m.num_rows):
+                with span("dacp.morsel", rows=m.num_rows):
                     out = make_item(cops, m)
                 sizer.observe(m.num_rows, time.perf_counter() - t0)
             except BaseException as e:  # noqa: BLE001 - surfaced to consumer
@@ -778,7 +768,7 @@ def _run_ordered(
 
 
 # ---------------------------------------------------------------------------
-# op-spec finalization (backend binding + filter→select fusion)
+# op-spec finalization
 # ---------------------------------------------------------------------------
 def _finalize_ops(specs: list, backend: ComputeBackend, in_schema: Schema | None = None, agg=None) -> tuple:
     """Turn compile-time op specs into ``(morsel closures, fused plan)``.
@@ -786,29 +776,20 @@ def _finalize_ops(specs: list, backend: ComputeBackend, in_schema: Schema | None
     When the whole chain (and, for aggregate drives, the fold) fits the
     fused-pipeline kernel envelope, ``plan`` is a
     :class:`~repro.core.backend.FusedChainPlan` that executes everything in
-    ONE device launch per morsel; the per-op closures remain the fallback
-    for morsels outside the envelope.  Independently, adjacent
-    filter+select pairs are peephole-fused into the backend's two-op
-    kernel on the per-op path."""
+    ONE device launch per morsel; the numpy closures run the morsels it
+    gives back, and every morsel when ``plan`` is None."""
     plan = plan_fused_chain(specs, in_schema, agg=agg, backend=backend) if in_schema is not None else None
     ops: list = []
-    i = 0
-    while i < len(specs):
-        kind, args = specs[i]
-        if kind == "filter" and i + 1 < len(specs) and specs[i + 1][0] == "select":
-            pred, cols = args[0], list(specs[i + 1][1][0])
-            ops.append(lambda b, _p=pred, _c=cols: backend.filter_select(b, _p, _c))
-            i += 2
-            continue
+    for kind, args in specs:
         if kind == "filter":
             pred = args[0]
-            ops.append(lambda b, _p=pred: filter_morsel(b, _p, backend))
+            ops.append(lambda b, _p=pred: filter_morsel(b, _p))
         elif kind == "select":
             cols = list(args[0])
             ops.append(lambda b, _c=cols: select_morsel(b, _c))
         elif kind == "project":
             exprs, out_schema = args
-            ops.append(lambda b, _e=exprs, _s=out_schema: backend.project(b, _e, _s))
+            ops.append(lambda b, _e=exprs, _s=out_schema: project_morsel(b, _e, _s))
         elif kind == "map":
             mf, fn_params = args
             ops.append(lambda b, _m=mf, _p=fn_params: map_morsel(b, _m, _p))
@@ -821,7 +802,6 @@ def _finalize_ops(specs: list, backend: ComputeBackend, in_schema: Schema | None
             )
         else:  # pragma: no cover - compiler invariant
             raise PlanError(f"unknown morsel op {kind!r}")
-        i += 1
     return ops, plan
 
 
@@ -977,10 +957,7 @@ class _Compiler:
             b = _apply_ops((ops, None), morsel)
             if b is None or b.num_rows == 0:
                 return None
-            # backend-aware fold: eligible aggregates run on the
-            # segment-reduce kernel once keys are factorized (pushdown R9
-            # partials on the accelerator)
-            st = GroupState(keys, aggs, mode, in_schema, vectorized=True, backend=backend)
+            st = GroupState(keys, aggs, mode, in_schema, vectorized=True)
             st.update(b)
             return st
 
@@ -1017,7 +994,6 @@ class _Compiler:
                                 in_schema,
                                 out_schema,
                                 acct,
-                                backend=backend,
                                 morsel_rows=cfg.initial_morsel_rows(),
                                 fanout=cfg.spill_fanout,
                                 spill_dir=cfg.spill_dir,

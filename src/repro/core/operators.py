@@ -131,12 +131,9 @@ register_map("lowercase", reads=("*",), writes=())(_lowercase)
 # morsel-pure operator functions (shared by the pull chain and the parallel
 # executor; each maps one batch -> one batch or None, no cross-batch state)
 # ---------------------------------------------------------------------------
-def filter_morsel(batch: RecordBatch, predicate: Expr, backend=None) -> RecordBatch | None:
+def filter_morsel(batch: RecordBatch, predicate: Expr) -> RecordBatch | None:
     """Surviving rows of one morsel, or None when fully masked (no empty
-    frames downstream).  ``backend`` dispatches eligible morsels to
-    accelerator kernels; None means the numpy reference path."""
-    if backend is not None:
-        return backend.filter(batch, predicate)
+    frames downstream)."""
     mask = np.asarray(predicate.evaluate(batch), dtype=bool)
     if mask.all():
         return batch
@@ -436,12 +433,6 @@ class GroupState:
     state per morsel and merges them in morsel order, so the grouped output
     is deterministic regardless of worker count.
 
-    ``backend`` (a ``ComputeBackend``) lets the per-batch fold dispatch to
-    the backend's ``segment_reduce`` kernel once the keys are factorized:
-    eligible aggregates (counts, integer sums, int32/finite-f32 min/max)
-    fold on the accelerator, the rest scatter with numpy — bit-identical
-    either way, so a ``None`` backend is the reference semantics.
-
     ``map_s`` is the host time spent mapping keys to group ids (span
     ``dacp.agg.map``).
     """
@@ -453,13 +444,11 @@ class GroupState:
         mode: str,
         in_schema: Schema,
         vectorized: bool = False,
-        backend=None,
     ):
         self.keys = keys
         self.aggs = aggs
         self.mode = mode
         self.in_schema = in_schema
-        self.backend = backend
         self.vectorized = vectorized and all(not in_schema.field(k).dtype.is_varwidth for k in keys)
         self.ngroups = 0
         self.map_s = 0.0
@@ -634,56 +623,13 @@ class GroupState:
             if len(cur) < ngroups:
                 self.acc[name] = np.concatenate([cur, np.full(ngroups - len(cur), init, dt)])
 
-    def _kernel_specs(self, batch: RecordBatch, fresh: bool = False) -> list:
-        """(state name, fn, values) triples for ``backend.segment_reduce``.
-        The backend accelerates the subset it can reproduce bit-exactly and
-        ``update`` scatters the remainder with numpy.
-
-        Float sums (and mean partial sums) are tagged ``fsum`` when the
-        state is ``fresh`` (no groups yet — the executor's per-morsel fold):
-        starting from +0.0 accumulators, a backend may fold them in its
-        f64-accumulating reference path bit-identically.  A reused state
-        keeps the plain ``sum`` tag (sequential ``np.add.at`` into non-zero
-        accumulators has no order-free equivalent), which backends ignore.
-        """
-        specs = []
-        for out, spec in self.aggs.items():
-            fn = spec["fn"]
-            if fn == "count":
-                if self.mode == "final":
-                    specs.append((out, "sum", np.asarray(batch.column(out).values)))
-                else:
-                    specs.append((out, "count", None))
-            elif fn == "mean":
-                # psum folds in float64 — fresh states expose it as an
-                # ``fsum``; pcnt is a plain count (final mode: a sum of the
-                # partial counts)
-                if fresh:
-                    psrc = f"{out}__psum" if self.mode == "final" else spec["column"]
-                    specs.append((f"{out}__psum", "fsum", np.asarray(batch.column(psrc).to_numpy(), np.float64)))
-                if self.mode == "final":
-                    specs.append((f"{out}__pcnt", "sum", np.asarray(batch.column(f"{out}__pcnt").values)))
-                else:
-                    specs.append((f"{out}__pcnt", "count", None))
-            else:
-                vals = np.asarray(batch.column(_agg_src(out, spec, self.mode)).to_numpy())
-                if fn == "sum" and fresh and vals.dtype.kind == "f":
-                    specs.append((out, "fsum", np.asarray(vals, np.float64)))
-                else:
-                    specs.append((out, fn, vals))
-        return specs
-
     def update(self, batch: RecordBatch) -> None:
         n = batch.num_rows
         if n == 0:
             return
-        fresh = self.ngroups == 0
         gidx = self._factorize(batch)
         self._grow()
         ngroups = self.ngroups
-        kres: dict = {}
-        if self.backend is not None:
-            kres = self.backend.segment_reduce(gidx, ngroups, self._kernel_specs(batch, fresh), n) or {}
         counts = None
 
         def _counts():
@@ -693,13 +639,11 @@ class GroupState:
             return counts
 
         # scatter each batch's values straight into the (dtype-exact)
-        # accumulators; kernel-folded states combine vectorized instead
+        # accumulators
         for out, spec in self.aggs.items():
             fn = spec["fn"]
             if fn == "count":
-                if out in kres:
-                    self.acc[out][:ngroups] += kres[out]
-                elif self.mode == "final":
+                if self.mode == "final":
                     vals = np.asarray(batch.column(out).values, dtype=np.int64)
                     np.add.at(self.acc[out], gidx, vals)
                 else:
@@ -707,36 +651,17 @@ class GroupState:
             elif fn == "mean":
                 pc, ps = f"{out}__pcnt", f"{out}__psum"
                 if self.mode == "final":
-                    if ps in kres:
-                        self.acc[ps][:ngroups] += kres[ps]
-                    else:
-                        np.add.at(self.acc[ps], gidx, np.asarray(batch.column(ps).values, np.float64))
-                    if pc in kres:
-                        self.acc[pc][:ngroups] += kres[pc]
-                    else:
-                        np.add.at(self.acc[pc], gidx, np.asarray(batch.column(pc).values, np.int64))
+                    np.add.at(self.acc[ps], gidx, np.asarray(batch.column(ps).values, np.float64))
+                    np.add.at(self.acc[pc], gidx, np.asarray(batch.column(pc).values, np.int64))
                 else:
-                    if ps in kres:
-                        self.acc[ps][:ngroups] += kres[ps]
-                    else:
-                        vals = np.asarray(batch.column(spec["column"]).to_numpy(), dtype=np.float64)
-                        np.add.at(self.acc[ps], gidx, vals)
-                    if pc in kres:
-                        self.acc[pc][:ngroups] += kres[pc]
-                    else:
-                        self.acc[pc] += _counts()
+                    vals = np.asarray(batch.column(spec["column"]).to_numpy(), dtype=np.float64)
+                    np.add.at(self.acc[ps], gidx, vals)
+                    self.acc[pc] += _counts()
             else:  # sum / min / max
                 cur = self.acc[out]
-                if out in kres:
-                    if fn == "sum":
-                        cur[:ngroups] += kres[out]
-                    else:
-                        op = np.minimum if fn == "min" else np.maximum
-                        cur[:ngroups] = op(cur[:ngroups], kres[out].astype(cur.dtype))
-                else:
-                    vals = np.asarray(batch.column(_agg_src(out, spec, self.mode)).to_numpy()).astype(cur.dtype)
-                    op = {"sum": np.add, "min": np.minimum, "max": np.maximum}[fn]
-                    op.at(cur, gidx, vals)
+                vals = np.asarray(batch.column(_agg_src(out, spec, self.mode)).to_numpy()).astype(cur.dtype)
+                op = {"sum": np.add, "min": np.minimum, "max": np.maximum}[fn]
+                op.at(cur, gidx, vals)
 
     def merge(self, other: "GroupState") -> "GroupState":
         """Combine another partial state into this one (same keys/aggs/mode).

@@ -43,10 +43,10 @@ shared byte budget:
     in-memory probe-order by a stable sort on the row ids — byte-identical
     collected results.
 
-The Pallas ``segment_reduce`` path composes with spilling untouched: the
-per-morsel folds that produce the partial states still dispatch to the
-accelerator through the backend registry; only the (already vectorized,
-bit-exactness-critical) state *merges* stay on numpy.
+The fused device fold composes with spilling untouched: the per-morsel
+folds that produce the partial states still run on the accelerator; only
+the (already vectorized, bit-exactness-critical) state *merges* stay on
+numpy.
 """
 
 from __future__ import annotations
@@ -380,7 +380,6 @@ class GraceHashAggregate:
         in_schema: Schema,
         out_schema: Schema,
         acct: MemoryAccountant,
-        backend=None,
         morsel_rows: int = 65536,
         fanout: int = DEFAULT_SPILL_FANOUT,
         spill_dir: str | None = None,
@@ -391,7 +390,6 @@ class GraceHashAggregate:
         self.in_schema = in_schema
         self.out_schema = out_schema
         self.acct = acct
-        self.backend = backend
         self.morsel_rows = max(1, int(morsel_rows))
         self.fanout = max(2, int(fanout))
         self.spill_dir = spill_dir
@@ -484,7 +482,7 @@ class GraceHashAggregate:
         order) into a fresh GroupState; recursively re-partition when the
         partition itself exceeds the budget."""
         self.acct.note_depth(level)
-        total = GroupState(self.keys, self.aggs, self.mode, self.in_schema, vectorized=True, backend=self.backend)
+        total = GroupState(self.keys, self.aggs, self.mode, self.in_schema, vectorized=True)
         fs = np.zeros(0, np.int64)
         reserved = 0
         try:

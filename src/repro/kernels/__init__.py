@@ -1,12 +1,8 @@
 """Pallas TPU kernels (validated in interpret mode on CPU; Mosaic on TPU).
 
-    filter_select     — the paper's fused columnar Filter+Select (§IV-B);
-                        ``filter_select_planes`` is the bit-exact multi-dtype
-                        form the compute backend dispatches to
-    project_arith     — fused project-arithmetic chains compiled from Exprs
-    segment_reduce    — per-group sum/min/max/count partial aggregation
-    fused_pipeline    — whole-chain fusion: filter → project → segment fold
-                        in ONE launch per morsel (device-resident planes)
+    fused_pipeline    — the compute backend's one data-path kernel:
+                        filter → project → segment fold in ONE launch per
+                        morsel (device-resident bit-planes)
     flash_attention   — causal GQA prefill attention
     decode_attention  — split-K single-token decode (seq-shardable)
     ssd_scan          — Mamba2 SSD chunk scan
@@ -16,13 +12,9 @@
 from repro.kernels import ops, ref
 from repro.kernels.ops import (
     decode_attention,
-    filter_select_planes,
     flash_attention,
     fused_chain_tiles,
     mlstm_chunk,
-    project_tiles,
-    segment_minmax_tiles,
-    segment_sum_tiles,
     ssd_scan,
 )
 
@@ -30,11 +22,7 @@ __all__ = [
     "ops",
     "ref",
     "decode_attention",
-    "filter_select_planes",
     "fused_chain_tiles",
-    "project_tiles",
-    "segment_sum_tiles",
-    "segment_minmax_tiles",
     "flash_attention",
     "mlstm_chunk",
     "ssd_scan",

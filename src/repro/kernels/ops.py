@@ -3,8 +3,7 @@
 ``interpret`` defaults to auto: True on the CPU (the kernels execute via
 the Pallas interpreter for the correctness tests, ``JAX_PLATFORMS=cpu``),
 False everywhere else (Mosaic compilation — a platform the kernels cannot
-compile for fails loudly instead of interpreting).  Wrappers also own the
-thin jnp epilogues (e.g. global compaction after per-tile filter_select).
+compile for fails loudly instead of interpreting).
 """
 
 from __future__ import annotations
@@ -12,18 +11,13 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as ref_mod
 from repro.kernels.decode_attention import decode_attention as _decode_attention
-from repro.kernels.filter_select import filter_select_planes as _filter_select_planes
 from repro.kernels.flash_attention import flash_attention as _flash_attention
+from repro.kernels.fused_pipeline import SUM_ROW_CAP
 from repro.kernels.fused_pipeline import fused_chain_tiles as _fused_chain_tiles
 from repro.kernels.mlstm_chunk import mlstm_chunk as _mlstm_chunk
-from repro.kernels.project_arith import project_tiles as _project_tiles
-from repro.kernels.segment_reduce import SUM_ROW_CAP
-from repro.kernels.segment_reduce import segment_minmax_tiles as _segment_minmax_tiles
-from repro.kernels.segment_reduce import segment_sum_tiles as _segment_sum_tiles
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
 __all__ = [
@@ -32,11 +26,7 @@ __all__ = [
     "decode_attention",
     "ssd_scan",
     "mlstm_chunk",
-    "filter_select_planes",
     "fused_chain_tiles",
-    "project_tiles",
-    "segment_sum_tiles",
-    "segment_minmax_tiles",
     "SUM_ROW_CAP",
 ]
 
@@ -63,29 +53,6 @@ def ssd_scan(x, dt, A, B, C, chunk: int = 256):
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def mlstm_chunk(q, k, v, log_i, log_f, chunk: int = 256):
     return _mlstm_chunk(q, k, v, log_i, log_f, chunk=chunk, interpret=auto_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("op", "kind", "tile"))
-def filter_select_planes(pred_planes, table, scalars, op: str, kind: str, tile: int = 256):
-    # scalars = [n_rows, t_hi bits, t_lo bits] rides as traced data: a new
-    # predicate literal (or morsel row count) reuses the compiled kernel
-    return _filter_select_planes(
-        pred_planes, table, scalars, op=op, kind=kind, tile=tile, interpret=auto_interpret()
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("ngroups", "tile"))
-def segment_sum_tiles(gidx, limbs, n_rows, ngroups: int, tile: int = 256):
-    return _segment_sum_tiles(gidx, limbs, n_rows, ngroups, tile=tile, interpret=auto_interpret())
-
-
-@functools.partial(jax.jit, static_argnames=("ngroups", "fns", "tile"))
-def segment_minmax_tiles(gidx, vals, n_rows, ngroups: int, fns: tuple, tile: int = 256):
-    return _segment_minmax_tiles(gidx, vals, n_rows, ngroups, fns, tile=tile, interpret=auto_interpret())
-
-
-def project_tiles(table, descrs, tile: int = 256):
-    return _project_tiles(table, descrs, tile=tile, interpret=auto_interpret())
 
 
 _FUSED_STATIC = (

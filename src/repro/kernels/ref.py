@@ -11,38 +11,11 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "filter_select_ref",
     "flash_attention_ref",
     "decode_attention_ref",
     "ssd_scan_ref",
     "mlstm_chunk_ref",
 ]
-
-
-def filter_select_ref(table, pred_col: int, threshold, sel_cols, tile: int):
-    """Per-tile front-compaction (the kernel's contract).
-
-    table: (N, D) f32;  predicate: table[:, pred_col] > threshold.
-    Returns (out (N, len(sel_cols)) with selected rows compacted to the front
-    of each ``tile``-row tile, zeros elsewhere; counts (N//tile,) int32).
-    """
-    n, _ = table.shape
-    assert n % tile == 0
-    sel = jnp.asarray(sel_cols)
-    mask = table[:, pred_col] > threshold
-    tiles = n // tile
-    tmask = mask.reshape(tiles, tile)
-    trows = table[:, sel].reshape(tiles, tile, len(sel_cols))
-    counts = tmask.sum(axis=1).astype(jnp.int32)
-
-    def compact(rows, m):
-        pos = jnp.cumsum(m) - 1
-        out = jnp.zeros_like(rows)
-        out = out.at[jnp.where(m, pos, tile - 1)].add(jnp.where(m[:, None], rows, 0.0))
-        return out
-
-    out = jax.vmap(compact)(trows, tmask).reshape(n, len(sel_cols))
-    return out, counts
 
 
 def flash_attention_ref(q, k, v, causal: bool = True):

@@ -2,23 +2,28 @@
 **byte-identical** RecordBatches — filter/select over every supported
 predicate dtype (float32/int32/int64) and comparison (< <= > >= == !=),
 multi-dtype projections (f64/i64/u8/f16/bool ride through the bit-plane
-kernel), project arithmetic, and segment-reduce aggregation — including
-``-0.0``, NaN payloads, and full-range int64.  Skipped cleanly when jax is
-absent (the pallas backend then cannot be resolved).  A kernel failure is
-never turned into a numpy answer: the last section checks that it fails
-the request instead."""
+kernel), project arithmetic, and aggregation — including ``-0.0``, NaN
+payloads, and full-range int64.  Every case runs through the executor:
+chains inside the fused kernel's envelope must run one ``fused_chain_tiles``
+launch per morsel, and chains or morsels outside it must leave the kernel
+(``plan_fused_chain`` returns None, or the morsel is ``FUSED_INELIGIBLE``)
+and still match.  Skipped cleanly when jax is absent (the pallas backend
+then cannot be resolved).  A kernel failure is never turned into a numpy
+answer: the last section checks that it fails the request instead."""
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 
-from repro.core.backend import get_backend  # noqa: E402
+from repro.core import dtypes  # noqa: E402
+from repro.core.backend import FUSED_INELIGIBLE, get_backend, plan_fused_chain  # noqa: E402
 from repro.core.batch import Column, RecordBatch  # noqa: E402
 from repro.core.dag import Dag  # noqa: E402
-from repro.core.executor import ExecutorConfig, execute_parallel  # noqa: E402
+from repro.core.executor import ExecutorConfig, ExecutorStats, execute_parallel  # noqa: E402
 from repro.core.expr import col  # noqa: E402
-from repro.core.operators import GroupState, project_schema  # noqa: E402
+from repro.core.operators import project_schema  # noqa: E402
+from repro.core.schema import Field, Schema  # noqa: E402
 from repro.core.sdf import StreamingDataFrame  # noqa: E402
 
 N_ROWS = 700  # spans multiple kernel tiles (256) incl. a ragged tail
@@ -79,6 +84,49 @@ def _run(dag, batch, backend):
     return execute_parallel(dag, lambda n: _sdf(batch), cfg).collect()
 
 
+def _fused_run(dag, batch, backend_name, **cfg_kw):
+    stats = ExecutorStats()
+    cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend=backend_name, **cfg_kw)
+    out = execute_parallel(dag, lambda n: _sdf(batch), cfg, stats=stats).collect()
+    return out, stats
+
+
+def _chain(*links):
+    """A DAG of ``(op, params)`` links over one source."""
+    bld = Dag.build()
+    node = bld.source("dacp://h:1/d")
+    for op, params in links:
+        node = bld.add(op, params, [node])
+    return bld.finish(node)
+
+
+def _filter_select(pred, cols):
+    return _chain(("filter", {"predicate": pred}), ("select", {"columns": cols}))
+
+
+def _aggregate(keys, aggs):
+    return _chain(("aggregate", {"keys": keys, "aggs": aggs}))
+
+
+def _parity(dag, batch):
+    """Run ``dag`` on both backends, assert byte-identical answers, and
+    return the pallas answer and executor progress."""
+    got, stats = _fused_run(dag, batch, "pallas")
+    ref, _ = _fused_run(dag, batch, "numpy")
+    _assert_byte_identical(got, ref)
+    return got, stats.progress()
+
+
+def _all_fused(prog):
+    """Every morsel ran as one fused launch."""
+    assert prog["morsels_done"] > 0
+    assert prog["fused_launches"] == prog["morsels_done"], "a morsel left the fused kernel"
+
+
+def _plan(specs, schema, agg=None):
+    return plan_fused_chain(specs, schema, agg=agg, backend=get_backend("pallas"))
+
+
 # ---------------------------------------------------------------------------
 # fused filter+select
 # ---------------------------------------------------------------------------
@@ -106,27 +154,19 @@ def test_filter_select_parity(seed, pred_col, sel_cols):
 @pytest.mark.parametrize("op", ["lt", "le", "gt", "ge", "eq", "ne"])
 @pytest.mark.parametrize("pred_col,threshold", [("f32_a", 0.25), ("i32_e", 4), ("i64_d", 0)])
 def test_comparison_set_parity(op, pred_col, threshold):
-    """Every comparison × predicate dtype must dispatch AND stay
+    """Every comparison × predicate dtype must run fused AND stay
     byte-identical (eq/ne exercise the padded-tail row masking)."""
     batch = _random_batch(np.random.default_rng(3))
-    backend = get_backend("pallas")
     pred = getattr(col(pred_col), f"__{op}__")(threshold)
-    before = backend.kernel_calls
-    got = backend.filter_select(batch, pred, [pred_col, "f32_b"])
-    assert backend.kernel_calls == before + 1, f"{op} on {pred_col} did not dispatch"
-    ref = get_backend("numpy").filter_select(batch, pred, [pred_col, "f32_b"])
-    _assert_byte_identical(got, ref)
+    _, prog = _parity(_filter_select(pred, [pred_col, "f32_b"]), batch)
+    _all_fused(prog)
 
 
 def test_eq_matches_exact_int64_value():
     batch = _random_batch(np.random.default_rng(11))
     target = int(batch.column("i64_d").values[123])
-    backend = get_backend("pallas")
-    before = backend.kernel_calls
-    got = backend.filter_select(batch, col("i64_d") == target, ["i64_d"])
-    assert backend.kernel_calls == before + 1
-    ref = get_backend("numpy").filter_select(batch, col("i64_d") == target, ["i64_d"])
-    _assert_byte_identical(got, ref)
+    got, prog = _parity(_filter_select(col("i64_d") == target, ["i64_d"]), batch)
+    _all_fused(prog)
     assert got.num_rows >= 1
 
 
@@ -135,27 +175,19 @@ def test_negative_zero_is_bit_exact():
     the old MXU float path normalized it to +0.0)."""
     data = np.asarray([-0.0, 1.0, -0.0, -1.0, 0.0] * 60, np.float32)
     batch = RecordBatch.from_pydict({"a": data, "b": data[::-1].copy()})
-    backend = get_backend("pallas")
-    before = backend.kernel_calls
-    out = backend.filter_select(batch, col("a") <= 0.0, ["a", "b"])
-    assert backend.kernel_calls == before + 1
-    ref = get_backend("numpy").filter_select(batch, col("a") <= 0.0, ["a", "b"])
-    _assert_byte_identical(out, ref)
+    out, prog = _parity(_filter_select(col("a") <= 0.0, ["a", "b"]), batch)
+    _all_fused(prog)
     assert np.signbit(out.column("a").values).any()
 
 
 def test_nonfinite_dispatches_bit_exact():
-    """NaN/Inf no longer force a fallback: integer bit-plane compaction
-    moves payloads verbatim and float compares keep IEEE NaN semantics."""
-    backend = get_backend("pallas")
+    """NaN/Inf force no fallback: integer bit-plane compaction moves
+    payloads verbatim and float compares keep IEEE NaN semantics."""
     data = np.asarray([1.0, np.inf, -1.0, np.nan, 2.0] * 60, np.float32)
     batch = RecordBatch.from_pydict({"a": data, "b": data[::-1].copy()})
-    before = backend.kernel_calls
     for pred in (col("a") > 0.5, col("a") != 1.0, col("a") <= 0.5):
-        out = backend.filter_select(batch, pred, ["a", "b"])
-        ref = get_backend("numpy").filter_select(batch, pred, ["a", "b"])
-        _assert_byte_identical(out, ref)
-    assert backend.kernel_calls == before + 3
+        _, prog = _parity(_filter_select(pred, ["a", "b"]), batch)
+        _all_fused(prog)
 
 
 @pytest.mark.parametrize(
@@ -167,77 +199,49 @@ def test_numpy_typed_literals_dispatch(threshold):
     integer-typed or numpy-scalar literal against a float32 column must not
     be rejected when exactly representable (regression: ``col > 5``)."""
     batch = _random_batch(np.random.default_rng(5))
-    backend = get_backend("pallas")
-    before = backend.kernel_calls
-    got = backend.filter_select(batch, col("f32_a") > threshold, ["f32_a"])
-    assert backend.kernel_calls == before + 1, f"literal {threshold!r} did not dispatch"
-    ref = get_backend("numpy").filter_select(batch, col("f32_a") > threshold, ["f32_a"])
-    _assert_byte_identical(got, ref)
+    _, prog = _parity(_filter_select(col("f32_a") > threshold, ["f32_a"]), batch)
+    _all_fused(prog)
 
 
 def test_float_literal_on_int_column_rewrites():
-    """``i32 > 2.5`` rewrites to the equivalent integer comparison and
-    dispatches; ``i32 == 2.5`` (a constant mask) falls back."""
+    """``i32 > 2.5`` rewrites to the equivalent integer comparison and runs
+    fused; ``i32 == 2.5`` (a constant mask) is declined."""
     batch = _random_batch(np.random.default_rng(6))
-    backend = get_backend("pallas")
-    nref = get_backend("numpy")
-    before = backend.kernel_calls
     for pred in (col("i32_e") > 2.5, col("i32_e") <= 2.5, col("i32_e") < 4.5, col("i32_e") >= 4.5):
-        _assert_byte_identical(
-            backend.filter_select(batch, pred, ["i32_e"]), nref.filter_select(batch, pred, ["i32_e"])
-        )
-    assert backend.kernel_calls == before + 4
-    before = backend.kernel_calls
-    _assert_byte_identical(
-        backend.filter_select(batch, col("i32_e") == 2.5, ["i32_e"]),
-        nref.filter_select(batch, col("i32_e") == 2.5, ["i32_e"]),
-    )
-    assert backend.kernel_calls == before  # constant mask → numpy
+        _, prog = _parity(_filter_select(pred, ["i32_e"]), batch)
+        _all_fused(prog)
+    pred = col("i32_e") == 2.5
+    assert _plan([("filter", (pred,)), ("select", (["i32_e"],))], batch.schema) is None
+    _, prog = _parity(_filter_select(pred, ["i32_e"]), batch)
+    assert prog["fused_launches"] == 0  # constant mask → numpy
 
 
 def test_pallas_kernel_actually_dispatches():
     """The all-float32 chain must execute device-resident: ONE fused launch
-    per morsel, zero per-op kernel calls (guards against the backend
-    silently degrading to numpy OR the fused planner silently bailing to
-    the per-op path)."""
-    from repro.core.executor import ExecutorStats
-
-    backend = get_backend("pallas")
+    per morsel (guards against the backend silently degrading to numpy or
+    the fused planner silently declining)."""
     batch = _random_batch(np.random.default_rng(7))
-    bld = Dag.build()
-    s = bld.source("dacp://h:1/d")
-    f = bld.add("filter", {"predicate": col("f32_a") > 0.0}, [s])
-    sel = bld.add("select", {"columns": ["f32_b", "f32_a"]}, [f])
-    dag = bld.finish(sel)
-    before = backend.kernel_calls
-    stats = ExecutorStats()
-    cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend="pallas")
-    execute_parallel(dag, lambda n: _sdf(batch), cfg, stats=stats).collect()
-    prog = stats.progress()
-    assert prog["fused_launches"] > 0, "eligible chain did not fuse"
-    assert backend.kernel_calls == before, "fused chain still launched per-op kernels"
+    _, stats = _fused_run(_filter_select(col("f32_a") > 0.0, ["f32_b", "f32_a"]), batch, "pallas")
+    _all_fused(stats.progress())
 
 
 def test_pallas_falls_back_on_unsupported_shapes():
-    """f64 predicates, masked columns, and var-width projections stay on the
+    """f64 predicates and var-width projections are declined at plan time,
+    and a morsel with a validity mask at run time; each runs the
     (bit-identical) numpy path."""
-    backend = get_backend("pallas")
     batch = _random_batch(np.random.default_rng(8))
-    before = backend.kernel_calls
-    out = backend.filter_select(batch, col("f64_c") > 0, ["i64_d", "f64_c"])
-    assert backend.kernel_calls == before  # f64 predicate → numpy fallback
-    ref = get_backend("numpy").filter_select(batch, col("f64_c") > 0, ["i64_d", "f64_c"])
-    _assert_byte_identical(out, ref)
-
-    out = backend.filter_select(batch, col("i64_d") > 0, ["tag"])
-    assert backend.kernel_calls == before  # string projection → fallback
-    _assert_byte_identical(out, get_backend("numpy").filter_select(batch, col("i64_d") > 0, ["tag"]))
+    for pred, cols in ((col("f64_c") > 0, ["i64_d", "f64_c"]), (col("i64_d") > 0, ["tag"])):
+        assert _plan([("filter", (pred,)), ("select", (cols,))], batch.schema) is None
+        _, prog = _parity(_filter_select(pred, cols), batch)
+        assert prog["fused_launches"] == 0
 
     masked = Column.from_values(batch.schema.field("f32_a").dtype, batch.column("f32_a").values)
     masked.validity = np.ones(batch.num_rows, bool)
     vb = batch.with_column(batch.schema.field("f32_a"), masked)
-    out = backend.filter_select(vb, col("f32_a") > 0.0, ["f32_a"])
-    assert backend.kernel_calls == before  # validity mask → fallback
+    plan = _plan([("filter", (col("f32_a") > 0.0,)), ("select", (["f32_a"],))], vb.schema)
+    assert plan.run(vb) is FUSED_INELIGIBLE  # validity mask → numpy
+    _, prog = _parity(_filter_select(col("f32_a") > 0.0, ["f32_a"]), vb)
+    assert prog["fused_launches"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -265,30 +269,32 @@ def test_project_parity(seed, exprs, keep):
 
 def test_project_kernel_dispatches():
     batch = _random_batch(np.random.default_rng(9))
-    backend = get_backend("pallas")
-    exprs = {"y": col("f32_a") * 2.0 + 1.0}
-    out_schema = project_schema(batch.schema, exprs, True)
-    before = backend.kernel_calls
-    got = backend.project(batch, exprs, out_schema)
-    assert backend.kernel_calls == before + 1
-    ref = get_backend("numpy").project(batch, exprs, out_schema)
-    _assert_byte_identical(got, ref)
+    dag = _chain(
+        ("filter", {"predicate": col("i32_e") >= 0}),  # keeps every row
+        ("project", {"exprs": {"y": col("f32_a") * 2.0 + 1.0, "b": col("f32_b")}, "keep": False}),
+    )
+    _, prog = _parity(dag, batch)
+    _all_fused(prog)
 
 
 def test_project_division_by_zero_parity():
+    """float32 division is declined (a TPU's is not correctly rounded); the
+    numpy path keeps inf and nan bit patterns."""
     a = np.asarray([1.0, -1.0, 0.0, 2.0] * 70, np.float32)
     b = np.asarray([0.0, 0.0, 0.0, 1.0] * 70, np.float32)
     batch = RecordBatch.from_pydict({"a": a, "b": b})
     exprs = {"q": col("a") / col("b")}
-    out_schema = project_schema(batch.schema, exprs, True)
+    pred = col("b") >= 0.0
+    specs = [("filter", (pred,)), ("project", (exprs, project_schema(batch.schema, exprs, True)))]
+    assert _plan(specs, batch.schema) is None
+    dag = _chain(("filter", {"predicate": pred}), ("project", {"exprs": exprs, "keep": True}))
     with np.errstate(divide="ignore", invalid="ignore"):
-        got = get_backend("pallas").project(batch, exprs, out_schema)
-        ref = get_backend("numpy").project(batch, exprs, out_schema)
-    _assert_byte_identical(got, ref)  # inf and nan bit patterns included
+        _, prog = _parity(dag, batch)  # inf and nan bit patterns included
+    assert prog["fused_launches"] == 0
 
 
 # ---------------------------------------------------------------------------
-# aggregation (segment-reduce kernel)
+# aggregation (the fused fold)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("seed", [3, 4])
 @pytest.mark.parametrize("key", ["i32_e", "tag"])
@@ -318,29 +324,9 @@ def test_filter_aggregate_parity(seed, key):
 
 def test_segment_reduce_kernel_dispatches():
     batch = _random_batch(np.random.default_rng(10))
-    backend = get_backend("pallas")
-    st = GroupState(
-        ["i32_e"],
-        {"n": {"fn": "count"}, "s": {"fn": "sum", "column": "i64_d"}, "hi": {"fn": "max", "column": "i32_e"}},
-        "full",
-        batch.schema,
-        vectorized=True,
-        backend=backend,
-    )
-    before = backend.kernel_calls
-    st.update(batch)
-    assert backend.kernel_calls == before + 1
-    ref = GroupState(
-        ["i32_e"],
-        {"n": {"fn": "count"}, "s": {"fn": "sum", "column": "i64_d"}, "hi": {"fn": "max", "column": "i32_e"}},
-        "full",
-        batch.schema,
-        vectorized=True,
-    )
-    ref.update(batch)
-    assert st.key_rows == ref.key_rows
-    for name in st.acc:
-        assert np.array_equal(st.acc[name], ref.acc[name]), name
+    aggs = {"n": {"fn": "count"}, "s": {"fn": "sum", "column": "i64_d"}, "hi": {"fn": "max", "column": "i32_e"}}
+    _, prog = _parity(_aggregate(["i32_e"], aggs), batch)
+    _all_fused(prog)
 
 
 def test_segment_reduce_int64_wraparound_parity():
@@ -349,234 +335,147 @@ def test_segment_reduce_int64_wraparound_parity():
     big = np.asarray([2**62, 2**62, 2**62, -(2**61)] * 64, np.int64)
     keys = np.asarray([0, 1, 0, 1] * 64, np.int32)
     batch = RecordBatch.from_pydict({"k": keys, "v": big})
-    aggs = {"s": {"fn": "sum", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    before = backend.kernel_calls
     with np.errstate(over="ignore"):
-        st.update(batch)
-        ref.update(batch)
-    assert backend.kernel_calls == before + 1
-    assert np.array_equal(st.acc["s"], ref.acc["s"])
+        _, prog = _parity(_aggregate(["k"], {"s": {"fn": "sum", "column": "v"}}), batch)
+    _all_fused(prog)
 
 
 def test_segment_reduce_nan_minmax_falls_back():
-    """min/max over a float column containing NaN must not use the kernel
-    (XLA reduce NaN semantics are not trusted) — and still match numpy."""
+    """min/max over a float column containing NaN must leave the kernel
+    (order keys do not propagate NaN) — and still match numpy."""
     vals = np.asarray([1.0, np.nan, -2.0, 3.0] * 64, np.float32)
     keys = np.asarray([0, 0, 1, 1] * 64, np.int32)
     batch = RecordBatch.from_pydict({"k": keys, "v": vals})
     aggs = {"lo": {"fn": "min", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    st.update(batch)
-    ref.update(batch)
-    assert np.array_equal(st.acc["lo"], ref.acc["lo"], equal_nan=True)
+    plan = _plan([], batch.schema, agg=(["k"], aggs, "full", batch.schema))
+    assert plan.fold(batch) is FUSED_INELIGIBLE
+    _, prog = _parity(_aggregate(["k"], aggs), batch)
+    assert prog["fused_launches"] == 0
 
 
 def test_masked_keys_still_use_value_kernel():
     """A validity mask on the key column forces the row-loop factorization,
-    but the segment-reduce kernel still folds the values."""
-    from repro.core import dtypes
-    from repro.core.schema import Field, Schema
-
+    but the fused kernel still folds the values."""
     schema = Schema([Field("k", dtypes.INT64), Field("v", dtypes.INT64)])
     kc = Column.from_values(dtypes.INT64, [1, 1, 2, 2] * 64)
     kc.validity = np.asarray([True, False, True, True] * 64)
     vc = Column.from_values(dtypes.INT64, list(range(256)))
     batch = RecordBatch(schema, [kc, vc])
-    backend = get_backend("pallas")
     aggs = {"s": {"fn": "sum", "column": "v"}, "n": {"fn": "count"}}
-    st = GroupState(["k"], aggs, "full", schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", schema, vectorized=True)
-    before = backend.kernel_calls
-    st.update(batch)
-    ref.update(batch)
-    assert backend.kernel_calls == before + 1
-    assert st.key_rows == ref.key_rows  # null key stays a distinct group
-    assert np.array_equal(st.acc["s"], ref.acc["s"])
-    assert np.array_equal(st.acc["n"], ref.acc["n"])
+    got, prog = _parity(_aggregate(["k"], aggs), batch)
+    _all_fused(prog)
+    assert got.column("k").to_pylist() == [1, None, 2]  # null key stays a distinct group
 
 
 # ---------------------------------------------------------------------------
-# PR 4: int64 min/max (two-word compare) + f64-accumulating float sums
+# wide min/max and float sums: min/max over int64, uint32, uint64 and
+# float64 are declined by the fused plan; float sums fold fused
 # ---------------------------------------------------------------------------
+def _declined_minmax(batch, aggs):
+    """``aggs`` grouped by ``k``: declined at plan time, byte-identical."""
+    assert _plan([], batch.schema, agg=(["k"], aggs, "full", batch.schema)) is None
+    got, prog = _parity(_aggregate(["k"], aggs), batch)
+    assert prog["fused_launches"] == 0
+    return got
+
+
 def test_segment_reduce_int64_minmax_two_word_parity():
-    """Full-range int64 min/max dispatch through the two-pass hi/lo compare
-    and match numpy's scatter exactly (the old path fell back silently)."""
+    """Full-range int64 min/max (hi-word ties, so the low word decides)."""
     rng = np.random.default_rng(17)
     vals = rng.integers(-(2**63), 2**63 - 1, 512, dtype=np.int64)
-    # force hi-word ties so the lo-word pass actually decides winners
     vals[1::4] = vals[::4] | np.int64(1)
     keys = rng.integers(0, 9, 512).astype(np.int32)
     batch = RecordBatch.from_pydict({"k": keys, "v": vals})
-    aggs = {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    before = backend.kernel_calls
-    st.update(batch)
-    ref.update(batch)
-    assert backend.kernel_calls == before + 1, "int64 min/max did not dispatch"
-    assert np.array_equal(st.acc["lo"], ref.acc["lo"])
-    assert np.array_equal(st.acc["hi"], ref.acc["hi"])
+    _declined_minmax(batch, {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}})
 
 
 def test_segment_reduce_uint32_minmax_parity():
-    """uint32 lifts exactly onto the two-word path (it never fit int32)."""
     rng = np.random.default_rng(18)
     vals = rng.integers(0, 2**32 - 1, 512, dtype=np.uint32)
     keys = rng.integers(0, 5, 512).astype(np.int32)
     batch = RecordBatch.from_pydict({"k": keys, "v": vals})
-    aggs = {"hi": {"fn": "max", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    before = backend.kernel_calls
-    st.update(batch)
-    ref.update(batch)
-    assert backend.kernel_calls == before + 1
-    assert np.array_equal(st.acc["hi"], ref.acc["hi"])
+    _declined_minmax(batch, {"hi": {"fn": "max", "column": "v"}})
 
 
-# ---------------------------------------------------------------------------
-# PR 5: f64 / uint64 min/max on the two-word compare path
-# ---------------------------------------------------------------------------
 def test_segment_reduce_uint64_minmax_parity():
-    """Full-range uint64 min/max dispatch via the top-bit-flip key image —
-    values straddling 2^63 must compare unsigned (min over [1, 2^63+5] is 1,
-    never a wrapped negative), matching the uint64 accumulator exactly."""
+    """Full-range uint64 min/max — values straddling 2^63 compare unsigned
+    (min over [1, 2^63+5] is 1, never a wrapped negative)."""
     rng = np.random.default_rng(19)
     vals = rng.integers(0, 2**64 - 1, 512, dtype=np.uint64)
     vals[:4] = [1, 2**63 + 5, 2**64 - 1, 0]
     keys = rng.integers(0, 7, 512).astype(np.int32)
     keys[:4] = 0
     batch = RecordBatch.from_pydict({"k": keys, "v": vals})
-    aggs = {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    before = backend.kernel_calls
-    st.update(batch)
-    ref.update(batch)
-    assert backend.kernel_calls == before + 1, "uint64 min/max did not dispatch"
-    assert st.acc["lo"].dtype == np.uint64 and np.array_equal(st.acc["lo"], ref.acc["lo"])
-    assert np.array_equal(st.acc["hi"], ref.acc["hi"])
+    got = _declined_minmax(batch, {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}})
+    assert got.column("lo").values.dtype == np.uint64
 
 
 def test_segment_reduce_float64_minmax_parity():
-    """float64 min/max dispatch via the sign-magnitude fold: bit patterns
-    (incl. ±Inf and subnormals) compare in float order through two int32
-    word passes, byte-identical to numpy's scatter."""
+    """float64 min/max (incl. ±Inf and subnormals), byte-identical."""
     rng = np.random.default_rng(20)
     vals = rng.standard_normal(512) * 10.0**rng.integers(-200, 200, 512)
     vals[:4] = [np.inf, -np.inf, 5e-324, -5e-324]
     keys = rng.integers(0, 6, 512).astype(np.int32)
     keys[:4] = 1
     batch = RecordBatch.from_pydict({"k": keys, "v": vals})
-    aggs = {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    before = backend.kernel_calls
-    st.update(batch)
-    ref.update(batch)
-    assert backend.kernel_calls == before + 1, "float64 min/max did not dispatch"
-    assert st.acc["lo"].tobytes() == ref.acc["lo"].tobytes()
-    assert st.acc["hi"].tobytes() == ref.acc["hi"].tobytes()
+    _declined_minmax(batch, {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}})
 
 
 def test_segment_reduce_float64_sentinels_on_absent_groups():
-    """A second batch that misses some already-interned groups exercises the
-    empty-group sentinel decode (must be the ±Inf identities, not NaN)."""
+    """A second morsel that misses some already-interned groups: the merged
+    extremes are the ±Inf identities' results, not NaN."""
     b1 = RecordBatch.from_pydict(
         {"k": np.asarray([0, 1, 2, 3] * 64, np.int32), "v": np.arange(256, dtype=np.float64) - 128.0}
     )
     b2 = RecordBatch.from_pydict(
         {"k": np.asarray([1, 3] * 128, np.int32), "v": -(np.arange(256, dtype=np.float64)) * 7.5}
     )
-    aggs = {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", b1.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", b1.schema, vectorized=True)
-    for b in (b1, b2):
-        st.update(b)
-        ref.update(b)
-    assert st.acc["lo"].tobytes() == ref.acc["lo"].tobytes()
-    assert st.acc["hi"].tobytes() == ref.acc["hi"].tobytes()
+    batch = RecordBatch.from_pydict({n: np.concatenate([b1.column(n).values, b2.column(n).values]) for n in "kv"})
+    got = _declined_minmax(batch, {"lo": {"fn": "min", "column": "v"}, "hi": {"fn": "max", "column": "v"}})
+    assert not np.isnan(got.column("lo").values).any()
 
 
 @pytest.mark.parametrize("poison", ["nan", "negzero"])
 def test_segment_reduce_float64_nan_negzero_fall_back(poison):
-    """NaN (total order ≠ numpy NaN propagation) and -0.0 (operand-order
-    dependent in numpy min/max) keep float64 columns off the kernel — and
-    the numpy scatter result is bit-preserved."""
+    """NaN (numpy propagates it) and -0.0 (numpy's min/max result depends
+    on operand order) in a float64 column: the numpy result is
+    bit-preserved."""
     vals = np.arange(256, dtype=np.float64)
     vals[7] = np.nan if poison == "nan" else -0.0
     keys = np.asarray([0, 1] * 128, np.int32)
     batch = RecordBatch.from_pydict({"k": keys, "v": vals})
-    aggs = {"lo": {"fn": "min", "column": "v"}}
-    backend = get_backend("pallas")
-    st = GroupState(["k"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["k"], aggs, "full", batch.schema, vectorized=True)
-    st.update(batch)
-    ref.update(batch)
-    assert st.acc["lo"].tobytes() == ref.acc["lo"].tobytes()
+    _declined_minmax(batch, {"lo": {"fn": "min", "column": "v"}})
 
 
 def test_float_sums_take_f64_reference_path():
-    """Float sums (and mean partial sums) from a fresh state no longer fall
-    back silently: the backend folds them in its f64-accumulating reference
-    path (counted in ``f64_folds``) bit-identically to the numpy scatter."""
+    """Float sums (and mean partial sums) fold fused: their source planes
+    ride the compaction and the host folds them in float64, in row order —
+    bit-identical to the numpy scatter."""
     batch = _random_batch(np.random.default_rng(19))
     aggs = {
         "sf": {"fn": "sum", "column": "f32_a"},
         "sd": {"fn": "sum", "column": "f64_c"},
         "m": {"fn": "mean", "column": "f64_c"},
     }
-    backend = get_backend("pallas")
-    st = GroupState(["i32_e"], aggs, "full", batch.schema, vectorized=True, backend=backend)
-    ref = GroupState(["i32_e"], aggs, "full", batch.schema, vectorized=True)
-    before = backend.f64_folds
-    st.update(batch)
-    ref.update(batch)
-    assert backend.f64_folds == before + 3, "float sums fell back silently"
-    for name in st.acc:
-        assert np.array_equal(st.acc[name], ref.acc[name]), name
+    _, prog = _parity(_aggregate(["i32_e"], aggs), batch)
+    _all_fused(prog)
 
 
 def test_spill_composes_with_pallas_backend():
-    """Grace-hash spilling must not disable kernel acceleration: the
-    per-morsel folds still dispatch, and the spilled result stays
+    """Grace-hash spilling must not disable the fused fold: every
+    per-morsel fold still runs on the kernel, and the spilled result stays
     byte-identical to the numpy in-memory run."""
-    from repro.core.executor import ExecutorStats
-
     batch = _random_batch(np.random.default_rng(20), n=2000)
-    bld = Dag.build()
-    s = bld.source("dacp://h:1/d")
-    a = bld.add(
-        "aggregate",
-        {
-            "keys": ["tag"],
-            "aggs": {
-                "n": {"fn": "count"},
-                "s64": {"fn": "sum", "column": "i64_d"},
-                "sf": {"fn": "sum", "column": "f32_a"},
-                "lo64": {"fn": "min", "column": "i64_d"},
-            },
-        },
-        [s],
-    )
-    dag = bld.finish(a)
+    aggs = {
+        "n": {"fn": "count"},
+        "s64": {"fn": "sum", "column": "i64_d"},
+        "sf": {"fn": "sum", "column": "f32_a"},
+        "lo": {"fn": "min", "column": "f32_b"},
+    }
+    dag = _aggregate(["tag"], aggs)
     ref = _run(dag, batch, "numpy")
-    backend = get_backend("pallas")
-    before = backend.kernel_calls
-    stats = ExecutorStats()
-    cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend="pallas", memory_budget=1)
-    got = execute_parallel(dag, lambda n: _sdf(batch), cfg, stats=stats).collect()
-    assert backend.kernel_calls > before, "spilling disabled kernel dispatch"
+    got, stats = _fused_run(dag, batch, "pallas", memory_budget=1)
+    _all_fused(stats.progress())
     assert stats.to_dict()["spill"]["spills"] >= 1
     _assert_byte_identical(got, ref)
 
@@ -584,21 +483,11 @@ def test_spill_composes_with_pallas_backend():
 # ---------------------------------------------------------------------------
 # PR 7: device-resident fused pipelines (one launch per morsel chain)
 # ---------------------------------------------------------------------------
-def _fused_run(dag, batch, backend_name, **cfg_kw):
-    from repro.core.executor import ExecutorStats
-
-    stats = ExecutorStats()
-    cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend=backend_name, **cfg_kw)
-    out = execute_parallel(dag, lambda n: _sdf(batch), cfg, stats=stats).collect()
-    return out, stats
-
-
 @pytest.mark.parametrize("seed", range(6))
 def test_fused_chain_random_eligible_chains_parity(seed):
     """Random eligible filter/select/project chains — filter leading or
     mid-chain, computed-of-computed arithmetic, mixed-dtype passthrough —
-    run as ONE fused launch per morsel, byte-identical to numpy, with the
-    per-op kernels silent."""
+    run as ONE fused launch per morsel, byte-identical to numpy."""
     rng = np.random.default_rng(100 + seed)
     batch = _random_batch(np.random.default_rng(seed))
     pc, thr = [
@@ -630,13 +519,10 @@ def test_fused_chain_random_eligible_chains_parity(seed):
     for op, params in links:
         node = bld.add(op, params, [node])
     dag = bld.finish(node)
-    backend = get_backend("pallas")
-    before = backend.kernel_calls
     got, stats = _fused_run(dag, batch, "pallas")
     ref, _ = _fused_run(dag, batch, "numpy")
     _assert_byte_identical(got, ref)
     assert stats.progress()["fused_launches"] > 0, "eligible chain did not fuse"
-    assert backend.kernel_calls == before, "fused chain still launched per-op kernels"
 
 
 def test_fused_chain_nan_negzero_payload_parity():
@@ -678,10 +564,7 @@ def test_fused_chain_full_range_int64_parity():
 
 def test_fused_aggregate_single_launch_per_morsel():
     """filter → project → group-by folds in the SAME launch: the fused
-    counter ticks exactly once per morsel and the per-op kernels (filter,
-    project, segment-reduce) stay silent."""
-    from repro.core.executor import ExecutorStats
-
+    counter ticks exactly once per morsel."""
     batch = _random_batch(np.random.default_rng(23))
     bld = Dag.build()
     s = bld.source("dacp://h:1/d")
@@ -703,13 +586,10 @@ def test_fused_aggregate_single_launch_per_morsel():
         [p],
     )
     dag = bld.finish(a)
-    backend = get_backend("pallas")
-    before = backend.kernel_calls
     got, stats = _fused_run(dag, batch, "pallas")
     ref, _ = _fused_run(dag, batch, "numpy")
     _assert_byte_identical(got, ref)
     assert stats.progress()["fused_launches"] == 4  # 700 rows / 200-row morsels
-    assert backend.kernel_calls == before, "fused fold still launched per-op kernels"
 
 
 def test_fused_chain_composes_with_spill(monkeypatch):
@@ -717,8 +597,6 @@ def test_fused_chain_composes_with_spill(monkeypatch):
     partials come off the fused launch, the merged state crosses the budget
     and spills, and the result stays byte-identical to the in-memory numpy
     run."""
-    from repro.core.executor import ExecutorStats
-
     rng = np.random.default_rng(24)
     n = 4000
     batch = RecordBatch.from_pydict(
@@ -760,43 +638,41 @@ def _cook_dag(kind):
     if kind == "fused":
         node = bld.add("filter", {"predicate": col("f32_a") > 0.0}, [s])
         node = bld.add("select", {"columns": ["f32_a", "i64_d"]}, [node])
-    elif kind == "filter_select":  # two filters: fused-ineligible, per-op kernels
+    elif kind == "project":  # a projecting stream chain
         node = bld.add("filter", {"predicate": col("f32_a") > 0.0}, [s])
-        node = bld.add("filter", {"predicate": col("i32_e") > 2}, [node])
-        node = bld.add("select", {"columns": ["f32_a", "i64_d"]}, [node])
-    elif kind == "project":  # no filter, no aggregate: per-op project
-        node = bld.add("project", {"exprs": {"y": col("f32_a") * 2.0}, "keep": True}, [s])
-    else:  # int64 min: fused-ineligible, per-op segment reduce
-        aggs = {"n": {"fn": "count"}, "lo": {"fn": "min", "column": "i64_d"}}
-        node = bld.add("aggregate", {"keys": ["i32_e"], "aggs": aggs}, [s])
+        node = bld.add("project", {"exprs": {"y": col("f32_a") * 2.0}, "keep": False}, [node])
+    elif kind == "fold":  # one-hot fold: at most 9 groups
+        node = bld.add("filter", {"predicate": col("f32_a") > -1.0}, [s])
+        aggs = {"n": {"fn": "count"}, "lo": {"fn": "min", "column": "f32_b"}}
+        node = bld.add("aggregate", {"keys": ["i32_e"], "aggs": aggs}, [node])
+    elif kind == "global_fold":  # no keys: one group
+        node = bld.add("aggregate", {"keys": [], "aggs": {"s": {"fn": "sum", "column": "i64_d"}}}, [s])
+    else:  # windowed fold: 700 distinct keys in one morsel, above the 256-group cap
+        aggs = {"n": {"fn": "count"}, "s": {"fn": "sum", "column": "f32_a"}}
+        node = bld.add("aggregate", {"keys": ["i64_d"], "aggs": aggs}, [s])
     return bld.finish(node)
 
 
 @pytest.mark.parametrize(
-    "kind,op",
-    [
-        ("fused", "fused_chain_tiles"),
-        ("filter_select", "filter_select_planes"),
-        ("project", "project_tiles"),
-        ("aggregate", "segment_sum_tiles"),
-        ("aggregate", "segment_minmax_tiles"),
-    ],
+    "kind", ["fused", "project", "fold", "global_fold", "windowed"], ids=lambda k: f"{k}-fused_chain_tiles"
 )
-def test_kernel_failure_fails_the_cook(monkeypatch, kind, op):
-    """A kernel that raises fails the request; it never comes back as the
-    numpy answer."""
+def test_kernel_failure_fails_the_cook(monkeypatch, kind):
+    """A fused launch that raises fails the request — in a stream chain, a
+    projecting chain, a one-hot fold, a keyless fold and a windowed fold;
+    it never comes back as the numpy answer."""
     from repro.kernels import ops
 
     backend = get_backend("pallas")
     backend._ops()
 
     def broken(*args, **kwargs):
-        raise RuntimeError(f"{op} failed")
+        raise RuntimeError("fused_chain_tiles failed")
 
-    monkeypatch.setattr(ops, op, broken)
+    monkeypatch.setattr(ops, "fused_chain_tiles", broken)
     batch = _random_batch(np.random.default_rng(30))
-    with pytest.raises(RuntimeError, match=f"{op} failed"):
-        _run(_cook_dag(kind), batch, "pallas")
+    cfg = ExecutorConfig(num_workers=2, morsel_rows=N_ROWS, backend="pallas")
+    with pytest.raises(RuntimeError, match="fused_chain_tiles failed"):
+        execute_parallel(_cook_dag(kind), lambda n: _sdf(batch, rows=N_ROWS), cfg).collect()
 
 
 def test_pallas_backend_needs_jax(monkeypatch):
@@ -838,8 +714,6 @@ def test_out_of_range_device_index_raises(monkeypatch, via_env):
 
 def test_pinned_device_counts_launches():
     """Pinned pipelines report where their fused launches ran."""
-    from repro.core.executor import ExecutorStats
-
     batch = _random_batch(np.random.default_rng(32))
     stats = ExecutorStats()
     cfg = ExecutorConfig(num_workers=2, morsel_rows=200, backend="pallas", devices=(0,))

@@ -66,20 +66,48 @@ def test_mlstm_chunk_sweep(s, chunk, d):
     assert_allclose(np.asarray(got), np.asarray(want), rtol=5e-4, atol=5e-4)
 
 
+def _stream(pred, table, scalars, op: str, kind: str, tile: int):
+    """The fused kernel in stream mode (a filter and its compaction, no
+    projection, no fold): ``(per-tile-compacted table, per-tile counts)``."""
+    n = table.shape[0]
+    zcol = jnp.zeros((n, 1), jnp.int32)
+    ctab, counts, *_ = ops.fused_chain_tiles(
+        scalars,
+        pred,
+        jnp.zeros(n, jnp.int32),
+        table,
+        zcol,
+        jnp.zeros((n, 1), jnp.float32),
+        zcol,
+        jnp.zeros((n, 1), jnp.float32),
+        zcol,
+        op=op,
+        kind=kind,
+        descrs_f=(),
+        descrs_i=(),
+        csums=(),
+        fns_f=("min",),
+        fns_i=("min",),
+        with_gidx=False,
+        segmented=False,
+        ngroups=8,
+        tile=tile,
+    )
+    return np.asarray(ctab), np.asarray(counts)
+
+
 @pytest.mark.parametrize("n,d,tile", [(512, 4, 128), (1024, 8, 256)])
 @pytest.mark.parametrize("op", ["gt", "le", "eq"])
-def test_filter_select_planes_sweep(n, d, tile, op):
-    """Bit-plane kernel compaction == numpy boolean indexing, bit-exact."""
+def test_fused_stream_compaction_sweep(n, d, tile, op):
+    """Bit-plane compaction of the fused kernel's stream mode == numpy
+    boolean indexing, bit-exact."""
     vals = R.normal(size=(n, d)).astype(np.float32)
     vals[::37, 0] = -0.0
     thr = np.float32(0.1)
     planes = vals.view(np.int32)
     t_hi = np.array([thr], np.float32).view(np.int32)[0]
-    scalars = np.array([n, t_hi, 0], np.int32)
-    got, counts = ops.filter_select_planes(
-        jnp.asarray(planes[:, :1]), jnp.asarray(planes), scalars, op=op, kind="f32", tile=tile
-    )
-    got, counts = np.asarray(got), np.asarray(counts)
+    scalars = np.array([n, t_hi, 0, 0], np.int32)
+    got, counts = _stream(jnp.asarray(planes[:, :1]), jnp.asarray(planes), scalars, op, "f32", tile)
     cmp = {"gt": np.greater, "le": np.less_equal, "eq": np.equal}[op]
     mask = cmp(vals[:, 0], thr)
     front = np.concatenate([got[i * tile : i * tile + c] for i, c in enumerate(counts)])
@@ -87,7 +115,7 @@ def test_filter_select_planes_sweep(n, d, tile, op):
     np.testing.assert_array_equal(front.view(np.float32), vals[mask])
 
 
-def test_filter_select_planes_i64_two_word():
+def test_fused_stream_i64_two_word():
     """int64 predicates compare as two int32 words — full-range exact."""
     n, tile = 512, 128
     v = R.integers(-(2**62), 2**62, size=n, dtype=np.int64)
@@ -98,11 +126,8 @@ def test_filter_select_planes_i64_two_word():
     pred = np.stack([hi, lo], axis=1)
     t_hi = np.int32(target >> 32)
     t_lo = np.int32(np.uint32(target & 0xFFFFFFFF).view(np.int32) ^ np.int32(-(2**31)))
-    scalars = np.array([n, t_hi, t_lo], np.int32)
-    got, counts = ops.filter_select_planes(
-        jnp.asarray(pred), jnp.asarray(pred), scalars, op="gt", kind="i64", tile=tile
-    )
-    got, counts = np.asarray(got), np.asarray(counts)
+    scalars = np.array([n, t_hi, t_lo, 0], np.int32)
+    got, counts = _stream(jnp.asarray(pred), jnp.asarray(pred), scalars, "gt", "i64", tile)
     mask = v > target
     front = np.concatenate([got[i * tile : i * tile + c] for i, c in enumerate(counts)])
     assert counts.sum() == mask.sum()

@@ -98,7 +98,8 @@ def test_filter_then_concat_is_subset(batch, data):
 
 @given(st.lists(st.floats(-100, 100, allow_nan=False, width=32), min_size=8, max_size=64))
 def test_filter_select_kernel_property(vals):
-    """Kernel compaction == numpy boolean indexing for arbitrary data."""
+    """The fused kernel's stream-mode compaction == numpy boolean indexing
+    for arbitrary data."""
     import jax.numpy as jnp
 
     from repro.kernels import ops
@@ -109,9 +110,29 @@ def test_filter_select_kernel_property(vals):
     arr[:, 1] = np.arange(n)
     planes = arr.view(np.int32)
     thr = np.float32(1.5)
-    scalars = np.array([n, np.array([thr], np.float32).view(np.int32)[0], 0], np.int32)
-    out, counts = ops.filter_select_planes(
-        jnp.asarray(planes[:, :1]), jnp.asarray(planes), scalars, op="gt", kind="f32", tile=8
+    scalars = np.array([n, np.array([thr], np.float32).view(np.int32)[0], 0, 0], np.int32)
+    zcol = jnp.zeros((n, 1), jnp.int32)
+    out, counts, *_ = ops.fused_chain_tiles(
+        scalars,
+        jnp.asarray(planes[:, :1]),
+        jnp.zeros(n, jnp.int32),
+        jnp.asarray(planes),
+        zcol,
+        jnp.zeros((n, 1), jnp.float32),
+        zcol,
+        jnp.zeros((n, 1), jnp.float32),
+        zcol,
+        op="gt",
+        kind="f32",
+        descrs_f=(),
+        descrs_i=(),
+        csums=(),
+        fns_f=("min",),
+        fns_i=("min",),
+        with_gidx=False,
+        segmented=False,
+        ngroups=8,
+        tile=8,
     )
     out, counts = np.asarray(out), np.asarray(counts)
     mask = arr[:, 0] > thr

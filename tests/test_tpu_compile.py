@@ -1,6 +1,7 @@
-"""The data-path Pallas kernels compile for a TPU v5e chip (Mosaic, not the
-interpreter) at the executor's real sizes: morsels of ``SUM_ROW_CAP`` rows,
-tile 256, up to ``_SEG_GROUP_CAP`` groups.
+"""The data-path Pallas kernel, ``fused_chain_tiles``, compiles for a TPU
+v5e chip (Mosaic, not the interpreter) at the executor's real sizes:
+morsels of ``SUM_ROW_CAP`` rows, tile 256, up to ``_SEG_GROUP_CAP`` groups
+— the whole chain, and each of its stages alone.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  The topology is described inside a fixture (never at import),
@@ -18,8 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.backend import _SEG_GROUP_CAP
-from repro.kernels import filter_select, fused_pipeline, project_arith, segment_reduce
-from repro.kernels.segment_reduce import SUM_ROW_CAP
+from repro.kernels import fused_pipeline
+from repro.kernels.fused_pipeline import SUM_ROW_CAP
 
 N = SUM_ROW_CAP
 TILE = 256
@@ -89,37 +90,51 @@ def test_fused_chain_tiles_compiles(one_chip, segmented):
     )
 
 
-@pytest.mark.parametrize("kind,planes", [("f32", 1), ("i32", 1), ("i64", 2)])
-def test_filter_select_planes_compiles(one_chip, kind, planes):
-    fn = functools.partial(filter_select.filter_select_planes, op="le", kind=kind, tile=TILE, interpret=False)
-    _compile(fn, one_chip, ((N, planes), I32), ((N, 5), I32), ((3,), I32))
+I32_DESCRS = (("sub", ("mul", ("col", 0), ("lit", 3)), ("col", 1)),)
+
+# one stage of the chain each: a predicate kind, a computed projection, a
+# segmented min/max, a limb sum (the rest of the launch left at its dummies)
+STAGES = {
+    "pred_f32": {"kind": "f32"},
+    "pred_i32": {"kind": "i32"},
+    "pred_i64": {"kind": "i64", "planes": 2},
+    "project_f32": {"descrs_f": F32_DESCRS},
+    "project_i32": {"descrs_i": I32_DESCRS},
+    "minmax_f32": {"segmented": True, "fns_f": ("min", "max")},
+    "minmax_i32": {"segmented": True, "fns_i": ("min", "max")},
+    "limb_sum": {"segmented": True, "limbs": 16},
+}
 
 
-def test_segment_sum_tiles_compiles(one_chip):
-    def fn(gidx, limbs, n_rows):
-        return segment_reduce.segment_sum_tiles(gidx, limbs, n_rows, _SEG_GROUP_CAP, tile=TILE, interpret=False)
-
-    _compile(fn, one_chip, ((N,), I32), ((N, 16), I32), ((), I32))
-
-
-@pytest.mark.parametrize("dtype", [F32, I32], ids=["f32", "i32"])
-def test_segment_minmax_tiles_compiles(one_chip, dtype):
-    def fn(gidx, vals, n_rows):
-        return segment_reduce.segment_minmax_tiles(
-            gidx, vals, n_rows, _SEG_GROUP_CAP, ("min", "max"), tile=TILE, interpret=False
-        )
-
-    _compile(fn, one_chip, ((N,), I32), ((N, 2), dtype), ((), I32))
-
-
-@pytest.mark.parametrize(
-    "dtype,descrs",
-    [
-        (F32, F32_DESCRS),
-        (I32, (("sub", ("mul", ("col", 0), ("lit", 3)), ("col", 1)),)),
-    ],
-    ids=["f32", "i32"],
-)
-def test_project_tiles_compiles(one_chip, dtype, descrs):
-    fn = functools.partial(project_arith.project_tiles, descrs=descrs, tile=TILE, interpret=False)
-    _compile(fn, one_chip, ((N, 2), dtype))
+@pytest.mark.parametrize("stage", list(STAGES))
+def test_fused_chain_stage_compiles(one_chip, stage):
+    cfg = {"kind": "none", "planes": 1, "descrs_f": (), "descrs_i": (), "fns_f": ("min",), "fns_i": ("min",),
+           "segmented": False, "limbs": 1, **STAGES[stage]}
+    fn = functools.partial(
+        fused_pipeline.fused_chain_tiles,
+        op="le",
+        kind=cfg["kind"],
+        descrs_f=cfg["descrs_f"],
+        descrs_i=cfg["descrs_i"],
+        csums=(),
+        fns_f=cfg["fns_f"],
+        fns_i=cfg["fns_i"],
+        with_gidx=False,
+        segmented=cfg["segmented"],
+        ngroups=_SEG_GROUP_CAP if cfg["segmented"] else 8,
+        tile=TILE,
+        interpret=False,
+    )
+    _compile(
+        fn,
+        one_chip,
+        ((4,), I32),
+        ((N, cfg["planes"]), I32),
+        ((N,), I32),
+        ((N, 5), I32),
+        ((N, cfg["limbs"]), I32),
+        ((N, len(cfg["fns_f"])), F32),
+        ((N, len(cfg["fns_i"])), I32),
+        ((N, 2), F32),
+        ((N, 2), I32),
+    )

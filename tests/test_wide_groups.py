@@ -61,15 +61,15 @@ def _batch(rng, dtype, groups: int, rows: int = ROWS) -> RecordBatch:
     )
 
 
-def _dag(keys=KEYS, filtered=True) -> Dag:
+def _dag(keys=KEYS, filtered=True, aggs=AGGS) -> Dag:
     bld = Dag.build()
     node = bld.source("dacp://h:1/d")
     if filtered:
         node = bld.add("filter", {"predicate": col("v") > -30.0}, [node])
-    return bld.finish(bld.add("aggregate", {"keys": list(keys), "aggs": AGGS}, [node]))
+    return bld.finish(bld.add("aggregate", {"keys": list(keys), "aggs": aggs}, [node]))
 
 
-def _run(batch: RecordBatch, backend: str, morsel_rows: int, filtered=True):
+def _run(batch: RecordBatch, backend: str, morsel_rows: int, filtered=True, aggs=AGGS):
     def sdf():
         def gen():
             for s in range(0, batch.num_rows, morsel_rows):
@@ -79,7 +79,7 @@ def _run(batch: RecordBatch, backend: str, morsel_rows: int, filtered=True):
 
     stats = ExecutorStats()
     cfg = ExecutorConfig(num_workers=2, morsel_rows=morsel_rows, backend=backend)
-    out = execute_parallel(_dag(filtered=filtered), lambda n: sdf(), cfg, stats=stats).collect()
+    out = execute_parallel(_dag(filtered=filtered, aggs=aggs), lambda n: sdf(), cfg, stats=stats).collect()
     return out, stats.progress()
 
 
@@ -174,23 +174,17 @@ def test_nan_and_negative_zero_keys_fall_back_and_match(backend, odd):
 
 @pytest.mark.parametrize("groups", [_SEG_GROUP_CAP + 1, 3000], ids=lambda g: f"g{g}")
 def test_per_op_segment_reduce_above_the_cap(groups):
-    """The per-op fold (``GroupState.update`` on the pallas backend) folds
-    more than 256 groups on the device, rows sorted by group, and matches
+    """More than 256 groups a morsel in an aggregate the fused plan declines
+    (int64 min/max): the pallas backend folds every morsel op by op on the
+    numpy path, a fresh state and then one with groups already, and matches
     the numpy backend bit for bit."""
-    batch = _batch(np.random.default_rng(groups), np.int32, groups, rows=4000)
-    bk = get_backend("pallas")
-    states = {}
-    for name, backend in (("numpy", None), ("pallas", bk)):
-        st = GroupState(KEYS, AGGS, "full", batch.schema, vectorized=True, backend=backend)
-        st.update(batch.slice(0, 2500))  # a fresh state, then one with groups already
-        calls = bk.kernel_calls
-        st.update(batch.slice(2500, 4000))
-        if backend is not None:
-            assert bk.kernel_calls == calls + 1
-        states[name] = st
-    assert states["pallas"].key_rows == states["numpy"].key_rows
-    for name, acc in states["numpy"].acc.items():
-        assert states["pallas"].acc[name].tobytes() == acc.tobytes(), name
+    aggs = {**AGGS, "alo": {"fn": "min", "column": "a"}, "bhi": {"fn": "max", "column": "b"}}
+    batch = _batch(np.random.default_rng(groups), np.int64, groups, rows=4000)
+    got, prog = _run(batch, "pallas", 2500, aggs=aggs)
+    want, _ = _run(batch, "numpy", 2500, aggs=aggs)
+    _assert_same(got, want)
+    assert prog["fused_launches"] == 0 and prog["morsels_done"] == 2
+    assert prog["groups"] == want.num_rows
 
 
 def test_columnar_keys_map_like_the_row_loop():
@@ -238,7 +232,7 @@ def test_windowed_fold_compiles_for_a_tpu(one_chip):
     import jax.numpy as jnp
 
     from repro.kernels import fused_pipeline
-    from repro.kernels.segment_reduce import SUM_ROW_CAP
+    from repro.kernels.fused_pipeline import SUM_ROW_CAP
 
     n = SUM_ROW_CAP
     squares = tuple(("mul", ("col", i), ("col", i)) for i in range(6))
