@@ -56,7 +56,7 @@ from pathlib import Path
 
 import numpy as np
 
-from repro.core.batch import Column, RecordBatch
+from repro.core.batch import CoalescedBatch, Column, RecordBatch
 from repro.core.env import env_str
 from repro.core.expr import Expr
 from repro.core.trace import span
@@ -762,7 +762,14 @@ class FusedChainPlan:
         self._sizer.bump_device(dev.id)
 
     # -- per-morsel envelope ------------------------------------------------
-    def _pad(self, n: int) -> int:
+    def _rows(self, batch: RecordBatch) -> int:
+        """The morsel's kernel row count: its rows rounded up to a tile,
+        or, for a :class:`CoalescedBatch`, its ``kernel_rows`` (at most
+        ``SUM_ROW_CAP``), one shape for every coalesced morsel.  The kernel
+        masks the rows at or past ``n``."""
+        n = batch.num_rows
+        if isinstance(batch, CoalescedBatch):
+            n = max(n, min(batch.kernel_rows, self._kernel_ops.SUM_ROW_CAP))
         return -(-n // self._tile) * self._tile
 
     def _morsel_ok(self, batch: RecordBatch) -> bool:
@@ -820,7 +827,7 @@ class FusedChainPlan:
         """The morsel's kernel input tables; ``order`` lays the rows out in
         that order (a windowed fold's rows sorted by group id)."""
         n = batch.num_rows
-        n_pad = self._pad(n)
+        n_pad = self._rows(batch)
         sch = batch.schema
 
         def values(name):
@@ -917,7 +924,7 @@ class FusedChainPlan:
             return FUSED_INELIGIBLE
         n = batch.num_rows
         arrs = staged if staged is not None else self._encode_inline(batch)
-        gidx = np.zeros(self._pad(n), np.int32)
+        gidx = np.zeros(self._rows(batch), np.int32)
         with span("dacp.morsel.launch", rows=n):
             out = self._launch(arrs, gidx, n, segmented=False, ngroups=8)
         with span("dacp.morsel.sync"):
@@ -973,7 +980,8 @@ class FusedChainPlan:
             return FUSED_INELIGIBLE
         order = steps = None
         live = 0
-        g32 = np.zeros(self._pad(n), np.int32)
+        n_pad = self._rows(batch)
+        g32 = np.zeros(n_pad, np.int32)
         if ng <= _SEG_GROUP_CAP:
             g_pad = max(8, -(-ng // 8) * 8)
             g32[:n] = gidx_full
@@ -984,7 +992,7 @@ class FusedChainPlan:
                 g32[:n] = gidx_full[order]
             else:
                 g32[:n] = gidx_full
-            steps, live = _window_steps(g32[:n], self._pad(n), self._tile)
+            steps, live = _window_steps(g32[:n], n_pad, self._tile)
         if staged is None or order is not None:
             arrs = self._encode_inline(batch, order)
         else:
@@ -1038,11 +1046,13 @@ def _window_steps(gidx: np.ndarray, n_pad: int, tile: int) -> tuple:
     ``(steps, live)``, ``steps`` the row tile then the group window (of
     ``tile`` groups) of each of ``2 * n_pad / tile`` steps, ordered by
     window and then tile, ``live`` how many are real (the rest repeat the
-    last).  Every group id below the largest has a row, so the rows of one
-    tile span at most ``tile`` consecutive ids: two windows at most, and a
-    tile in two windows is folded once into each, at consecutive steps."""
+    last).  Only the tiles that hold rows are named.  Every group id below
+    the largest has a row, so the rows of one tile span at most ``tile``
+    consecutive ids: two windows at most, and a tile in two windows is
+    folded once into each, at consecutive steps."""
     n = gidx.size
-    nt = n_pad // tile
+    nt = -(-n // tile)
+    half = 2 * (n_pad // tile)
     lo = gidx[0:n:tile] // tile
     hi = gidx[np.minimum(np.arange(1, nt + 1) * tile, n) - 1] // tile
     two = np.flatnonzero(hi > lo)
@@ -1050,11 +1060,11 @@ def _window_steps(gidx: np.ndarray, n_pad: int, tile: int) -> tuple:
     w = np.concatenate([lo, hi[two]])
     o = np.lexsort((t, w))
     live = o.size
-    steps = np.empty(4 * nt, np.int32)
+    steps = np.empty(2 * half, np.int32)
     steps[:live] = t[o]
-    steps[live : 2 * nt] = t[o[-1]]
-    steps[2 * nt : 2 * nt + live] = w[o]
-    steps[2 * nt + live :] = w[o[-1]]
+    steps[live:half] = t[o[-1]]
+    steps[half : half + live] = w[o]
+    steps[half + live :] = w[o[-1]]
     return steps, live
 
 

@@ -21,7 +21,7 @@ from repro.core.dtypes import DType
 from repro.core.errors import SchemaError, TypeMismatchError
 from repro.core.schema import Field, Schema
 
-__all__ = ["Column", "RecordBatch", "concat_batches"]
+__all__ = ["Column", "RecordBatch", "CoalescedBatch", "concat_batches"]
 
 _ALIGN = 8
 
@@ -333,3 +333,16 @@ def concat_batches(batches) -> RecordBatch:
             )
         cols.append(col)
     return RecordBatch(schema, cols)
+
+
+class CoalescedBatch(RecordBatch):
+    """A morsel merged from consecutive batches of one stream.  A device
+    kernel lays it out at ``kernel_rows`` rows, the morsel size it was
+    merged up to, so every such morsel shares one compiled shape."""
+
+    __slots__ = ("kernel_rows",)
+
+    def __init__(self, batches, kernel_rows: int):
+        merged = concat_batches(batches)
+        super().__init__(merged.schema, merged.columns)
+        self.kernel_rows = kernel_rows

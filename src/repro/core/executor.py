@@ -4,8 +4,9 @@ hardware allows").
 ``execute_parallel`` compiles an (optimized) COOK DAG into **pipelines** —
 maximal chains of morsel-pure operators (filter/select/project/map)
 separated by **pipeline breakers** (aggregate build, join build).  Each
-pipeline's source stream is cut into *morsels* (RecordBatch slices of
-``morsel_rows``) that a pool of worker threads drains concurrently; results
+pipeline's source stream is cut into *morsels* (a batch above
+``morsel_rows`` sliced to it, consecutive smaller batches coalesced up to
+it) that a pool of worker threads drains concurrently; results
 are reassembled **in input order** through a bounded reorder window, which
 doubles as backpressure: workers stop pulling new morsels when the consumer
 falls more than ``window`` morsels behind.  Output batches therefore stream
@@ -41,10 +42,13 @@ backend.
 
 Morsel sizing is either static (``morsel_rows=N``: byte-deterministic
 output for a given N regardless of worker count) or adaptive
-(``morsel_rows="auto"``: each pipeline tunes its slice size from an EWMA of
+(``morsel_rows="auto"``: each pipeline tunes its size from an EWMA of
 observed morsel latency toward ~1 ms/morsel, clamped to [4096, 262144];
 row *order* is still deterministic, but float aggregation partial sums may
-group differently run-to-run as boundaries move).  The ``ExecutorStats`` a
+group differently run-to-run as boundaries move).  Both modes coalesce
+runs of batches smaller than the size (a selective filter sunk into the
+scan thins every batch), and a coalesced morsel reaches the fused kernel
+at the morsel size, one row shape for all of them.  The ``ExecutorStats`` a
 caller passes in (a flow's own, for STATUS) reports per-pipeline morsel
 counts, the tuned size and the run's scan counters.
 
@@ -83,7 +87,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Iterator
 
 from repro.core.backend import FUSED_INELIGIBLE, ComputeBackend, get_backend, plan_fused_chain, resolve_device
-from repro.core.batch import RecordBatch, concat_batches
+from repro.core.batch import CoalescedBatch, RecordBatch, concat_batches
 from repro.core.dag import Dag, Node
 from repro.core.env import env_bytes, env_devices, env_dir, env_int, env_morsel_rows, env_str, knob_default
 from repro.core.errors import FlowCancelled, PlanError, SchemaError
@@ -141,7 +145,8 @@ class ExecutorConfig:
     num_workers   morsel worker threads per pipeline stage; 1 = sequential
                   in-line execution (no threads), 0 = delegate to the
                   reference pull chain (``operators.execute``).
-    morsel_rows   rows per morsel (source batches are sliced to this), or
+    morsel_rows   rows per morsel (larger source batches are sliced to
+                  this, runs of smaller ones coalesced up to it), or
                   ``"auto"``: each pipeline tunes its own size from an EWMA
                   of observed morsel latency (target ~1 ms/morsel, clamped
                   to [4096, 262144]); the chosen size lands in the run's
@@ -515,14 +520,16 @@ class _Branch:
     """One pipeline input: an SDF plus the op specs applied to its morsels.
     Unions contribute several branches to the same stage.  ``source`` marks
     an SDF the resolver opened (a scan or an exchange pull), as opposed to
-    an upstream breaker's output."""
+    an upstream breaker's output.  ``stream`` names the partition union
+    (one scan split by part range) the branch is a part of, or is None."""
 
-    __slots__ = ("sdf", "specs", "source")
+    __slots__ = ("sdf", "specs", "source", "stream")
 
-    def __init__(self, sdf: StreamingDataFrame, specs: list | None = None, source: bool = False):
+    def __init__(self, sdf: StreamingDataFrame, specs: list | None = None, source: bool = False, stream=None):
         self.sdf = sdf
         self.specs = specs if specs is not None else []
         self.source = source
+        self.stream = stream
 
 
 def _apply_ops(cops, batch: RecordBatch) -> RecordBatch | None:
@@ -554,14 +561,34 @@ def _morsel_slices(batch: RecordBatch, sizer: _MorselSizer):
         s += rows
 
 
-def _branch_items(cops, batches, sizer: _MorselSizer, cfg: ExecutorConfig, do_stage: bool):
-    """One branch's batches → morsels, in input order.
+def _streams(compiled: list) -> list:
+    """The indices of ``compiled``'s branches, in streams: the consecutive
+    branches of one partition union form one stream, whose batches arrive
+    as the unsplit scan's would, and every other branch is a stream of its
+    own."""
+    out: list = []
+    for i, (br, _cops) in enumerate(compiled):
+        if out and br.stream is not None and compiled[out[-1][-1]][0].stream == br.stream:
+            out[-1].append(i)
+        else:
+            out.append([i])
+    return out
 
-    Adaptive mode coalesces runs of tiny source batches into a single
-    morsel (**micro-morsel batching**: when the sizer picks sizes larger
-    than what the source produces, launches amortize over the coalesced
-    run instead of one per fragment; output order is preserved because
-    only *consecutive* batches merge).  On a fused plan, each emitted
+
+def _branch_items(cops, batches, sizer: _MorselSizer, do_stage: bool):
+    """One stream's batches → morsels, in input order.
+
+    A batch at or above the morsel size is sliced to it.  Smaller batches
+    are held and **coalesced**: consecutive ones merge into one morsel until
+    the next would push it past the size, or the branch ends, so a run of
+    batches that a selective filter thinned costs one launch, not one each.
+    Only consecutive batches of one stream (see :func:`_streams`) merge, so
+    row order holds, and the boundaries depend only on the batch sizes:
+    static output stays byte-deterministic whatever the worker count, and
+    a partition-parallel plan's equal to the unsplit plan's.  A morsel merged from two
+    or more batches is a :class:`CoalescedBatch` whose ``kernel_rows`` is
+    the morsel size, so every such morsel reaches the fused kernel at one
+    row shape; a lone batch keeps its own.  On a fused plan, each emitted
     morsel's kernel inputs are staged to the device before the morsel is
     handed to a worker (**double-buffering**: jax H2D transfers are async,
     so morsel N+1's upload overlaps morsel N's compute)."""
@@ -576,32 +603,28 @@ def _branch_items(cops, batches, sizer: _MorselSizer, cfg: ExecutorConfig, do_st
 
     def flush():
         nonlocal pending, pending_rows
-        if not pending:
-            return None
-        m = pending[0] if len(pending) == 1 else concat_batches(pending)
-        if len(pending) > 1:
+        if len(pending) == 1:
+            m = pending[0]
+        else:
+            m = CoalescedBatch(pending, sizer.current())
             sizer.bump("micromorsels_coalesced", len(pending) - 1)
         pending = []
         pending_rows = 0
         return emit(m)
 
     for batch in batches:
-        if cfg.auto_morsels and batch.num_rows < sizer.current():
+        if batch.num_rows < sizer.current():
             if pending and pending_rows + batch.num_rows > sizer.current():
-                out = flush()
-                if out is not None:
-                    yield out
+                yield flush()
             pending.append(batch)
             pending_rows += batch.num_rows
             continue
-        out = flush()
-        if out is not None:
-            yield out
+        if pending:
+            yield flush()
         for m in _morsel_slices(batch, sizer):
             yield emit(m)
-    out = flush()
-    if out is not None:
-        yield out
+    if pending:
+        yield flush()
 
 
 _device_rr = itertools.count()  # round-robin cursor over cfg.devices
@@ -653,8 +676,10 @@ def _run_ordered(
 
     if cfg.num_workers <= 1:
         try:
-            for br, cops in compiled:
-                for m in _branch_items(cops, br.sdf.iter_batches(), sizer, cfg, do_stage=False):
+            for idx in _streams(compiled):
+                cops = compiled[idx[0]][1]
+                batches = itertools.chain.from_iterable(compiled[i][0].sdf.iter_batches() for i in idx)
+                for m in _branch_items(cops, batches, sizer, do_stage=False):
                     if cancel is not None and cancel.is_set():
                         raise FlowCancelled("execution cancelled")
                     t0 = time.perf_counter()
@@ -676,8 +701,10 @@ def _run_ordered(
         pf.start()  # all sources (incl. every exchange pull) activate now
 
     def morsels():
-        for (_, cops), pf in zip(compiled, prefetchers):
-            for m in _branch_items(cops, pf, sizer, cfg, do_stage=True):
+        for idx in _streams(compiled):
+            cops = compiled[idx[0]][1]
+            batches = itertools.chain.from_iterable(prefetchers[i] for i in idx)
+            for m in _branch_items(cops, batches, sizer, do_stage=True):
                 yield cops, m
 
     it = morsels()
@@ -871,11 +898,11 @@ class _Compiler:
         if memo is not None:
             branches, schema = memo
             # consumers mutate spec lists; hand each its own copy
-            return [_Branch(br.sdf, list(br.specs), br.source) for br in branches], schema
+            return [_Branch(br.sdf, list(br.specs), br.source, br.stream) for br in branches], schema
         out = self._compile_node(self.dag.nodes[nid])
         self._memo[nid] = out
         branches, schema = out
-        return [_Branch(br.sdf, list(br.specs), br.source) for br in branches], schema
+        return [_Branch(br.sdf, list(br.specs), br.source, br.stream) for br in branches], schema
 
     def _compile_node(self, node: Node) -> tuple:
         op = node.op
@@ -895,6 +922,9 @@ class _Compiler:
                 if not s2.equals(schema):
                     raise SchemaError("union over mismatched schemas")
                 branches.extend(b2)
+            if node.params.get("partition"):
+                for br in branches:
+                    br.stream = node.id
             return branches, schema
         if op == "aggregate":
             return self._compile_aggregate(node)

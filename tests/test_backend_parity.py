@@ -630,6 +630,82 @@ def test_fused_chain_composes_with_spill(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# coalesced morsels: thinned batches merged up to morsel_rows, one kernel shape
+# ---------------------------------------------------------------------------
+# At 2048-row morsels these batches make four morsels: 500+500+500 merged,
+# 1100 alone, 1400+300 merged, 700 alone.  A merged morsel's own rows would
+# round up to 1536 and 1792.
+COALESCE_SIZES = [500, 500, 500, 1100, 1400, 300, 700]
+COALESCE_LAUNCHES = [(700, 768), (1100, 1280), (1500, 2048), (1700, 2048)]  # (rows, kernel rows)
+
+
+def _coalesce_dag(kind):
+    bld = Dag.build()
+    s = bld.source("dacp://h:1/d")
+    if kind == "rows":
+        node = bld.add("filter", {"predicate": col("price") > 20.0}, [s])
+        node = bld.add("select", {"columns": ["price", "g"]}, [node])
+        return bld.finish(node)
+    node = bld.add("project", {"exprs": {"revenue": col("price") * col("disc")}, "keep": True}, [s])
+    aggs = {"revenue": {"fn": "sum", "column": "revenue"}}
+    keys = {"keyless_sum": [], "keyed_fold": ["k"], "windowed_fold": ["g"]}[kind]
+    if keys:
+        aggs.update({"n": {"fn": "count"}, "lo": {"fn": "min", "column": "price"}, "q": {"fn": "sum", "column": "qty"}})
+    return bld.finish(bld.add("aggregate", {"keys": keys, "aggs": aggs}, [node]))
+
+
+@pytest.mark.parametrize("kind", ["keyless_sum", "keyed_fold", "windowed_fold", "rows"])
+def test_coalesced_morsels_match_numpy_at_one_kernel_shape(monkeypatch, kind):
+    """Static morsels merged from thinned batches give the numpy backend's
+    bytes (a Q6-shaped keyless float sum, a one-hot keyed fold, a windowed
+    fold above 256 groups, a row stream); every merged morsel reaches the
+    kernel at ``morsel_rows`` rows, and a lone batch at its own rows
+    rounded up to a tile."""
+    from repro.core.backend import FusedChainPlan
+
+    launches = []
+    orig = FusedChainPlan._launch
+
+    def spy(self, arrs, gidx, n, *args, **kwargs):
+        launches.append((n, gidx.shape[0], arrs["pred"].shape[0]))
+        return orig(self, arrs, gidx, n, *args, **kwargs)
+
+    monkeypatch.setattr(FusedChainPlan, "_launch", spy)
+    rng = np.random.default_rng(40)
+    n = sum(COALESCE_SIZES)
+    batch = RecordBatch.from_pydict(
+        {
+            "price": rng.uniform(1.0, 100.0, n).astype(np.float32),
+            "disc": rng.uniform(0.0, 0.1, n).astype(np.float32),
+            "qty": rng.integers(1, 50, n).astype(np.int32),
+            "k": rng.integers(0, 6, n).astype(np.int32),
+            "g": (np.arange(n) % 700).astype(np.int64),
+        }
+    )
+    edges = np.cumsum([0] + COALESCE_SIZES)
+
+    def sdf():
+        def gen():
+            for a, b in zip(edges[:-1], edges[1:]):
+                yield batch.slice(a, b)
+
+        return StreamingDataFrame(batch.schema, gen)
+
+    outs = {}
+    for backend in ("numpy", "pallas"):
+        stats = ExecutorStats()
+        cfg = ExecutorConfig(num_workers=2, morsel_rows=2048, backend=backend)
+        outs[backend] = execute_parallel(_coalesce_dag(kind), lambda nn: sdf(), cfg, stats=stats).collect()
+        prog = stats.progress()
+        assert prog["morsels_done"] == 4
+        assert prog["micromorsels_coalesced"] == 3
+    _assert_byte_identical(outs["pallas"], outs["numpy"])
+    assert prog["fused_launches"] == 4
+    assert sorted((rows, pad) for rows, pad, _ in launches) == COALESCE_LAUNCHES
+    assert all(pad == pred_rows for _, pad, pred_rows in launches)
+
+
+# ---------------------------------------------------------------------------
 # no silent fallback: kernel failures, missing jax, bad devices, the cache
 # ---------------------------------------------------------------------------
 def _cook_dag(kind):

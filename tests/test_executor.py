@@ -549,10 +549,11 @@ def test_dense_factorization_narrow_signed_keys():
 
 
 # ---------------------------------------------------------------------------
-# micro-morsel batching (PR 7)
+# micro-morsel batching: coalescing in both morsel modes
 # ---------------------------------------------------------------------------
-def test_micromorsel_coalescing_preserves_order():
-    """Adaptive mode coalesces runs of tiny source batches into one morsel;
+@pytest.mark.parametrize("morsel_rows", ["auto", 4096])
+def test_micromorsel_coalescing_preserves_order(morsel_rows):
+    """Both modes coalesce runs of tiny source batches into one morsel;
     only *consecutive* batches merge, so the output stays in exact input
     order across a multi-worker pool."""
     from repro.core.executor import ExecutorStats
@@ -565,11 +566,79 @@ def test_micromorsel_coalescing_preserves_order():
     dag = bld.finish(f)
 
     stats = ExecutorStats()
-    cfg = ExecutorConfig(num_workers=4, morsel_rows="auto", backend="numpy")
-    # 150-row fragments: far below AUTO_MORSEL_MIN, so runs of them coalesce
+    cfg = ExecutorConfig(num_workers=4, morsel_rows=morsel_rows, backend="numpy")
+    # 150-row fragments: far below either size, so runs of them coalesce
     got = execute_parallel(dag, lambda nn: _sdf(full, rows=150), cfg, stats=stats).collect()
     assert np.array_equal(got.column("seq").to_numpy(), np.arange(n))
     assert stats.progress()["micromorsels_coalesced"] > 0, "tiny batches never coalesced"
+
+
+@pytest.mark.parametrize(
+    "sizes, want",
+    [
+        ([1200] * 5, [(1200,) * 5]),
+        ([30_000] * 3, [(30_000, 30_000), 30_000]),
+        ([64_000, 64_200], [64_000, 64_200]),
+        ([1000, 1000, 70_000, 1000], [(1000, 1000), 65_536, 4464, 1000]),
+    ],
+    ids=["thinned", "up_to_the_size", "no_fit", "sliced_between"],
+)
+def test_static_morsels_coalesce_up_to_morsel_rows(sizes, want):
+    """Static mode: batches below ``morsel_rows`` merge, in input order,
+    until the next would pass the size; a batch at or above it is sliced.
+    ``want`` lists each morsel: a tuple of the batches merged into it, or
+    the rows of a lone batch or slice.  A merged morsel is laid out at the
+    morsel size, and ``micromorsels_coalesced`` counts each batch it took
+    in past the first."""
+    from repro.core.batch import CoalescedBatch
+    from repro.core.executor import _branch_items, _MorselSizer
+
+    edges = np.cumsum([0] + sizes)
+    full = RecordBatch.from_pydict({"seq": np.arange(edges[-1])})
+    batches = [full.slice(a, b) for a, b in zip(edges[:-1], edges[1:])]
+    sizer = _MorselSizer(65_536, adaptive=False)
+    got = list(_branch_items(([], None), iter(batches), sizer, do_stage=False))
+
+    assert [m.num_rows for m in got] == [sum(w) if isinstance(w, tuple) else w for w in want]
+    assert np.array_equal(np.concatenate([m.column("seq").to_numpy() for m in got]), np.arange(edges[-1]))
+    for m, w in zip(got, want):
+        assert isinstance(m, CoalescedBatch) == isinstance(w, tuple)
+        if isinstance(w, tuple):
+            assert m.kernel_rows == 65_536
+    assert sizer.micromorsels_coalesced == sum(len(w) - 1 for w in want if isinstance(w, tuple))
+
+
+@pytest.mark.parametrize("kind", ["rows", "aggregate"])
+def test_static_coalescing_is_byte_identical_across_workers(kind):
+    """Morsel boundaries depend only on the batch sizes, so a static run
+    over thinned batches gives the same bytes with 1 and 4 workers, float
+    partial sums included: 300 batches of 200 rows merge 20 to a morsel."""
+    from repro.core.executor import ExecutorStats
+
+    full = _table(60_000, seed=5)
+    bld = Dag.build()
+    s = bld.source("dacp://h:1/d")
+    node = bld.add("filter", {"predicate": col("x") > 0.5}, [s])
+    if kind == "aggregate":
+        aggs = {"n": {"fn": "count"}, "sx": {"fn": "sum", "column": "x"}, "mx": {"fn": "mean", "column": "x"}}
+        node = bld.add("aggregate", {"keys": ["k"], "aggs": aggs}, [node])
+    else:
+        node = bld.add("select", {"columns": ["k", "x"]}, [node])
+    dag = bld.finish(node)
+
+    outs = []
+    for workers in (1, 4):
+        stats = ExecutorStats()
+        got = execute_parallel(dag, lambda n: _sdf(full, rows=200), _cfg(workers, morsel_rows=4096), stats=stats)
+        got = got.collect()
+        outs.append({name: got.column(name).to_numpy().tobytes() for name in got.schema.names})
+        prog = stats.progress()
+        assert prog["morsels_done"] == 15
+        assert prog["micromorsels_coalesced"] == 285
+    assert outs[0] == outs[1]
+    if kind == "rows":
+        ref = execute(dag, lambda n: _sdf(full, rows=200)).collect()
+        assert outs[0] == {name: ref.column(name).to_numpy().tobytes() for name in ref.schema.names}
 
 
 def test_cancel_mid_batch_clears_staged_buffers(monkeypatch):
